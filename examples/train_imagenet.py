@@ -141,6 +141,11 @@ def main():
     add_data_args(parser)
     args = parser.parse_args()
     logging.basicConfig(level=logging.INFO)
+    # a ResNet-50 step takes about a minute to compile: keep it across
+    # runs (JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache)
+    logging.info('compile cache: %s',
+                 mx.compile_cache.ensure_persistent_cache(
+                     checkout_default=True))
 
     image_shape = tuple(int(v) for v in args.image_shape.split(','))
     kw = {'stem': args.stem,

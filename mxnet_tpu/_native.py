@@ -2,8 +2,10 @@
 
 The reference crosses this boundary via the C API
 (``MXRecordIOReaderCreate`` etc., ``src/c_api/c_api.cc:720-805``); here
-the flat ABI is loaded directly with ctypes.  If the shared object is
-missing it is built on first use with g++ (no pip deps).
+the flat ABI is loaded directly with ctypes.  The shared object is built
+on first use with g++ (no pip deps), and rebuilt when any of its
+``src/*.cc`` is newer than it, so what is loaded always corresponds to
+the sources in the tree.
 """
 from __future__ import annotations
 
@@ -14,30 +16,37 @@ import subprocess
 _LIB = None
 
 
-def _build_so(so_path, sources, extra_link):
-    """Compile to a per-pid temp file, then os.rename into place —
-    rename is atomic on POSIX, so concurrent builders (forked dist
-    workers, parallel test runners) never load a half-written .so."""
+_HERE = os.path.dirname(os.path.abspath(__file__))
+_SRC = os.path.join(_HERE, '..', 'src')
+
+
+def _fresh_so(so_path, sources, extra_link):
+    """``so_path``, (re)built from ``sources`` unless it is at least as
+    new as every one of them: the same mtime rule as ``src/Makefile``,
+    so a library older than its sources (left over from another commit)
+    is never loaded.  Compiles to a per-pid temp file, then os.rename
+    into place — rename is atomic on POSIX, so concurrent builders
+    (forked dist workers, parallel test runners) never load a
+    half-written .so."""
+    sources = list(sources)
+    if os.path.exists(so_path) and os.path.getmtime(so_path) >= \
+            max(os.path.getmtime(s) for s in sources):
+        return so_path
     tmp = '%s.%d.tmp' % (so_path, os.getpid())
     subprocess.check_call(
         ['g++', '-O3', '-std=c++17', '-fPIC', '-Wall', '-shared'] +
-        list(sources) + ['-o', tmp] + list(extra_link))
+        sources + ['-o', tmp] + list(extra_link))
     os.rename(tmp, so_path)
+    return so_path
 
 
 def lib():
     global _LIB
     if _LIB is not None:
         return _LIB
-    here = os.path.dirname(os.path.abspath(__file__))
-    # ABI-versioned filename: a stale pre-extension library on disk is
-    # simply ignored (re-dlopening the same path would return the old
-    # handle — glibc dedups by pathname and ctypes never dlcloses)
-    so_path = os.path.join(here, 'libmxtpu_io_abi2.so')
-    src = os.path.join(here, '..', 'src', 'recordio.cc')
-    if not os.path.exists(so_path):
-        _build_so(so_path, [src], ['-ljpeg', '-lpthread'])
-    L = ctypes.CDLL(so_path)
+    L = ctypes.CDLL(_fresh_so(
+        os.path.join(_HERE, 'libmxtpu_io_abi2.so'),
+        [os.path.join(_SRC, 'recordio.cc')], ['-ljpeg', '-lpthread']))
     L.MXTPURecordIOWriterCreate.restype = ctypes.c_void_p
     L.MXTPURecordIOWriterCreate.argtypes = [ctypes.c_char_p]
     L.MXTPURecordIOWriterTell.restype = ctypes.c_long
@@ -100,14 +109,10 @@ def rt_lib():
     global _RT_LIB
     if _RT_LIB is not None:
         return _RT_LIB
-    here = os.path.dirname(os.path.abspath(__file__))
-    so_path = os.path.join(here, 'libmxtpu_rt.so')
-    if not os.path.exists(so_path):
-        srcdir = os.path.join(here, '..', 'src')
-        _build_so(so_path, [os.path.join(srcdir, 'engine.cc'),
-                            os.path.join(srcdir, 'storage.cc')],
-                  ['-lpthread'])
-    L = ctypes.CDLL(so_path)
+    L = ctypes.CDLL(_fresh_so(
+        os.path.join(_HERE, 'libmxtpu_rt.so'),
+        [os.path.join(_SRC, 'engine.cc'), os.path.join(_SRC, 'storage.cc')],
+        ['-lpthread']))
     L.MXTPUEngineCreate.restype = ctypes.c_void_p
     L.MXTPUEngineCreate.argtypes = [ctypes.c_int, ctypes.c_int]
     L.MXTPUEngineFree.argtypes = [ctypes.c_void_p]

@@ -53,7 +53,6 @@ as MXTPU_PROFILE / MXTPU_PERFWATCH.
 """
 from __future__ import annotations
 
-import logging
 import re
 import sys
 import threading
@@ -71,16 +70,14 @@ __all__ = [
 
 # Peak per-chip interconnect bandwidth (bytes/sec, all links combined)
 # per device kind — the denominator of the communication roofline leg,
-# the sibling of perfwatch.PEAKS.  Conservative public figures; the CPU
-# entry is a nominal shared-memory figure so perf.comm_fraction stays
-# defined (not meaningful) in CPU tests; unknown kinds fall back to
-# TPU v5 lite like the FLOPs table.  MXTPU_PEAK_BW pins it explicitly.
+# the sibling of perfwatch.PEAKS (TPU v5 lite: 1,600 Gbit/s, Google
+# Cloud documentation, "TPU v5e").  An unknown kind is an error;
+# MXTPU_PEAK_BW pins the figure explicitly (CPU tests do).
 ICI_PEAKS = {
     'TPU v5 lite': 200e9,
     'TPU v5': 600e9,
     'TPU v4': 300e9,
     'TPU v6 lite': 400e9,
-    'cpu': 10e9,
 }
 
 _on = False
@@ -132,36 +129,17 @@ def activate_fit():
 # Interconnect peaks
 # ---------------------------------------------------------------------------
 
-_warned_fallback_bw = False
-
-
 def interconnect_bw(kind=None):
     """Peak interconnect bytes/sec for the comm-roofline denominator:
     the MXTPU_PEAK_BW override when set, else :data:`ICI_PEAKS` by
-    device kind (``perfwatch._live_device_kind`` — the same
-    never-initialize probe the FLOPs table uses).  Falling back with
-    jax live warns ONCE naming the unknown kind: a comm_fraction
-    against the wrong fabric peak must not be silently wrong."""
-    global _warned_fallback_bw
+    device kind (the attached device's when None).  An unknown kind
+    raises."""
     override = float(config.get('MXTPU_PEAK_BW'))
     if override > 0:
         return override
-    jax_live = False
-    if kind is None:
-        jax_live, kind = perfwatch._live_device_kind()
-    if kind:
-        for key, bw in ICI_PEAKS.items():
-            if str(kind).startswith(key):
-                return bw
-    if jax_live and not _warned_fallback_bw:
-        _warned_fallback_bw = True
-        logging.warning(
-            'mxtpu commwatch: device kind %r not in the interconnect '
-            'peak table — perf.comm_fraction uses the %s fallback '
-            '(%.3g B/s); set MXTPU_PEAK_BW to pin it', kind,
-            perfwatch.DEFAULT_PEAK_KEY,
-            ICI_PEAKS[perfwatch.DEFAULT_PEAK_KEY])
-    return ICI_PEAKS[perfwatch.DEFAULT_PEAK_KEY]
+    return perfwatch.lookup_peak(
+        ICI_PEAKS, perfwatch.device_kind() if kind is None else kind,
+        'MXTPU_PEAK_BW')
 
 
 # ---------------------------------------------------------------------------
@@ -308,22 +286,6 @@ def collective_stats(hlo_text, num_devices=1):
     return stats
 
 
-def _hlo_text(compiled):
-    """The compiled (post-SPMD-partitioning) HLO text, across the two
-    jax Compiled APIs; None when the backend exposes neither."""
-    try:
-        mods = getattr(compiled, 'hlo_modules', None)
-        if callable(mods):
-            return '\n'.join(m.to_string() for m in mods())
-    except Exception:
-        pass
-    try:
-        txt = compiled.as_text()
-        return txt if isinstance(txt, str) else None
-    except Exception:
-        return None
-
-
 def _kind_gauge(ckind):
     return 'comm.' + ckind.replace('-', '_')
 
@@ -346,8 +308,8 @@ def analyze_executable(kind, key, compiled, num_devices=1):
             row = _programs.get((kind, keystr))
         if row is not None:
             return row
-        text = _hlo_text(compiled)
-        stats = collective_stats(text, num_devices) if text else {}
+        # the compiled (post-SPMD-partitioning) HLO text
+        stats = collective_stats(compiled.as_text(), num_devices)
         total_wire = sum(s['wire_bytes'] for s in stats.values())
         row = {'kind': kind, 'key': keystr,
                'num_devices': max(1, int(num_devices)),

@@ -8,14 +8,15 @@ its key appears mid-epoch, and nothing persists compiled artifacts
 across runs.  This module is the warm-start half of the ROADMAP's "as
 fast as the hardware allows" north star, in three legs:
 
-1. **Persistent cache** (``MXTPU_COMPILE_CACHE=<dir>``) —
-   :func:`ensure_persistent_cache` wires JAX's persistent compilation
-   cache at that directory (with the compile-time floor dropped to 0 so
-   small CPU-sized programs persist too), so a second process reuses
-   compiled executables from disk instead of re-invoking XLA.  The
-   cache's monitoring events land in the PR-1 instrument registry as
-   ``compile.cache_hits`` / ``compile.cache_misses`` and the
-   ``compile.time_saved_secs`` timer.
+1. **Persistent cache** — :func:`ensure_persistent_cache` wires JAX's
+   persistent compilation cache at the directory :func:`resolve_cache_dir`
+   names (``JAX_COMPILATION_CACHE_DIR``, else ``MXTPU_COMPILE_CACHE``,
+   else for the run scripts ``<checkout>/.jax_cache``), with the
+   compile-time floor dropped to 0 so small CPU-sized programs persist
+   too, so a second process reuses compiled executables from disk
+   instead of re-invoking XLA.  The cache's monitoring events land in
+   the PR-1 instrument registry as ``compile.cache_hits`` /
+   ``compile.cache_misses`` and the ``compile.time_saved_secs`` timer.
 
 2. **AOT warmup manifest** — every jit trace taken through
    :func:`traced` (the executor's forward/fwd+bwd programs, the fused
@@ -42,7 +43,7 @@ fast as the hardware allows" north star, in three legs:
    bound the number of distinct compiled inference shapes (the
    ``compile.shape_buckets`` gauge).
 
-Zero overhead when off: with no ``MXTPU_COMPILE_CACHE`` the manifest is
+Zero overhead when off: with neither variable set the manifest is
 never created (recording is one module-global ``is None`` check, taken
 only at trace time anyway), no JAX config is touched, no listener is
 registered, and no pool thread exists.
@@ -59,7 +60,8 @@ import time
 from . import config, instrument
 
 __all__ = [
-    'ensure_persistent_cache', 'cache_dir', 'manifest_path',
+    'resolve_cache_dir', 'ensure_persistent_cache', 'cache_dir',
+    'manifest_path',
     'fingerprint', 'traced', 'manifest_entries', 'record_entry',
     'jsonable',
     'warm_start', 'warmup_submit',
@@ -82,15 +84,43 @@ _inflight = 0
 # Leg 1: persistent compilation cache
 # ---------------------------------------------------------------------------
 
-def ensure_persistent_cache():
-    """Install the JAX persistent compilation cache at the
-    ``MXTPU_COMPILE_CACHE`` directory (idempotent; re-reads the env var
-    until installed, so a knob exported after import still takes).
-    Returns the directory, or None when the knob is unset."""
+# the run scripts' cache when the environment names none: a fixed path
+# inside the checkout (git-ignored), because the directory is part of
+# the cache key and one that moves never hits
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    '.jax_cache')
+
+
+def resolve_cache_dir(checkout_default=False):
+    """The one rule for where compiled programs persist.  Returns
+    ``(directory, jax_owns_it)``:
+
+    - ``JAX_COMPILATION_CACHE_DIR`` wins; JAX reads it itself, so no
+      code sets ``jax_compilation_cache_dir`` (``jax_owns_it`` True);
+    - else ``MXTPU_COMPILE_CACHE``;
+    - else :data:`CHECKOUT_CACHE_DIR` when ``checkout_default`` (the run
+      scripts: chip_smoke.py, bench.py, examples/train_imagenet.py), or
+      ``None`` (a plain ``import mxnet_tpu`` writes no cache).
+    """
+    env = os.environ.get('JAX_COMPILATION_CACHE_DIR')
+    if env:
+        return env, True
+    d = config.get('MXTPU_COMPILE_CACHE')
+    if d:
+        return d, False
+    return (CHECKOUT_CACHE_DIR if checkout_default else None), False
+
+
+def ensure_persistent_cache(checkout_default=False):
+    """Install the JAX persistent compilation cache + warmup manifest at
+    the :func:`resolve_cache_dir` directory (idempotent; re-reads the
+    environment until installed, so a knob exported after import still
+    takes).  Returns the directory, or None when none is named."""
     global _cache_dir, _manifest
     if _cache_dir is not None:
         return _cache_dir
-    d = config.get('MXTPU_COMPILE_CACHE')
+    d, jax_owns_it = resolve_cache_dir(checkout_default)
     if not d:
         return None
     with _lock:
@@ -98,7 +128,8 @@ def ensure_persistent_cache():
             return _cache_dir
         os.makedirs(d, exist_ok=True)
         import jax
-        jax.config.update('jax_compilation_cache_dir', d)
+        if not jax_owns_it:
+            jax.config.update('jax_compilation_cache_dir', d)
         # the default 1s floor would skip every CPU-sized program — a
         # warm start that only helps big models is not a warm start
         jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)

@@ -14,6 +14,13 @@ import threading
 
 import jax
 
+from .base import MXNetError
+
+
+def _pinned_to_cpu():
+    """Whether the process asked for the CPU backend and nothing else."""
+    return (jax.config.jax_platforms or '') == 'cpu'
+
 
 class Context:
     """A logical device, e.g. ``Context('tpu', 0)``.
@@ -65,10 +72,13 @@ class Context:
     def jax_device(self) -> jax.Device:
         """Resolve to a concrete ``jax.Device``.
 
-        ``tpu`` resolves to the default accelerator backend's devices; when
-        the process runs on CPU only (tests force ``JAX_PLATFORMS=cpu`` with
-        a virtual multi-device host), ``tpu(i)`` maps onto virtual CPU
-        device ``i`` so multi-device code paths stay exercisable.
+        ``tpu(i)`` is device ``i`` of JAX's default backend.  That backend
+        is the CPU only when the process was pinned to it
+        (``jax_platforms=cpu``, as the tests do with a virtual
+        multi-device host); an unpinned process in which JAX found no
+        accelerator raises instead of training on the host.  ``cpu(i)``
+        is a host device; its id wraps, as the reference treats every
+        CPU ordinal as the same host.
         """
         # local_devices, not devices: under jax.distributed the global
         # list includes other processes' devices, which are not
@@ -76,11 +86,20 @@ class Context:
         # like the reference's per-process CUDA ordinals)
         if self.device_type == 'tpu':
             devs = jax.local_devices()
-        else:
-            try:
-                devs = jax.local_devices(backend='cpu')
-            except RuntimeError:
-                devs = jax.local_devices()
+            if devs[0].platform == 'cpu' and not _pinned_to_cpu():
+                raise MXNetError(
+                    '%s requested but JAX found no accelerator (default '
+                    "backend is 'cpu' and jax_platforms is not pinned to "
+                    "'cpu')" % self)
+            if not 0 <= self.device_id < len(devs):
+                raise MXNetError('%s is out of range: %d %s device(s)'
+                                 % (self, len(devs), devs[0].platform))
+            return devs[self.device_id]
+        try:
+            devs = jax.local_devices(backend='cpu')
+        except RuntimeError:
+            # jax_platforms names the accelerator only: no host backend
+            devs = jax.local_devices()
         return devs[self.device_id % len(devs)]
 
 
@@ -105,6 +124,10 @@ def num_devices():
 
 
 def current_context() -> Context:
-    """The thread-local default context (default ``cpu(0)``)."""
+    """The thread-local default context.  With none set it follows
+    JAX's default backend: ``tpu(0)`` where an accelerator answers,
+    ``cpu(0)`` in a process pinned to the CPU (docs/deviations.md)."""
     ctx = getattr(Context._default_ctx, 'value', None)
-    return ctx if ctx is not None else Context('cpu', 0)
+    if ctx is not None:
+        return ctx
+    return Context('cpu' if jax.default_backend() == 'cpu' else 'tpu', 0)

@@ -69,8 +69,7 @@ and the normalized activation never materializes.  If any consumer is
 not a fusable conv the chain is left alone (the activation would
 materialize for that consumer anyway, making fusion traffic-neutral).
 
-Enabled for Module.fit / make_fit_step via ``MXTPU_FUSE_BN_CONV=1``
-(docs/roadmap.md perf item 1).  The rewrite preserves parameter names,
+Enabled for Module.fit / make_fit_step via ``MXTPU_FUSE_BN_CONV=1``.  The rewrite preserves parameter names,
 aux state and observable numerics (tests/test_fuse_bn_conv.py asserts
 fwd+bwd equality for every shape class).
 """
@@ -299,8 +298,7 @@ def _nhwc_regions(sym: Symbol) -> Symbol:
     each fused node is sandwiched in its own NCHW<->NHWC transposes —
     and since Pallas custom calls have FIXED operand layouts, XLA
     cannot always absorb those the way it can for native ops, risking a
-    materialized activation copy per kernel (docs/roadmap.md layout
-    finding).
+    materialized activation copy per kernel.
     """
     return _nhwc_regions_counted(sym)[0]
 
@@ -1069,9 +1067,8 @@ class FusePass(object):
 
 
 def _kernel_paths_live():
-    """True when the Pallas kernel paths actually compile (a TPU whose
-    Mosaic passes the ``ops/_caps`` capability probe, MXTPU_ASSUME_TPU,
-    or interpret forced).  The kernel-LOWERED rewrites
+    """True when the Pallas kernel paths actually compile (a TPU,
+    MXTPU_ASSUME_TPU, or interpret forced).  The kernel-LOWERED rewrites
     (``bn_relu_conv`` and its NHWC layout planning) only pay for
     themselves when their kernels are real: on the jnp reference path
     the fallback forms MATERIALIZE the normalize pass XLA would have

@@ -440,8 +440,13 @@ class PrefetchingIter(DataIter):
             if _sys.is_finalizing() or getattr(self._engine, '_handle',
                                                None) is None:
                 return
+            # Every queued fetch holds this object through its closure,
+            # so none is pending when __del__ runs — except the one whose
+            # release triggered it, on the engine's own worker thread,
+            # where waiting on the var would wait on the op that is
+            # running (a deadlock at exit: tools/check_io.py hung in it).
+            # del_var frees each var once what is queued on it completed.
             for v in self._vars:
-                self._engine.wait_for_var(v)
                 self._engine.del_var(v)
         except Exception:
             pass

@@ -33,21 +33,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from . import _caps
-from ._caps import pltpu, mosaic_missing_attr  # noqa: F401 (re-export)
-
-_HAS_PLTPU = _caps.HAS_PLTPU
-_MOSAIC_REQUIRED_ATTRS = _caps.MOSAIC_REQUIRED_ATTRS
-
-
-def _mosaic_degraded():
-    """Compat shim over the single shared probe (``ops/_caps.py``):
-    True when the compiled kernel path must fall back to the jnp
-    reference form because the installed Mosaic lacks a required
-    attribute.  The probe warns once process-wide for the whole kernel
-    library."""
-    return _caps.mosaic_degraded()
+from jax.experimental.pallas import tpu as pltpu
 
 # Measured on v5e (T=2048, D=128, causal): 128x128 blocks run at 8.5
 # TFLOPs (grid-overhead bound), 512x1024 at ~26, 1024x1024 at ~28 — vs 14
@@ -76,22 +62,16 @@ INTERPRET_MIN_SEQ = 2048
 
 
 def _mode(seq_len=None):
-    # The kernel's VMEM scratch shapes need pltpu even in interpret
-    # mode.  cpu_default='interpret' only at long sequence lengths:
+    # cpu_default='interpret' only at long sequence lengths:
     # attention's reference materializes the full score matrix, so the
     # interpreted kernel is the better CPU path there — but on short and
     # medium sequences the dense jnp expression wins (grid emulation in
     # Python is slow), so those keep 'reference'.
-    if not _HAS_PLTPU:
-        return 'reference'
     from .. import config
     cpu_default = 'interpret'
     if seq_len is not None and seq_len < INTERPRET_MIN_SEQ:
         cpu_default = 'reference'
-    mode = config.pallas_mode(cpu_default=cpu_default)
-    if mode == 'kernel' and _mosaic_degraded():
-        return 'reference'
-    return mode
+    return config.pallas_mode(cpu_default=cpu_default)
 
 
 def _use_pallas():
@@ -176,17 +156,13 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
     nk = pl.cdiv(tk, block_k)
 
     kwargs = {}
-    if _HAS_PLTPU:
-        vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
-        scratch = [pltpu.VMEM((block_q, 1), jnp.float32),
-                   pltpu.VMEM((block_q, 1), jnp.float32),
-                   pltpu.VMEM((block_q, d), jnp.float32)]
-        if not _interpret():
-            kwargs['compiler_params'] = pltpu.CompilerParams(
-                dimension_semantics=('parallel', 'parallel', 'arbitrary'))
-    else:  # pragma: no cover - interpret-only environments
-        vmem = pl.BlockSpec
-        scratch = []
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    scratch = [pltpu.VMEM((block_q, 1), jnp.float32),
+               pltpu.VMEM((block_q, 1), jnp.float32),
+               pltpu.VMEM((block_q, d), jnp.float32)]
+    if not _interpret():
+        kwargs['compiler_params'] = pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'parallel', 'arbitrary'))
 
     grid = (bh, nq, nk)
     out_shape = [jax.ShapeDtypeStruct((bh, tq, d), q.dtype),

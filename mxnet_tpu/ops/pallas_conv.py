@@ -2,8 +2,8 @@
 
 ``fused_scale_bias_conv(x, w, scale, bias) = conv3x3(relu(x*scale+bias), w)``
 — the 3x3 case of "fold the normalize pass into the consuming conv"
-(``pallas_fused.py`` is the 1x1/matmul case; ``docs/roadmap.md`` perf
-item 1).  XLA cannot fuse a reduction-fed elementwise prologue into a
+(``pallas_fused.py`` is the 1x1/matmul case).  XLA cannot fuse a
+reduction-fed elementwise prologue into a
 convolution, so the normalized activation otherwise materializes in HBM
 (one extra write + read of the full activation per conv).  Here the
 affine + relu + zero-padding all happen in VMEM on the streamed block:
@@ -33,8 +33,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ._caps import HAS_PLTPU as _HAS_PLTPU, pltpu
+
+# Mosaic's default scoped-VMEM limit is 16 MiB, and the nine unrolled
+# taps overrun it at ResNet-50's largest stride-2 shape: on a v5e the
+# bf16 56x56x128 stride-2 kernel was refused with "Scoped allocation
+# with size 24.88M and limit 16.00M exceeded scoped vmem limit by
+# 8.88M" (chip_smoke.py, PR 21).  A v5e core has 128 MiB of VMEM.
+VMEM_LIMIT_BYTES = 64 * 2 ** 20
 
 
 def _pick(total, pref):
@@ -99,7 +106,8 @@ def _pallas_conv(x, w, scale, bias, stride, relu, bc, bf, interpret):
     scratch = [pltpu.VMEM((oh * ow, bf), jnp.float32)]
     if not interpret:
         kwargs['compiler_params'] = pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'parallel', 'arbitrary'))
+            dimension_semantics=('parallel', 'parallel', 'arbitrary'),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES)
     return pl.pallas_call(
         functools.partial(_kernel, nc=nc, oh=oh, ow=ow, stride=stride,
                           relu=relu),
@@ -133,37 +141,39 @@ def _reference(x, w, scale, bias, stride, relu):
     return _conv(xa.astype(x.dtype), w, stride).astype(x.dtype)
 
 
-def _dispatch(x, w, scale, bias, stride, relu):
-    from .. import config
-    from . import _caps
-    mode = config.pallas_mode() if _HAS_PLTPU else 'reference'
-    if mode == 'kernel' and _caps.mosaic_degraded():
-        # installed Mosaic lacks a required attribute (warn-once in
-        # ops/_caps.py): the compiled path would AttributeError
-        # mid-trace, the jnp reference form is numerically identical
-        mode = 'reference'
-    if mode == 'reference':
-        return _reference(x, w, scale, bias, stride, relu)
-    interpret = mode == 'interpret'
+def kernel_blocks(x_shape, f, stride):
+    """The (bc, bf) channel blocks the kernel runs an NHWC ``x_shape``
+    with ``f`` filters at, or None for a shape it does not take, which
+    ``_dispatch`` routes to :func:`_reference`."""
+    _, h, wd, c = x_shape
     if stride not in (1, 2):
         # the kernel's tap factoring is written for strides 1 and 2
         # only; anything else silently sampling wrong rows would be a
-        # correctness bug, so fall back
-        return _reference(x, w, scale, bias, stride, relu)
-    if stride == 2 and (x.shape[1] % 2 or x.shape[2] % 2):
+        # correctness bug
+        return None
+    if stride == 2 and (h % 2 or wd % 2):
         # the reshape-factored stride-2 taps read a 2*oh slab from the
         # pad-1 block, which only fits when h and w are even (always
         # true for the ResNet stage boundaries)
-        return _reference(x, w, scale, bias, stride, relu)
-    c, f = x.shape[3], w.shape[3]
+        return None
     bc, bf = _pick(c, 128), _pick(f, 256)
     if bc is None or bf is None:
-        return _reference(x, w, scale, bias, stride, relu)
+        return None
     # VMEM guard: padded f32 activation block must stay well on-chip
-    if (x.shape[1] + 2) * (x.shape[2] + 2) * bc * 4 > 6 * 2 ** 20:
+    if (h + 2) * (wd + 2) * bc * 4 > 6 * 2 ** 20:
+        return None
+    return bc, bf
+
+
+def _dispatch(x, w, scale, bias, stride, relu):
+    from .. import config
+    mode = config.pallas_mode()
+    blocks = None if mode == 'reference' else \
+        kernel_blocks(x.shape, w.shape[3], stride)
+    if blocks is None:
         return _reference(x, w, scale, bias, stride, relu)
-    return _pallas_conv(x, w, scale, bias, stride, relu, bc, bf,
-                        interpret)
+    return _pallas_conv(x, w, scale, bias, stride, relu, *blocks,
+                        interpret=mode == 'interpret')
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))

@@ -5,8 +5,7 @@ computes a per-feature affine transform (exactly BatchNorm's inference/
 train *apply* step, with ``scale = gamma * rsqrt(var+eps)`` and
 ``bias = beta - mean * scale``) fused into the consuming matmul — the
 1x1-convolution case of "fold the normalize pass into the next conv"
-(docs/roadmap.md perf item 1; a 1x1 conv IS this matmul with
-``x = NHWC->(N*H*W, C)``).
+(a 1x1 conv IS this matmul with ``x = NHWC->(N*H*W, C)``).
 
 On a memory-bound graph the separate BN-apply pass costs one extra HBM
 read + write of the activation; here the affine happens in VMEM on the
@@ -30,8 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from ._caps import HAS_PLTPU as _HAS_PLTPU, pltpu
+from jax.experimental.pallas import tpu as pltpu
 
 from .registry import register_simple
 
@@ -105,32 +103,27 @@ def _reference(x, w, scale, bias, relu=False):
 
 
 def _mode():
-    """Shared kernel-dispatch decision: the config Pallas mode with the
-    Mosaic capability probe (``ops/_caps.py``) applied — 'kernel' only
-    when the installed Mosaic can actually compile these kernels."""
+    """The shared kernel-dispatch decision (``config.pallas_mode``)."""
     from .. import config
-    from . import _caps
-    mode = config.pallas_mode() if _HAS_PLTPU else 'reference'
-    if mode == 'kernel' and _caps.mosaic_degraded():
-        # installed Mosaic lacks a required attribute (warn-once in
-        # ops/_caps.py): the compiled path would AttributeError
-        # mid-trace, the jnp reference form is numerically identical
-        return 'reference'
-    return mode
+    return config.pallas_mode()
+
+
+def dot_blocks(m, k, n):
+    """The (bm, bn, bk) blocks the matmul kernels run an (m, k) x (k, n)
+    product at, or None for a shape they do not take, which the
+    dispatchers route to the jnp reference."""
+    blocks = _block(m, 512), _block(n, 256), _block(k, 512)
+    return None if None in blocks else blocks
 
 
 def _dispatch(x, w, scale, bias, relu):
     mode = _mode()
-    if mode == 'reference':
+    blocks = None if mode == 'reference' else \
+        dot_blocks(x.shape[0], x.shape[1], w.shape[1])
+    if blocks is None:
         return _reference(x, w, scale, bias, relu)
-    interpret = mode == 'interpret'
-    m, k = x.shape
-    n = w.shape[1]
-    bm, bn, bk = _block(m, 512), _block(n, 256), _block(k, 512)
-    if None in (bm, bn, bk):
-        return _reference(x, w, scale, bias, relu)
-    return _pallas_forward(x, w, scale, bias, bm, bn, bk, interpret,
-                           relu=relu)
+    return _pallas_forward(x, w, scale, bias, *blocks,
+                           interpret=mode == 'interpret', relu=relu)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -184,8 +177,6 @@ register_simple('fused_scale_bias_dot', fused_scale_bias_dot, ninputs=4,
 # read+write of the activation instead of three.  Channels-last 2D
 # tiling (M, C); the public entry reshapes NCHW around the kernel only
 # on the kernel paths (the jnp reference form broadcasts in place).
-# Lands blind on degraded-Mosaic installs (warn-once jnp form, same
-# contract as the other kernels) and activates on a real TPU.
 
 def _bn_relu_kernel(x_ref, s_ref, b_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)
@@ -218,6 +209,13 @@ def _bn_relu_reference(x, scale, bias):
     return jnp.maximum(y, 0.0).astype(x.dtype)
 
 
+def bn_relu_blocks(m, c):
+    """The (bm, bc) blocks the BN-ReLU kernel runs an (m, c) view at, or
+    None for a shape it does not take (routed to the jnp reference)."""
+    blocks = _block(m, 512), _block(c, 256)
+    return None if None in blocks else blocks
+
+
 def _bn_relu_dispatch(x, scale, bias):
     mode = _mode()
     if mode == 'reference':
@@ -231,11 +229,10 @@ def _bn_relu_dispatch(x, scale, bias):
         x2d = jnp.transpose(x, perm).reshape(-1, x.shape[1])
     else:
         x2d = x
-    m, c = x2d.shape
-    bm, bc = _block(m, 512), _block(c, 256)
-    if bm is None or bc is None:
+    blocks = bn_relu_blocks(*x2d.shape)
+    if blocks is None:
         return _bn_relu_reference(x, scale, bias)
-    y2d = _bn_relu_pallas(x2d, scale, bias, bm, bc, interpret)
+    y2d = _bn_relu_pallas(x2d, scale, bias, *blocks, interpret)
     if x.ndim > 2:
         spatial = x.shape[2:]
         y = y2d.reshape((x.shape[0],) + spatial + (x.shape[1],))
@@ -292,7 +289,7 @@ register_simple('fused_bn_relu', fused_bn_relu, ninputs=3,
 # applied to the fp32 accumulator at the last K step, so the matmul
 # result crosses HBM exactly once with the epilogue already folded in —
 # the cuDNN fused-epilogue discipline the elementwise-epilogue fusion
-# pass (fuse.py) lowers to when the Mosaic capability probe passes.
+# pass (fuse.py) lowers to on the kernel paths.
 
 def _dot_epi_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, nk, relu,
                     clip_lo, clip_hi):
@@ -353,14 +350,11 @@ def _dot_epi_reference(x, w, bias, relu, clip):
 
 def _dot_epi_dispatch(x, w, bias, relu, clip):
     mode = _mode()
-    if mode == 'reference':
+    blocks = None if mode == 'reference' else \
+        dot_blocks(x.shape[0], x.shape[1], w.shape[1])
+    if blocks is None:
         return _dot_epi_reference(x, w, bias, relu, clip)
-    m, k = x.shape
-    n = w.shape[1]
-    bm, bn, bk = _block(m, 512), _block(n, 256), _block(k, 512)
-    if None in (bm, bn, bk):
-        return _dot_epi_reference(x, w, bias, relu, clip)
-    return _dot_epi_pallas(x, w, bias, bm, bn, bk, mode == 'interpret',
+    return _dot_epi_pallas(x, w, bias, *blocks, mode == 'interpret',
                            relu, clip)
 
 

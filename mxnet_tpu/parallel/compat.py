@@ -1,76 +1,7 @@
-"""jax API compatibility shims for the parallel legs.
-
-``shard_map`` has moved twice across the jax versions this framework
-meets in the wild: modern jax exports ``jax.shard_map`` at top level,
-older releases keep it in ``jax.experimental.shard_map``, and the
-signature drifted with it (the replication-checking kwarg was renamed
-``check_rep`` -> ``check_vma``).  Every in-repo user imports through
-this module instead of ``from jax import shard_map`` so the whole
-``parallel/`` package — and the tests riding it — degrade to a single,
-explainable skip instead of per-file ImportErrors.
-
-Usage::
-
-    from .compat import shard_map           # None when unavailable
-    from .compat import require_shard_map   # raises with the reason
-
-``shard_map`` here always accepts the NEW kwarg spelling
-(``check_vma``) and translates for older jax.
-"""
+"""Capability probes for the parallel legs."""
 from __future__ import annotations
 
-import functools
-import inspect
-
-__all__ = ['shard_map', 'require_shard_map', 'SHARD_MAP_ERROR',
-           'multiprocess_cpu_missing']
-
-# why shard_map is unavailable (None when it is available)
-SHARD_MAP_ERROR = None
-
-
-def _resolve():
-    import jax
-    fn = getattr(jax, 'shard_map', None)
-    if fn is not None and callable(fn):
-        return fn
-    from jax.experimental.shard_map import shard_map as fn
-    return fn
-
-
-def _wrap(fn):
-    """Present the modern signature (``check_vma``) over whichever one
-    the installed jax has."""
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):
-        params = {}
-    has_vma = 'check_vma' in params
-    has_rep = 'check_rep' in params
-
-    @functools.wraps(fn)
-    def shard_map(f=None, *args, check_vma=None, check_rep=None, **kw):
-        flag = check_vma if check_vma is not None else check_rep
-        if flag is not None:
-            if has_vma:
-                kw['check_vma'] = flag
-            elif has_rep:
-                kw['check_rep'] = flag
-            # neither kwarg known: drop the flag (newer-than-known jax
-            # that removed it entirely — semantics default on)
-        if f is None:
-            # partial application (the decorator form with kwargs only)
-            return functools.partial(shard_map, *args, **kw)
-        return fn(f, *args, **kw)
-
-    return shard_map
-
-
-try:
-    shard_map = _wrap(_resolve())
-except Exception as exc:  # pragma: no cover - depends on installed jax
-    shard_map = None
-    SHARD_MAP_ERROR = '%s: %s' % (type(exc).__name__, exc)
+__all__ = ['multiprocess_cpu_missing']
 
 
 def multiprocess_cpu_missing():
@@ -98,16 +29,3 @@ def multiprocess_cpu_missing():
                 "computations aren't implemented on this CPU backend"
                 % getattr(jaxlib, '__version__', '?'))
     return None
-
-
-def require_shard_map():
-    """``shard_map`` or an ImportError naming why there is none — the
-    library-side entry (tests prefer checking ``shard_map is None`` and
-    skipping with :data:`SHARD_MAP_ERROR`)."""
-    if shard_map is None:
-        raise ImportError(
-            'shard_map is unavailable in this jax (%s); the shard_map-'
-            'based parallel legs (zero/ring/sp/moe/pipeline) need '
-            'jax.shard_map or jax.experimental.shard_map'
-            % SHARD_MAP_ERROR)
-    return shard_map

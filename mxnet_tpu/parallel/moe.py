@@ -113,8 +113,7 @@ def make_moe_ffn(mesh: Mesh, expert_axis: str = 'expert',
     experts and back via the two ``all_to_all`` exchanges — the ICI
     dispatch pattern.
     """
-    from .compat import require_shard_map
-    shard_map = require_shard_map()
+    from jax import shard_map
     n = mesh.shape[expert_axis]
 
     def fn(x, gate_w, up_w, down_w):

@@ -82,8 +82,7 @@ def make_pipeline(mesh: Mesh, axis: str, stage_fn):
         # the real output
         return bank[None]
 
-    from .compat import require_shard_map
-    shard_map = require_shard_map()
+    from jax import shard_map
     mapped = shard_map(
         spmd, mesh=mesh,
         in_specs=(P(axis), P()),
@@ -314,8 +313,7 @@ def make_pipeline_1f1b(mesh: Mesh, axis: str, stage_fn, loss_grad_fn):
             lambda g: g[None] / num_micro, grads)
         return mean_loss, grads_out
 
-    from .compat import require_shard_map
-    shard_map = require_shard_map()
+    from jax import shard_map
     return shard_map(spmd, mesh=mesh,
                      in_specs=(P(axis), P(), P()),
                      out_specs=(P(), P(axis)))

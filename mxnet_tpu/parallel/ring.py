@@ -106,8 +106,7 @@ def full_attention(q, k, v, causal=False):
 
 def make_ring_attention(mesh: Mesh, seq_axis: str = 'seq', causal=False):
     """Jitted sharded attention: inputs [B, H, T, D] sharded on T."""
-    from .compat import require_shard_map
-    shard_map = require_shard_map()
+    from jax import shard_map
 
     spec = P(None, None, seq_axis, None)
 
@@ -126,8 +125,7 @@ def make_ulysses_attention(mesh: Mesh, seq_axis: str = 'seq', causal=False):
     sharded axis from sequence to heads, runs full attention locally on
     H/N heads, and swaps back.  Complementary to ring attention — better
     when H >= N and the all-to-all fits ICI."""
-    from .compat import require_shard_map
-    shard_map = require_shard_map()
+    from jax import shard_map
 
     spec = P(None, None, seq_axis, None)
 

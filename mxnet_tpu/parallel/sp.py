@@ -139,8 +139,7 @@ def make_sp_train_step(symbol, mesh: Mesh, optimizer_update,
     _mapped_cache = {}
 
     def step(params, opt_state, batch, rng):
-        from .compat import require_shard_map
-        shard_map = require_shard_map()
+        from jax import shard_map
         # the shard_map wrapper depends only on the pytree KEY sets —
         # build it once per structure, not per batch
         cache_key = (tuple(sorted(params)), tuple(sorted(batch)))

@@ -198,8 +198,7 @@ def make_zero_train_step(symbol, mesh, axis_name, lr=0.05,
     reduction).  Moving-average aux states are pmean'd so replicas
     stay identical.
     """
-    from .compat import require_shard_map
-    shard_map = require_shard_map()
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from .train_step import make_fit_step, _PlainUpdate
 

@@ -72,6 +72,7 @@ import weakref
 from collections import deque
 
 from . import config, instrument
+from .base import MXNetError
 
 __all__ = [
     'enabled', 'set_enabled', 'refresh', 'activate_fit',
@@ -85,18 +86,17 @@ __all__ = [
     'note_fuse', 'fuse_cost_delta',
 ]
 
-# (peak bf16 TFLOP/s, peak HBM GB/s) per device kind; conservative
-# public numbers.  The CPU entry is a nominal host figure so MFU stays
-# defined (not meaningful) in CPU tests; unknown kinds fall back to
-# TPU v5 lite, matching the bench harness's historical behavior.
+# (peak bf16 FLOP/s, peak HBM bytes/s) per device kind; published
+# per-chip figures (TPU v5 lite: Google Cloud documentation, "TPU v5e").
+# A device kind that is not here is an error, not a default: an MFU
+# against another chip's peak is wrong, not approximate.  A CPU run
+# that wants an MFU pins the denominator with MXTPU_PEAK_FLOPS.
 PEAKS = {
     'TPU v5 lite': (197e12, 819e9),
     'TPU v5': (459e12, 1228e9),
     'TPU v4': (275e12, 1228e9),
     'TPU v6 lite': (918e12, 1640e9),
-    'cpu': (2e11, 1e11),
 }
-DEFAULT_PEAK_KEY = 'TPU v5 lite'
 
 _on = False
 _sample_n = 0
@@ -189,14 +189,12 @@ def activate_fit():
 
 def extract_cost(compiled):
     """``{'flops': F, 'bytes_accessed': B}`` from a compiled
-    executable's ``cost_analysis()`` (list- and dict-form tolerated;
-    zeros when the backend reports none).  The single implementation
+    executable's ``cost_analysis()`` (zeros when the backend reports
+    none).  The single implementation
     behind both the runtime gauges and ``bench.py``'s MFU line."""
     out = {'flops': 0.0, 'bytes_accessed': 0.0}
     try:
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
         out['flops'] = float(ca.get('flops', 0.0) or 0.0)
         out['bytes_accessed'] = float(ca.get('bytes accessed', 0.0) or 0.0)
     except Exception:
@@ -367,80 +365,51 @@ def clear_executables():
 # Leg 2a: MFU
 # ---------------------------------------------------------------------------
 
-_warned_fallback_peaks = False
+def device_kind():
+    """``device_kind`` of the first device of JAX's default backend."""
+    import jax
+    return jax.devices()[0].device_kind
 
 
-def _live_device_kind():
-    """``(jax_live, kind)`` of the attached device WITHOUT initializing
-    a backend — un-imported/uninitialized jax probes as (False, None),
-    a live CPU backend as (True, 'cpu').  The single probe behind
-    :func:`device_peaks` and ``commwatch.interconnect_bw``, so the two
-    peak tables resolve the device identically."""
-    import sys
-    if 'jax' not in sys.modules:
-        return False, None
-    try:
-        import jax
-        from jax._src import xla_bridge as _xb
-        if not getattr(_xb, '_backends', None):
-            return False, None
-        dev = jax.devices()[0]
-        return True, ('cpu' if dev.platform == 'cpu'
-                      else dev.device_kind)
-    except Exception:
-        return False, None
+def _table_row(table, kind):
+    """``table``'s row for device ``kind`` by prefix match, or None."""
+    for key, row in table.items():
+        if str(kind).startswith(key):
+            return row
+    return None
+
+
+def lookup_peak(table, kind, override_knob):
+    """``table``'s row for device ``kind``.  An unknown kind raises,
+    naming the knob that pins the figure — shared by this module's
+    :data:`PEAKS` and ``commwatch.ICI_PEAKS``."""
+    row = _table_row(table, kind)
+    if row is None:
+        raise MXNetError(
+            'device kind %r is not in the peak table (%s); set %s to pin '
+            'the figure' % (kind, ', '.join(sorted(table)), override_knob))
+    return row
 
 
 def device_peaks(kind=None):
-    """(peak flops/sec, peak HBM bytes/sec) for a device kind (probed
-    from the live backend when None).  Never initializes a backend by
-    itself — an un-imported/uninitialized jax yields the fallback.
-    Falling back with jax live warns ONCE: an MFU against the wrong
-    peak table must not be silently wrong (set MXTPU_PEAK_FLOPS to
-    pin the denominator explicitly)."""
-    global _warned_fallback_peaks
-    jax_live = False
-    if kind is None:
-        jax_live, kind = _live_device_kind()
-        if kind == 'cpu':
-            return PEAKS['cpu']
-    if kind:
-        for key, pk in PEAKS.items():
-            if str(kind).startswith(key):
-                return pk
-    if jax_live and not _warned_fallback_peaks:
-        _warned_fallback_peaks = True
-        import logging
-        logging.warning(
-            'mxtpu perfwatch: device kind %r not in the peak table — '
-            'perf.mfu/bench MFU use the %s fallback peaks; set '
-            'MXTPU_PEAK_FLOPS to override', kind, DEFAULT_PEAK_KEY)
-    return PEAKS[DEFAULT_PEAK_KEY]
+    """(peak flops/sec, peak HBM bytes/sec) of a device kind (the
+    attached device's when None).  An unknown kind raises."""
+    return lookup_peak(PEAKS, device_kind() if kind is None else kind,
+                       'MXTPU_PEAK_FLOPS')
 
 
 def peaks():
-    """Resolved (peak_flops, peak_bw), honoring the MXTPU_PEAK_FLOPS
-    override for the flops term.  Cached only once a LIVE backend
-    answered the probe — an early call before backend init must not
-    pin the fallback for the whole process."""
+    """Resolved (peak_flops, peak_bw).  ``MXTPU_PEAK_FLOPS`` pins the
+    flops term, and with it set a device the table lacks (the CPU
+    backend in tests) gets a zero bandwidth term instead of raising."""
     global _peaks
     override = float(config.get('MXTPU_PEAK_FLOPS'))
-    pk = _peaks
-    if pk is None:
-        import sys
-        live = False
-        if 'jax' in sys.modules:
-            try:
-                from jax._src import xla_bridge as _xb
-                live = bool(getattr(_xb, '_backends', None))
-            except Exception:
-                live = False
-        pk = device_peaks()
-        if live:
-            _peaks = pk
-    if override > 0:
-        return (override, pk[1])
-    return pk
+    if _peaks is None:
+        kind = device_kind()
+        if override > 0 and _table_row(PEAKS, kind) is None:
+            return (override, 0.0)
+        _peaks = device_peaks(kind)
+    return (override, _peaks[1]) if override > 0 else _peaks
 
 
 def peak_flops():

@@ -33,7 +33,7 @@ import numpy as np
 from . import ndarray as nd
 from . import symbol as sym_mod
 from .base import MXNetError
-from .context import Context, cpu
+from .context import Context, cpu, current_context
 from .ndarray import NDArray
 
 
@@ -57,7 +57,7 @@ class Predictor(object):
     """(MXPredCreate / MXPredCreatePartialOut analogue)"""
 
     def __init__(self, symbol_json_str, param_raw_bytes_or_dict,
-                 input_shapes, dev_type='cpu', dev_id=0,
+                 input_shapes, dev_type=None, dev_id=0,
                  output_keys=None, pad_to_bucket=False,
                  mesh=None, partition=None, devices=None):
         symbol = sym_mod.load_json(symbol_json_str) \
@@ -68,7 +68,10 @@ class Predictor(object):
                               k + '_output'] for k in output_keys]
             symbol = sym_mod.Group(outs)
         self._symbol = symbol
-        self._ctx = Context(dev_type, dev_id)
+        # no dev_type: the default context, i.e. the accelerator where
+        # one answers (context.current_context)
+        self._ctx = Context(dev_type or current_context().device_type,
+                            dev_id)
         self._plan = None
 
         if isinstance(param_raw_bytes_or_dict, (bytes, bytearray)):

@@ -50,6 +50,7 @@ import numpy as np
 from .. import config, instrument, resilience
 from .. import model as model_mod
 from ..base import MXNetError
+from ..context import current_context
 from ..predictor import Predictor
 from .batcher import (DeadlineExceededError, DynamicBatcher,
                       ReplicaQuarantinedError, ServerOverloadedError)
@@ -111,11 +112,13 @@ class ModelServer(object):
     """
 
     def __init__(self, max_delay_ms=None, max_batch=None, max_queue=None,
-                 dev_type='cpu', dev_id=0):
+                 dev_type=None, dev_id=0):
         self._max_delay_ms = max_delay_ms
         self._max_batch = max_batch
         self._max_queue = max_queue
-        self._dev = (dev_type, dev_id)
+        # no dev_type: the default context's, i.e. the accelerator where
+        # one answers (context.current_context)
+        self._dev = (dev_type or current_context().device_type, dev_id)
         self._models = {}
         self._lock = threading.Lock()
         self._closed = False

@@ -16,9 +16,9 @@ SO = os.path.join(ROOT, 'mxnet_tpu', 'libmxtpu_predict.so')
 
 
 def lib():
-    if not os.path.exists(SO):
-        subprocess.check_call(['make', 'predict'],
-                              cwd=os.path.join(ROOT, 'src'))
+    # always run make: its dependency tracking rebuilds a stale .so
+    subprocess.check_call(['make', '-s', 'predict'],
+                          cwd=os.path.join(ROOT, 'src'))
     L = ctypes.CDLL(SO)
     L.MXGetLastError.restype = ctypes.c_char_p
     return L

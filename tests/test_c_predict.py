@@ -18,13 +18,13 @@ SO_AMALG = os.path.join(ROOT, 'amalgamation',
 
 
 def build_lib(so=SO):
-    if not os.path.exists(so):
-        if so is SO_AMALG:
-            subprocess.check_call(['make'],
-                                  cwd=os.path.join(ROOT, 'amalgamation'))
-        else:
-            subprocess.check_call(['make', 'predict'],
-                                  cwd=os.path.join(ROOT, 'src'))
+    # always run make: its dependency tracking rebuilds a stale .so
+    if so is SO_AMALG:
+        subprocess.check_call(['make', '-s'],
+                              cwd=os.path.join(ROOT, 'amalgamation'))
+    else:
+        subprocess.check_call(['make', '-s', 'predict'],
+                              cwd=os.path.join(ROOT, 'src'))
     L = ctypes.CDLL(so)
     L.MXGetLastError.restype = ctypes.c_char_p
     L.MXPredCreate.argtypes = [

@@ -21,9 +21,9 @@ DRIVER_SRC = os.path.join(ROOT, 'tests', 'c', 'train_lenet.c')
 
 
 def build(tmp_path):
-    if not os.path.exists(SO):
-        subprocess.check_call(['make', 'predict'],
-                              cwd=os.path.join(ROOT, 'src'))
+    # always run make: its dependency tracking rebuilds a stale .so
+    subprocess.check_call(['make', '-s', 'predict'],
+                          cwd=os.path.join(ROOT, 'src'))
     exe = str(tmp_path / 'train_lenet')
     subprocess.check_call(
         ['gcc', '-O1', '-Wall', '-Werror', DRIVER_SRC, '-o', exe,

@@ -4,10 +4,8 @@ ring bound, torn-tail tolerance), the query API's window math, the
 shared online detectors (no-flap on noise, level fire+clear, leak
 slope), the anomaly -> decision -> postmortem path, the unified
 decision-event API and timeline renderer, the off-by-default
-zero-surface contract, render_prometheus timestamps, the check_perf
-device_blind skip, bench.py's blind marker lifecycle, and
-check_trace's decision-lane validation."""
-import importlib.util
+zero-surface contract, render_prometheus timestamps, and check_trace's
+decision-lane validation."""
 import json
 import os
 import subprocess
@@ -22,7 +20,6 @@ from mxnet_tpu import chronicle, detector, instrument
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, 'tools'))
 import timeline  # noqa: E402
-import check_perf  # noqa: E402
 import check_trace  # noqa: E402
 
 TIMELINE = os.path.join(REPO, 'tools', 'timeline.py')
@@ -505,8 +502,7 @@ def test_start_implies_metrics_and_stop_detaches(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Satellites: prometheus timestamps, check_perf blind skip, bench
-# markers, check_trace decision lanes
+# Satellites: prometheus timestamps, check_trace decision lanes
 # ---------------------------------------------------------------------------
 
 def test_render_prometheus_timestamps():
@@ -524,51 +520,6 @@ def test_render_prometheus_timestamps():
     sample = [ln for ln in live.splitlines()
               if ln.startswith('mxtpu_app_reqs_total')][0]
     assert abs(int(sample.split()[-1]) - time.time() * 1000) < 60000
-
-
-def test_check_perf_skips_device_blind_legs(tmp_path):
-    base = tmp_path / 'base.json'
-    cur = tmp_path / 'cur.json'
-    base.write_text(json.dumps({
-        'train': {'value': 2000.0},
-        'gone_blind': {'value': 9.9, 'device_blind': True}}))
-    cur.write_text(json.dumps({
-        'device_blind': True, 'train': {'value': 1.0}}))
-    rows, regressions, missing = check_perf.compare(
-        check_perf.load_legs(str(base)), check_perf.load_legs(str(cur)),
-        require_all=True)
-    # a 2000 -> 1.0 cliff is NOT a regression when the round was blind,
-    # and a blind baseline leg missing from current is not one either
-    assert not regressions and not missing
-    assert {r[4] for r in rows} == {'blind'}
-    # the one-line primary form carries the marker too
-    cur.write_text(json.dumps({'metric': 'train', 'value': 1.0,
-                               'device_blind': True}))
-    legs = check_perf.load_legs(str(cur))
-    assert legs['train']['device_blind'] is True
-
-
-@pytest.fixture
-def bench(tmp_path, monkeypatch):
-    spec = importlib.util.spec_from_file_location(
-        'bench_under_chronicle_test', os.path.join(REPO, 'bench.py'))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    monkeypatch.setattr(mod, 'STATE_PATH',
-                        str(tmp_path / 'bench_state.json'))
-    return mod
-
-
-def test_bench_device_blind_marker_lifecycle(bench):
-    bench.record_leg('train', 2000.0)
-    out = bench.mark_device_blind({'metric': 'train', 'value': 2000.0})
-    assert out['device_blind'] is True
-    assert 'device_blind' in bench.load_state()   # persisted for tools
-    # the next FRESH measurement clears the marker, even a worse one
-    bench.record_leg('train', 1500.0)
-    state = bench.load_state()
-    assert 'device_blind' not in state
-    assert state['train']['value'] == 2000.0      # best still kept
 
 
 def test_check_trace_validates_decision_lanes():

@@ -122,8 +122,11 @@ def test_comm_fraction_bounds(monkeypatch):
     monkeypatch.delenv('MXTPU_PEAK_BW')
     assert commwatch.interconnect_bw('TPU v4 pod chip') == \
         commwatch.ICI_PEAKS['TPU v4']
-    assert commwatch.interconnect_bw('weird-accelerator') == \
-        commwatch.ICI_PEAKS[perfwatch.DEFAULT_PEAK_KEY]
+    # a kind the table lacks (the CPU backend included) is an error
+    with pytest.raises(mx.MXNetError, match='weird-accelerator'):
+        commwatch.interconnect_bw('weird-accelerator')
+    with pytest.raises(mx.MXNetError, match='MXTPU_PEAK_BW'):
+        commwatch.interconnect_bw()
 
 
 def test_analyze_executable_gauges():
@@ -310,18 +313,6 @@ def test_plan_records_idempotent_across_rebuilds():
     plan.param_sharding('h', (8, 16))
     assert plan.records['h']['shard_bytes'] == b16
     assert plan.records['h']['dtype'] == 'float16'
-
-
-def test_interconnect_fallback_warns_once(monkeypatch, caplog):
-    monkeypatch.setattr(perfwatch, '_live_device_kind',
-                        lambda: (True, 'weird-fabric'))
-    monkeypatch.setattr(commwatch, '_warned_fallback_bw', False)
-    with caplog.at_level(logging.WARNING):
-        bw = commwatch.interconnect_bw()
-        commwatch.interconnect_bw()
-    assert bw == commwatch.ICI_PEAKS[perfwatch.DEFAULT_PEAK_KEY]
-    warns = [r for r in caplog.records if 'weird-fabric' in r.getMessage()]
-    assert len(warns) == 1
 
 
 def test_records_for_shapes_matches_live_rules():

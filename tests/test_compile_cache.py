@@ -386,7 +386,56 @@ def test_imperative_cache_counters():
 # Knobs off: nothing installed, off path allocation-free
 # ---------------------------------------------------------------------------
 
+def test_cache_dir_rule(monkeypatch):
+    """One function decides where compiled programs persist:
+    JAX_COMPILATION_CACHE_DIR, else MXTPU_COMPILE_CACHE, else (run
+    scripts only) the fixed <checkout>/.jax_cache — never a directory
+    made from tempfile, a pid or a time, which could not hit twice."""
+    monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
+    monkeypatch.delenv('MXTPU_COMPILE_CACHE', raising=False)
+    assert compile_cache.resolve_cache_dir() == (None, False)
+    assert compile_cache.resolve_cache_dir(checkout_default=True) == \
+        (os.path.join(REPO, '.jax_cache'), False)
+    monkeypatch.setenv('MXTPU_COMPILE_CACHE', '/m')
+    assert compile_cache.resolve_cache_dir(checkout_default=True) == \
+        ('/m', False)
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', '/x')
+    assert compile_cache.resolve_cache_dir(checkout_default=True) == \
+        ('/x', True)
+
+
+def test_env_cache_dir_wins_and_jax_config_is_left_alone(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set JAX keeps its own setting: no
+    code calls jax.config.update('jax_compilation_cache_dir', ...), the
+    cache and the manifest both land in that directory, and a run
+    script's checkout default is not consulted."""
+    code = (
+        "import os, jax\n"
+        "calls, real = [], jax.config.update\n"
+        "jax.config.update = lambda k, v: (calls.append(k), "
+        "real(k, v))[1]\n"
+        "import jax.numpy as jnp\n"
+        "from mxnet_tpu import compile_cache\n"
+        "d = compile_cache.ensure_persistent_cache("
+        "checkout_default=True)\n"
+        "assert d == os.environ['JAX_COMPILATION_CACHE_DIR'], d\n"
+        "assert compile_cache.manifest_path().startswith(d)\n"
+        "assert 'jax_compilation_cache_dir' not in calls, calls\n"
+        "jax.jit(lambda x: x * 2 + 1)(jnp.ones(8)).block_until_ready()\n"
+        "print('CACHE-RULE-OK')\n")
+    cache = tmp_path / 'x'
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(cache),
+               MXTPU_COMPILE_CACHE=str(tmp_path / 'loses'),
+               JAX_PLATFORMS='cpu')
+    proc = subprocess.run([sys.executable, '-c', code], env=env, cwd=REPO,
+                          capture_output=True, text=True, timeout=120)
+    assert 'CACHE-RULE-OK' in proc.stdout, proc.stderr[-1500:]
+    assert os.listdir(str(cache)), 'nothing was cached in the env dir'
+    assert not (tmp_path / 'loses').exists()
+
+
 def test_knobs_off_nothing_installed():
+    assert not os.environ.get('JAX_COMPILATION_CACHE_DIR')
     assert not os.environ.get('MXTPU_COMPILE_CACHE')
     assert compile_cache.ensure_persistent_cache() is None
     assert compile_cache.cache_dir() is None

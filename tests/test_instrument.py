@@ -167,6 +167,23 @@ def test_profiler_run_stop_restores_flags(tmp_path):
     assert not instrument.metrics_enabled()
 
 
+def test_profiler_start_failure_propagates(tmp_path, monkeypatch):
+    """profiler_set_state('run') asks for a device trace: when
+    jax.profiler.start_trace fails the caller hears of it, and no
+    half-started run is left behind."""
+    import jax
+
+    def refuse(log_dir):
+        raise RuntimeError('trace refused: %s' % log_dir)
+
+    monkeypatch.setattr(jax.profiler, 'start_trace', refuse)
+    profiler.profiler_set_config(filename=str(tmp_path / 'p.json'))
+    with pytest.raises(RuntimeError, match='trace refused'):
+        profiler.profiler_set_state('run')
+    assert not instrument.profiling_enabled()
+    profiler.profiler_set_state('stop')      # nothing is running: a no-op
+
+
 # ---------------------------------------------------------------------------
 # Metrics
 # ---------------------------------------------------------------------------

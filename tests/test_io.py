@@ -133,3 +133,33 @@ def test_prefetch_multi_iter_error_aborts_epoch():
     assert not it.iter_next()     # epoch aborted
     it.reset()                    # realigns both streams
     assert it.iter_next()
+
+
+def test_prefetching_iter_released_on_engine_worker():
+    """The fetch closure holds the iterator, so the last reference can
+    die on the engine's own worker thread when the in-flight fetch
+    returns; __del__ there must not wait on the var whose op is the one
+    running (it deadlocked the process at exit)."""
+    import threading
+    from mxnet_tpu.io import DataIter, PrefetchingIter
+
+    entered, gate = threading.Event(), threading.Event()
+
+    class Slow(DataIter):
+        provide_data = [('data', (2, 2))]
+        provide_label = []
+
+        def next(self):
+            entered.set()
+            gate.wait(30)
+            raise StopIteration
+
+    it = PrefetchingIter(Slow())          # pushes the first fetch at once
+    assert entered.wait(30)
+    engine = it._engine
+    del it                                # the running fetch holds the last ref
+    gate.set()
+    waiter = threading.Thread(target=engine.wait_for_all, daemon=True)
+    waiter.start()
+    waiter.join(30)
+    assert not waiter.is_alive(), 'the engine worker deadlocked in __del__'

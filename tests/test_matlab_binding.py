@@ -29,9 +29,9 @@ MODEL_M = os.path.join(ROOT, 'matlab', '+mxnet', 'model.m')
 
 
 def build_lib():
-    if not os.path.exists(SO):
-        subprocess.check_call(['make', 'predict'],
-                              cwd=os.path.join(ROOT, 'src'))
+    # always run make: its dependency tracking rebuilds a stale .so
+    subprocess.check_call(['make', '-s', 'predict'],
+                          cwd=os.path.join(ROOT, 'src'))
     L = ctypes.CDLL(SO)
     L.MXGetLastError.restype = ctypes.c_char_p
     return L

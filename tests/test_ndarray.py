@@ -101,6 +101,41 @@ def test_broadcast():
     assert np.allclose(z.asnumpy(), x.asnumpy() + y.asnumpy())
 
 
+def test_default_context_follows_the_backend():
+    """With no context set the default follows JAX's default backend
+    (docs/deviations.md): cpu(0) in this CPU-pinned process, and
+    whatever a ``with`` block names inside it."""
+    assert mx.current_context() == mx.cpu(0)
+    assert mx.nd.zeros((2,)).context == mx.cpu(0)
+    with mx.tpu(1):
+        assert mx.current_context() == mx.tpu(1)
+    assert mx.current_context() == mx.cpu(0)
+
+
+def test_tpu_context_resolution():
+    """tpu(i) is device i of the default backend, which is the CPU only
+    because this process is pinned to it; an id past the device count
+    is an error, never a wrapped id."""
+    import jax
+    devs = jax.local_devices()
+    assert mx.tpu(len(devs) - 1).jax_device == devs[-1]
+    with pytest.raises(mx.MXNetError, match='out of range'):
+        mx.tpu(len(devs)).jax_device
+    with pytest.raises(mx.MXNetError, match='out of range'):
+        mx.nd.zeros((2,), ctx=mx.tpu(len(devs)))
+    # cpu ids name the same host, as in the reference
+    assert mx.cpu(len(devs)).jax_device == devs[0]
+
+
+def test_tpu_context_needs_an_accelerator_unless_pinned(monkeypatch):
+    """Where JAX fell back to the CPU by itself (no accelerator found,
+    platform not pinned), tpu() raises instead of using the host."""
+    from mxnet_tpu import context
+    monkeypatch.setattr(context, '_pinned_to_cpu', lambda: False)
+    with pytest.raises(mx.MXNetError, match='no accelerator'):
+        mx.tpu(0).jax_device
+
+
 def test_copyto_context():
     a = nd.ones((2, 2), ctx=mx.cpu())
     b = a.copyto(mx.tpu(0))

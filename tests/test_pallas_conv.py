@@ -68,10 +68,6 @@ def test_custom_vjp_matches_autodiff(stride):
                                    rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.skipif(
-    not __import__('mxnet_tpu.ops.pallas_conv',
-                   fromlist=['_HAS_PLTPU'])._HAS_PLTPU,
-    reason='pltpu absent: _dispatch always takes the reference path')
 def test_stride2_odd_dims_dispatch_to_xla(monkeypatch):
     """The reshape-factored stride-2 taps need even h/w; odd spatial
     dims must take the reference expression, even ones the kernel."""
@@ -82,11 +78,7 @@ def test_stride2_odd_dims_dispatch_to_xla(monkeypatch):
 
     monkeypatch.setattr(pc.jax, 'devices', lambda: [_FakeTpu()])
     monkeypatch.delenv('MXTPU_FORCE_PALLAS_INTERPRET', raising=False)
-    # dispatch SELECTION is under test (the kernel is stubbed below):
-    # neutralize the Mosaic capability degrade so kernel mode survives
-    # on installs whose pallas.tpu lacks CompilerParams
-    from mxnet_tpu.ops import _caps
-    monkeypatch.setattr(_caps, 'mosaic_degraded', lambda: False)
+    # dispatch SELECTION is under test (the kernel is stubbed below)
     monkeypatch.setattr(
         pc, '_pallas_conv',
         lambda *a, **k: (_ for _ in ()).throw(

@@ -135,25 +135,6 @@ def test_bn_relu_odd_shapes_fall_back(monkeypatch):
         rtol=2e-5, atol=2e-5)
 
 
-def test_bn_relu_degrades_warn_once_not_error(monkeypatch):
-    """A Mosaic missing the required attrs must degrade the kernel to
-    the jnp form (the warn-once contract), not raise — pinned by
-    forcing the capability probe to 'degraded' in kernel mode."""
-    import jax.numpy as jnp
-    from mxnet_tpu.ops import pallas_fused as pf
-    from mxnet_tpu.ops import _caps
-    monkeypatch.setenv('MXTPU_ASSUME_TPU', '1')   # kernel mode on CPU
-    monkeypatch.setattr(_caps, 'mosaic_degraded', lambda: True)
-    rng = np.random.RandomState(8)
-    x = jnp.asarray(rng.randn(2, 32, 4, 4).astype(np.float32))
-    s = jnp.asarray((rng.rand(32) + 0.5).astype(np.float32))
-    b = jnp.asarray(rng.randn(32).astype(np.float32))
-    out = np.asarray(pf.fused_bn_relu(x, s, b))   # must not raise
-    np.testing.assert_allclose(
-        out, np.asarray(pf._bn_relu_reference(x, s, b)),
-        rtol=2e-5, atol=2e-5)
-
-
 def test_dot_epilogue_interpret_matches_reference(monkeypatch):
     import jax.numpy as jnp
     from mxnet_tpu.ops import pallas_fused as pf

@@ -91,17 +91,19 @@ def test_mfu_math_and_peak_override(monkeypatch):
     assert perfwatch.mfu(1e12, 0.0, peak=197e12) == 0.0
     assert perfwatch.roofline_mandatory(1e9, 2.0, peak_bw=819e9) == \
         pytest.approx(2e9 / 819e9)
-    # device-kind table: prefix match + fallback
+    # device-kind table: prefix match; a kind it lacks is an error
     assert perfwatch.device_peaks('TPU v5 lite chip') == \
         perfwatch.PEAKS['TPU v5 lite']
-    assert perfwatch.device_peaks('weird-accelerator') == \
-        perfwatch.PEAKS[perfwatch.DEFAULT_PEAK_KEY]
+    with pytest.raises(mx.MXNetError, match='weird-accelerator'):
+        perfwatch.device_peaks('weird-accelerator')
     # the MXTPU_PEAK_FLOPS override replaces the flops term only
     monkeypatch.setenv('MXTPU_PEAK_FLOPS', '5e12')
     assert perfwatch.peaks()[0] == 5e12
     assert perfwatch.mfu(1e12, 1.0) == pytest.approx(0.2)
+    # without it the CPU backend has no row: no made-up host peak
     monkeypatch.delenv('MXTPU_PEAK_FLOPS')
-    assert perfwatch.peaks()[0] != 5e12
+    with pytest.raises(mx.MXNetError, match='MXTPU_PEAK_FLOPS'):
+        perfwatch.peaks()
 
 
 # ---------------------------------------------------------------------------

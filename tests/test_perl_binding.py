@@ -20,9 +20,9 @@ pytestmark = pytest.mark.skipif(perl is None,
 
 
 def build():
-    if not os.path.exists(SO):
-        subprocess.check_call(['make', 'predict'],
-                              cwd=os.path.join(ROOT, 'src'))
+    # always run make: its dependency tracking rebuilds a stale .so
+    subprocess.check_call(['make', '-s', 'predict'],
+                          cwd=os.path.join(ROOT, 'src'))
     if not os.path.exists(os.path.join(PKG, 'Makefile')):
         subprocess.check_call([perl, 'Makefile.PL'], cwd=PKG,
                               stdout=subprocess.DEVNULL)

@@ -18,8 +18,8 @@ SO = os.path.join(AMALG, 'libmxtpu_predict_lite.so')
 
 
 def build_lib():
-    if not os.path.exists(SO):
-        subprocess.check_call(['make', 'lite'], cwd=AMALG)
+    # always run make: its dependency tracking rebuilds a stale .so
+    subprocess.check_call(['make', '-s', 'lite'], cwd=AMALG)
     L = ctypes.CDLL(SO)
     L.MXGetLastError.restype = ctypes.c_char_p
     return L

@@ -62,9 +62,7 @@ def test_ring_attention_grad():
     q, k, v = _setup(B=1, H=2, T=16, D=4)
     mesh = _mesh(4)
     from functools import partial
-    from mxnet_tpu.parallel.compat import shard_map, SHARD_MAP_ERROR
-    if shard_map is None:
-        pytest.skip('shard_map unavailable: %s' % SHARD_MAP_ERROR)
+    from jax import shard_map
     from mxnet_tpu.parallel.ring import ring_attention
     spec = P(None, None, 'seq', None)
 

@@ -41,9 +41,7 @@ def test_state_is_sharded():
 
 
 def test_matches_replicated_update(mesh):
-    from mxnet_tpu.parallel.compat import shard_map, SHARD_MAP_ERROR
-    if shard_map is None:
-        pytest.skip('shard_map unavailable: %s' % SHARD_MAP_ERROR)
+    from jax import shard_map
     params = _params()
     rng = np.random.RandomState(1)
     # per-device gradients (dp-sharded leading axis)
@@ -79,9 +77,7 @@ def test_matches_replicated_update(mesh):
 
 
 def test_two_steps_momentum_carries(mesh):
-    from mxnet_tpu.parallel.compat import shard_map, SHARD_MAP_ERROR
-    if shard_map is None:
-        pytest.skip('shard_map unavailable: %s' % SHARD_MAP_ERROR)
+    from jax import shard_map
     params = _params()
     rng = np.random.RandomState(2)
     g1 = {k: jnp.asarray(rng.randn(N, *v.shape).astype(np.float32))

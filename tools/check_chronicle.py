@@ -244,6 +244,10 @@ def main(argv=None):
             'MXTPU_CHRONICLE': jdir,
             'MXTPU_CHRONICLE_EVERY_MS': str(EVERY_MS),
             'MXTPU_PERFWATCH': '1',
+            # the peak table holds real chips only: a nominal figure
+            # keeps perf.mfu defined on the CPU backend
+            'MXTPU_PEAK_FLOPS': os.environ.get('MXTPU_PEAK_FLOPS',
+                                               '2e11'),
             'MXTPU_IOWATCH': '1',
         }, timeout=600)
         t_inj = fit.get('t_inj')

@@ -148,6 +148,10 @@ def _run_child(mode, cache_dir=None, warm=False, perfwatch=True,
         env['XLA_FLAGS'] = \
             flags + ' --xla_force_host_platform_device_count=8'
     env['JAX_PLATFORMS'] = 'cpu'
+    # the peak tables hold real chips only: nominal figures keep
+    # perf.mfu / perf.comm_fraction defined on the virtual CPU devices
+    env.setdefault('MXTPU_PEAK_FLOPS', '2e11')
+    env.setdefault('MXTPU_PEAK_BW', '1e10')
     env['MXTPU_METRICS'] = '1'
     env['MXTPU_PERFWATCH'] = '1' if perfwatch else '0'
     env['MXTPU_COMMWATCH'] = '1' if commwatch else '0'

@@ -1,8 +1,6 @@
 #!/usr/bin/env python
 """Perf-regression gate: compare two bench result files leg by leg and
-exit nonzero when the current round regressed past tolerance — the
-missing guard behind the ROADMAP's "trajectory is blind" problem (BENCH
-r03-r05 produced no comparable datapoint and nothing noticed).
+exit nonzero when the current round regressed past tolerance.
 
 Usage::
 
@@ -37,10 +35,7 @@ serving supervisor's quarantine->replacement repair, off
 lower-is-better 50% + 2s treatment for the same reason: the figure is
 mostly the supervisor's detection interval plus scheduler jitter.
 Legs present only in the baseline are warnings unless
-``--require-all``.  Legs carrying ``device_blind`` (bench.py's
-wedged-probe fallback stamped the file: the values are persisted
-history, not this round's measurement) are SKIPPED, never compared —
-stale numbers can neither pass nor fail a gate honestly.
+``--require-all``.
 
 Run by ``tests/test_perfwatch.py`` as a self-comparison smoke so the
 gate itself stays exercised under tier-1.
@@ -112,19 +107,10 @@ def load_legs(path):
         doc = json.load(f)
     if not isinstance(doc, dict):
         raise ValueError('%s: not a JSON object' % path)
-    # a file-level device_blind marker (bench.py's wedged-probe
-    # fallback) means every leg it carries is a stale persisted value,
-    # not this round's measurement — mark them all
-    doc_blind = bool(doc.get('device_blind'))
     if 'metric' in doc and 'value' in doc:
-        fields = {'value': float(doc['value'])}
-        if doc_blind:
-            fields['device_blind'] = True
-        return {str(doc['metric']): fields}
+        return {str(doc['metric']): {'value': float(doc['value'])}}
     legs = {}
     for leg, entry in doc.items():
-        if leg == 'device_blind':
-            continue                       # the marker, not a leg
         if isinstance(entry, (int, float)):
             legs[str(leg)] = {'value': float(entry)}
         elif isinstance(entry, dict) and 'value' in entry:
@@ -135,13 +121,7 @@ def load_legs(path):
                 v = entry.get(k)
                 if isinstance(v, (int, float)):
                     fields[k] = float(v)
-            if doc_blind or entry.get('device_blind'):
-                fields['device_blind'] = True
             legs[str(leg)] = fields
-        else:
-            continue
-        if doc_blind and 'device_blind' not in legs[str(leg)]:
-            legs[str(leg)]['device_blind'] = True
     return legs
 
 
@@ -166,24 +146,11 @@ def compare(base_legs, cur_legs, tol=DEFAULT_TOL, leg_tol=None,
     rows, regressions, missing = [], [], []
     for leg in sorted(base_legs):
         if leg not in cur_legs:
-            if base_legs[leg].get('device_blind'):
-                # a blind baseline leg carries no gating claim — its
-                # absence from current is not a regression either
-                rows.append((leg, 'value', base_legs[leg].get('value'),
-                             None, 'blind'))
-                continue
             missing.append(leg)
             rows.append((leg, 'value', base_legs[leg].get('value'),
                          None, 'missing'))
             continue
         base, cur = base_legs[leg], cur_legs[leg]
-        if base.get('device_blind') or cur.get('device_blind'):
-            # a blind side is stale persisted evidence from a wedged
-            # device probe: SKIP the leg — neither a pass nor a
-            # regression can honestly be claimed from it
-            rows.append((leg, 'value', base.get('value'),
-                         cur.get('value'), 'blind'))
-            continue
         for field in sorted(base):
             if field not in cur:
                 continue
