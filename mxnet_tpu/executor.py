@@ -80,7 +80,12 @@ def _build_graph_fn(symbol: Symbol, is_train: bool, monitor_re=None,
             op = node.opdef()
             ins = [entry_vals[(id(n), x)] for n, x in node.inputs]
             node_rng = jax.random.fold_in(rng, i) if op.takes_rng else rng
-            outs, aux_upd = op.apply(node.attrs, ins, is_train, node_rng)
+            # operator and node name into every op's op_name metadata,
+            # forward and (as transpose(jvp(...))) backward; costs only
+            # while JAX traces and changes no HLO instruction
+            with jax.named_scope('%s/%s' % (node.op, node.name)):
+                outs, aux_upd = op.apply(node.attrs, ins, is_train,
+                                         node_rng)
             for j, o in enumerate(outs):
                 entry_vals[(id(node), j)] = o
             if monitor_re is not None:

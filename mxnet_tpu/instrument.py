@@ -177,6 +177,9 @@ class _NullSpan(object):
     def __enter__(self):
         return self
 
+    def cancel(self):
+        pass
+
     def __exit__(self, *exc):
         return False
 
@@ -683,38 +686,77 @@ class HistogramWindow(object):
         return hist_merge([self.delta(n) for n in names])
 
 
-class _HistSpan(object):
-    """One timed region that lands in BOTH a latency histogram and —
-    under profiling — a trace span, off a single ``time_ns`` read per
-    edge.  This is the shared phase clock of the attribution planes
-    (``perf.phase.*``, ``iowatch.stage.*``): one clock for histogram
-    and span means a phase event can never stick out of its enclosing
-    step span by clock skew (``tools/check_trace.py`` validates the
-    nesting)."""
-    __slots__ = ('name', 'cat', '_t0')
+_jax_profiler = None
 
-    def __init__(self, name, cat):
+
+def _profiler_annotation(name, step_num):
+    """The profiler-side sink of one :class:`_HistSpan`: a
+    ``jax.profiler`` annotation named ``mxtpu.`` + ``name``.  While a
+    ``jax.profiler`` session runs it lands on the ``/host:CPU`` plane of
+    the ``.xplane.pb``, on the clock of the device planes and on the
+    line of the calling thread; without a session a ``TraceMe`` is a
+    flag test.  The one prefix lets a reader pick the program's spans.
+    jax is imported on first use, so a process with every plane off
+    never pays for it here."""
+    global _jax_profiler
+    if _jax_profiler is None:
+        import jax.profiler as _jax_profiler
+    if step_num is None:
+        return _jax_profiler.TraceAnnotation('mxtpu.' + name)
+    return _jax_profiler.StepTraceAnnotation('mxtpu.' + name,
+                                             step_num=step_num)
+
+
+class _HistSpan(object):
+    """One timed region with three sinks: a latency histogram, a
+    ``jax.profiler`` annotation (:func:`_profiler_annotation`) and —
+    under profiling — a Chrome trace span.  Histogram and Chrome span
+    come off a single ``time_ns`` read per edge: this is the shared
+    phase clock of the attribution planes (``perf.fit_step``,
+    ``perf.phase.*``, ``iowatch.stage.*``), so a phase event can never
+    stick out of its enclosing step span by clock skew
+    (``tools/check_trace.py`` validates the nesting).  The annotation
+    is entered first and left last, so on the profiler's clock too a
+    child lies inside its parent."""
+    __slots__ = ('name', 'cat', '_t0', '_annotation')
+
+    def __init__(self, name, cat, step_num=None):
         self.name = name
         self.cat = cat
+        self._annotation = _profiler_annotation(name, step_num)
 
     def __enter__(self):
+        self._annotation.__enter__()
         self._t0 = time.time_ns()
         return self
 
+    def cancel(self):
+        """The region ended without the work it was opened for (the fit
+        loop asked an exhausted iterator for a batch): no histogram
+        sample and no Chrome span, so counts stay counts of work.  An
+        annotation once entered cannot be withdrawn from a profiler's
+        trace; it stays there marked ``cancelled=1``."""
+        self._t0 = None
+        self._annotation.set_metadata(cancelled=1)
+
     def __exit__(self, *exc):
-        dt = time.time_ns() - self._t0
-        observe_hist(self.name, dt / 1e9)
-        if _profile_on:
-            record_complete(self.name, self._t0 // 1000,
-                            max(dt, 0) // 1000, cat=self.cat)
+        if self._t0 is not None:
+            dt = time.time_ns() - self._t0
+            observe_hist(self.name, dt / 1e9)
+            if _profile_on:
+                record_complete(self.name, self._t0 // 1000,
+                                max(dt, 0) // 1000, cat=self.cat)
+        self._annotation.__exit__(*exc)
         return False
 
 
-def hist_span(name, cat='phase'):
+def hist_span(name, cat='phase', step_num=None):
     """Histogram+span region factory (see :class:`_HistSpan`).  NOT
     flag-gated itself — callers (perfwatch.phase, iowatch.stage) check
-    their own plane's enable flag and return a shared no-op when off."""
-    return _HistSpan(name, cat)
+    their own plane's enable flag and return a shared no-op when off.
+    ``step_num`` makes the profiler annotation a step annotation that
+    carries the number (the root of a fit iteration)."""
+    return _HistSpan(name, cat, step_num)
 
 
 class _TimedCtx(object):

@@ -245,11 +245,16 @@ class DeviceFeedIter(DataIter):
         return self.data_iter.provide_label
 
     def _fetch(self):
+        # on the feed thread, one batch ahead of the step that consumes
+        # it, so parentless: the inner iterator's work, then the host's
+        # share of the copy (conversion, linearising, issuing — the
+        # transfer's own time is on the runtime's lines of a trace)
         try:
-            batch = self.data_iter.next()
+            with _perfwatch.phase('feed_fetch'):
+                batch = self.data_iter.next()
         except StopIteration:
             return None
-        with instrument.span('io.device_feed_stage', cat='io'):
+        with _perfwatch.phase('feed_stage'):
             return _place_batch(batch, self._place_data,
                                 self._place_label)
 

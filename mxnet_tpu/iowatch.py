@@ -163,8 +163,10 @@ _NULL = instrument.NULL_CTX
 
 def stage(name):
     """Attribute the wrapped region's wall time to pipeline stage
-    ``name`` (``iowatch.stage.<name>`` histogram; a trace span too under
-    profiling — :func:`instrument.hist_span`, the same clock the
+    ``name`` (``iowatch.stage.<name>`` histogram; an
+    ``mxtpu.iowatch.stage.<name>`` annotation in a running
+    ``jax.profiler`` trace; a Chrome span too under profiling —
+    :func:`instrument.hist_span`, the same seam and clock the
     ``perf.phase.*`` spans use).  The shared no-op when the plane is
     off."""
     if not _on:

@@ -447,111 +447,142 @@ class BaseModule(object):
                          eval_batch_end_callback, monitor, begin_epoch,
                          num_epoch, checkpoint_prefix, checkpoint_period,
                          window):
+        fit_steps = 0       # iterations so far: the roots' step number
         for epoch in range(begin_epoch, num_epoch):
             tic = time.time()
             eval_metric.reset()
             nsamples = 0
             with instrument.span('fit.epoch[%d]' % epoch, cat='fit'):
-                for nbatch, data_batch in enumerate(train_data):
-                    # elastic actuation point (one global None check
-                    # when off): raises on a coordinated abort or a
-                    # fenced identity; blocks for the repair
-                    # rendezvous — charged to the goodput ledger's
-                    # 'recovery' bucket — when a rank was evicted
-                    _elastic.step_check(self, epoch)
-                    if monitor is not None:
-                        monitor.tic()
-                    # MXTPU_STEP_SAMPLE: every Nth step fully syncs
-                    # after dispatch for an honest device-step latency
-                    # (perf.step_latency) — exactly ceil(nbatch/N)
-                    # extra syncs per epoch, none on unsampled steps
-                    sampled = _perfwatch.sample_tick()
-                    if sampled:
-                        _samp_t0 = time.perf_counter()
-                        _samp_ts = time.time_ns() // 1000
-                    # a step that TRACED (cold jit — fused or fallback
-                    # — or a shape-driven retrace) spent its wall time
-                    # compiling, not training: the goodput ledger
-                    # reattributes it to the 'compile' bucket, minus
-                    # whatever nested account() regions (warmup waits,
-                    # the perfwatch AOT capture) already claimed.  Two
-                    # counter reads when nothing traced.
-                    with instrument.span('fit.batch', cat='fit'), \
-                            instrument.timed('fit.step'), \
-                            _iowatch.traced_dispatch():
-                        metric_on_device = self._fit_step(data_batch,
-                                                          eval_metric)
-                    window.admit(self._step_ticket())
-                    if sampled:
-                        # a deliberate measurement drain — same goodput
-                        # bucket as the metric drains, so the
-                        # exclusive-bucket invariant stays checkable
-                        # against perf.host_syncs
-                        with _iowatch.account('metric_drain'):
-                            _perfwatch.sample_sync(self._step_ticket(),
-                                                   _samp_t0, _samp_ts)
-                    if instrument.metrics_enabled():
-                        bs = data_batch.data[0].shape[0] if data_batch.data \
-                            else getattr(train_data, 'batch_size', 0)
-                        # pad rows are replicated filler, not samples
-                        bs -= getattr(data_batch, 'pad', 0) or 0
-                        nsamples += bs
-                        instrument.inc('fit.batches')
-                        instrument.inc('fit.samples', bs)
-                    if not metric_on_device:
-                        self.update_metric(eval_metric, data_batch.label)
-                    if monitor is not None:
-                        monitor.toc_print()
-                    if batch_end_callback is not None:
-                        batch_end_params = BatchEndParam(
-                            epoch=epoch, nbatch=nbatch,
-                            eval_metric=eval_metric, locals=locals())
-                        for callback in _as_list(batch_end_callback):
-                            callback(batch_end_params)
+                # one perf.fit_step root per iteration, from ASKING for
+                # the batch to the last batch-end callback's return, so
+                # consecutive roots tile the fit thread's time inside
+                # the epoch (the ask that finds the iterator exhausted
+                # is cancelled: the root's count is fit.batches')
+                batches = iter(train_data)
+                nbatch = 0
+                while True:
+                    with _perfwatch.fit_step(fit_steps) as root:
+                        data_batch = next(batches, None)
+                        if data_batch is None:
+                            root.cancel()
+                            break
+                        # elastic actuation point (one global None check
+                        # when off): raises on a coordinated abort or a
+                        # fenced identity; blocks for the repair
+                        # rendezvous — charged to the goodput ledger's
+                        # 'recovery' bucket — when a rank was evicted
+                        _elastic.step_check(self, epoch)
+                        if monitor is not None:
+                            monitor.tic()
+                        # MXTPU_STEP_SAMPLE: every Nth step fully syncs
+                        # after dispatch for an honest device-step
+                        # latency (perf.step_latency) — exactly
+                        # ceil(nbatch/N) extra syncs per epoch, none on
+                        # unsampled steps
+                        sampled = _perfwatch.sample_tick()
+                        if sampled:
+                            _samp_t0 = time.perf_counter()
+                            _samp_ts = time.time_ns() // 1000
+                        # a step that TRACED (cold jit — fused or
+                        # fallback — or a shape-driven retrace) spent its
+                        # wall time compiling, not training: the goodput
+                        # ledger reattributes it to the 'compile' bucket,
+                        # minus whatever nested account() regions (warmup
+                        # waits, the perfwatch AOT capture) already
+                        # claimed.  Two counter reads when nothing traced.
+                        with instrument.span('fit.batch', cat='fit'), \
+                                instrument.timed('fit.step'), \
+                                _iowatch.traced_dispatch():
+                            metric_on_device = self._fit_step(data_batch,
+                                                              eval_metric)
+                        window.admit(self._step_ticket())
+                        if sampled:
+                            # a deliberate measurement drain — same
+                            # goodput bucket as the metric drains, so the
+                            # exclusive-bucket invariant stays checkable
+                            # against perf.host_syncs
+                            with _iowatch.account('metric_drain'):
+                                _perfwatch.sample_sync(self._step_ticket(),
+                                                       _samp_t0, _samp_ts)
+                        if instrument.metrics_enabled():
+                            bs = data_batch.data[0].shape[0] \
+                                if data_batch.data \
+                                else getattr(train_data, 'batch_size', 0)
+                            # pad rows are replicated filler, not samples
+                            bs -= getattr(data_batch, 'pad', 0) or 0
+                            nsamples += bs
+                            instrument.inc('fit.batches')
+                            instrument.inc('fit.samples', bs)
+                        if not metric_on_device:
+                            self.update_metric(eval_metric,
+                                               data_batch.label)
+                        if monitor is not None:
+                            monitor.toc_print()
+                        if batch_end_callback is not None:
+                            with _perfwatch.phase('callbacks'):
+                                batch_end_params = BatchEndParam(
+                                    epoch=epoch, nbatch=nbatch,
+                                    eval_metric=eval_metric,
+                                    locals=locals())
+                                for callback in _as_list(
+                                        batch_end_callback):
+                                    callback(batch_end_params)
+                    nbatch += 1
+                    fit_steps += 1
 
-                # the epoch boundary is a real barrier: wait out every
-                # step still in the async window before timing/logging
-                window.drain()
-                # one epoch of training is finished
-                for name, val in eval_metric.get_name_value():
-                    self.logger.info('Epoch[%d] Train-%s=%f',
-                                     epoch, name, val)
-                if instrument.profiling_enabled():
-                    # an honest epoch time needs the device drained —
-                    # async dispatch otherwise under-reports (engine.sync
-                    # doubles as the WaitForAll wait span at the epoch
-                    # boundary).  Gated on PROFILING, not metrics:
-                    # metrics-only mode stays passive — no injected
-                    # blocking round-trip — at the cost of an epoch
-                    # timer that can under-report the last step's
-                    # un-drained tail
-                    from ..engine import sync as _engine_sync
-                    _engine_sync(None)
-                toc = time.time()
-            if instrument.metrics_enabled() and toc > tic:
-                instrument.set_gauge('fit.samples_per_sec',
-                                     nsamples / (toc - tic))
-                instrument.observe('fit.epoch', toc - tic)
-            self.logger.info('Epoch[%d] Time cost=%.3f', epoch, (toc - tic))
+                # everything an epoch's end runs, outside any root:
+                # the window drain, the metric log, the parameters'
+                # round trip to the host, the checkpoint, the
+                # epoch-end callbacks
+                with _perfwatch.phase('epoch_end'):
+                    # the epoch boundary is a real barrier: wait out
+                    # every step still in the async window before
+                    # timing/logging
+                    window.drain()
+                    # one epoch of training is finished
+                    for name, val in eval_metric.get_name_value():
+                        self.logger.info('Epoch[%d] Train-%s=%f',
+                                         epoch, name, val)
+                    if instrument.profiling_enabled():
+                        # an honest epoch time needs the device drained
+                        # — async dispatch otherwise under-reports
+                        # (engine.sync doubles as the WaitForAll wait
+                        # span at the epoch boundary).  Gated on
+                        # PROFILING, not metrics: metrics-only mode stays
+                        # passive — no injected blocking round-trip — at
+                        # the cost of an epoch timer that can
+                        # under-report the last step's un-drained tail
+                        from ..engine import sync as _engine_sync
+                        _engine_sync(None)
+                    toc = time.time()
+                    if instrument.metrics_enabled() and toc > tic:
+                        instrument.set_gauge('fit.samples_per_sec',
+                                             nsamples / (toc - tic))
+                        instrument.observe('fit.epoch', toc - tic)
+                    self.logger.info('Epoch[%d] Time cost=%.3f', epoch,
+                                     (toc - tic))
 
-            # sync aux params across devices
-            arg_params_, aux_params_ = self.get_params()
-            self.set_params(arg_params_, aux_params_)
+                    # sync aux params across devices
+                    arg_params_, aux_params_ = self.get_params()
+                    self.set_params(arg_params_, aux_params_)
 
-            if checkpoint_prefix and (
-                    (epoch + 1) % checkpoint_period == 0
-                    or epoch + 1 == num_epoch):
-                from ..model import save_checkpoint as _save_ckpt
-                with _iowatch.account('checkpoint'):
-                    _save_ckpt(checkpoint_prefix, epoch + 1, self.symbol,
-                               arg_params_, aux_params_)
-                    # keep this rank's ckpt_vote current so a joiner's
-                    # consensus never trusts a stale ballot
-                    _elastic.note_checkpoint(checkpoint_prefix)
+                    if checkpoint_prefix and (
+                            (epoch + 1) % checkpoint_period == 0
+                            or epoch + 1 == num_epoch):
+                        from ..model import save_checkpoint as _save_ckpt
+                        with _iowatch.account('checkpoint'):
+                            _save_ckpt(checkpoint_prefix, epoch + 1,
+                                       self.symbol, arg_params_,
+                                       aux_params_)
+                            # keep this rank's ckpt_vote current so a
+                            # joiner's consensus never trusts a stale
+                            # ballot
+                            _elastic.note_checkpoint(checkpoint_prefix)
 
-            if epoch_end_callback is not None:
-                for callback in _as_list(epoch_end_callback):
-                    callback(epoch, self.symbol, arg_params_, aux_params_)
+                    if epoch_end_callback is not None:
+                        for callback in _as_list(epoch_end_callback):
+                            callback(epoch, self.symbol, arg_params_,
+                                     aux_params_)
 
             # evaluation on validation set
             if eval_data:

@@ -615,48 +615,54 @@ class Module(BaseModule):
         compiled program (install a monitor or set MXTPU_FUSED_FIT=0 to
         observe gradients).
         """
-        from .. import health as _health
-        metric = self._device_metric(eval_metric)
-        mkey = metric.device_fold_key() if metric is not None else None
-        hkey = _health.fold_key()
-        if self._fused is not None and mkey == self._fused_metric_key \
-                and hkey == self._fused_health_key:
-            # same folded computation (possibly a FRESH metric object —
-            # fit() re-creates string metrics per call, and a fresh
-            # health monitor per fit): reuse the compiled program, just
-            # thread this fit's state objects
-            self._fused_metric_ref = metric
-            self._health_ref = _health.active_monitor()
-        if self._fused is None and not self._fused_unavailable:
-            self._try_build_fused(metric)
-        elif self._fused is not None and \
-                (mkey != self._fused_metric_key or
-                 hkey != self._fused_health_key):
-            # a structurally different (or no) metric/health probe is
-            # folded into the compiled step: rebuild for this one,
-            # keeping optimizer state
-            saved_state = self._fused_opt_state
-            self._fused = None
-            self._fused_unavailable = False
-            self._try_build_fused(metric)
-            if self._fused is not None and saved_state is not None:
-                self._fused_opt_state = saved_state
-        elif self._fused is not None and self._functional_opt is not None \
-                and self._functional_opt.mult_signature != \
-                self._optimizer._mult_signature():
-            # lr/wd multipliers changed (set_lr_mult after fit started):
-            # they are baked into the compiled step, rebuild it but keep
-            # the accumulated optimizer state (momentum etc.)
-            saved_state = self._fused_opt_state
-            self._fused = None
-            self._fused_unavailable = False
-            self._try_build_fused(metric)
-            if self._fused is not None and saved_state is not None:
-                self._fused_opt_state = saved_state
-        if self._fused is None:
+        from .. import perfwatch as _perfwatch
+        # step_prep runs from here to the opening of dispatch: choosing
+        # (or rebuilding) the compiled step, then _fused_call
+        with _perfwatch.phase('step_prep'):
+            from .. import health as _health
+            metric = self._device_metric(eval_metric)
+            mkey = metric.device_fold_key() if metric is not None else None
+            hkey = _health.fold_key()
+            if self._fused is not None and mkey == self._fused_metric_key \
+                    and hkey == self._fused_health_key:
+                # same folded computation (possibly a FRESH metric object —
+                # fit() re-creates string metrics per call, and a fresh
+                # health monitor per fit): reuse the compiled program, just
+                # thread this fit's state objects
+                self._fused_metric_ref = metric
+                self._health_ref = _health.active_monitor()
+            if self._fused is None and not self._fused_unavailable:
+                self._try_build_fused(metric)
+            elif self._fused is not None and \
+                    (mkey != self._fused_metric_key or
+                     hkey != self._fused_health_key):
+                # a structurally different (or no) metric/health probe is
+                # folded into the compiled step: rebuild for this one,
+                # keeping optimizer state
+                saved_state = self._fused_opt_state
+                self._fused = None
+                self._fused_unavailable = False
+                self._try_build_fused(metric)
+                if self._fused is not None and saved_state is not None:
+                    self._fused_opt_state = saved_state
+            elif self._fused is not None and self._functional_opt is not None \
+                    and self._functional_opt.mult_signature != \
+                    self._optimizer._mult_signature():
+                # lr/wd multipliers changed (set_lr_mult after fit started):
+                # they are baked into the compiled step, rebuild it but keep
+                # the accumulated optimizer state (momentum etc.)
+                saved_state = self._fused_opt_state
+                self._fused = None
+                self._fused_unavailable = False
+                self._try_build_fused(metric)
+                if self._fused is not None and saved_state is not None:
+                    self._fused_opt_state = saved_state
+            call = None if self._fused is None else \
+                self._fused_call(data_batch, self._fused_metric_ref)
+        if call is None:
             super()._fit_step(data_batch)
             return False
-        self._run_fused(data_batch, self._fused_metric_ref)
+        self._fused_dispatch(data_batch, self._fused_metric_ref, call)
         return self._fused_metric_ref is not None
 
     def _try_build_fused(self, metric=None):
@@ -807,7 +813,20 @@ class Module(BaseModule):
                     name, self._fused_opt_state[name])
 
     def _run_fused(self, data_batch, metric=None):
+        """One fused step on ``data_batch``: prepare, dispatch, commit."""
+        from .. import perfwatch as _perfwatch
+        with _perfwatch.phase('step_prep'):
+            call = self._fused_call(data_batch, metric)
+        self._fused_dispatch(data_batch, metric, call)
+
+    def _fused_call(self, data_batch, metric):
+        """Everything the host does for a fused step before the
+        executable is called: placing the batch, the warm-start lookup,
+        the update counts, the learning rate, the rng fold-in,
+        assembling ``args`` (``perf.phase.step_prep``, opened by the
+        caller).  Returns what :meth:`_fused_dispatch` takes."""
         import jax.numpy as jnp
+        from .. import perfwatch as _perfwatch
         group = self._exec_group
         exec_ = group.execs[0]
         batch = {}
@@ -826,7 +845,6 @@ class Module(BaseModule):
         # all; a still-in-flight warmup for this signature is waited on
         # (it is compiling exactly what we need — waiting is strictly
         # cheaper than tracing it a second time on the hot path)
-        from .. import perfwatch as _perfwatch
         aot = None
         sig = None
         # capture_on: the perf OR comm plane needs the AOT capture +
@@ -851,7 +869,8 @@ class Module(BaseModule):
                     # (done-callback stores then pops): re-check the
                     # finished table before giving up on the warmup
                     aot = self._fused_aot.get(sig)
-        params = {n: exec_.arg_dict[n].handle for n in self._fused_trainable}
+        params = {n: exec_.arg_dict[n].handle
+                  for n in self._fused_trainable}
         frozen = {n: exec_.arg_dict[n].handle for n in self._fused_frozen}
         aux = {k: v.handle for k, v in exec_.aux_dict.items()}
         for idx, name in enumerate(self._param_names):
@@ -873,36 +892,53 @@ class Module(BaseModule):
             # MXTPU_FAULTS='fit.step:delay:P:SECS' plan slows THIS
             # rank's step cadence — what cluster.step_skew must name
             resilience.fault_point('fit.step')
+        states = (params, frozen, aux, self._fused_opt_state)
+        if metric is not None:
+            states = states + (metric.device_state(),)
+        if health is not None:
+            states = states + (health.device_state(),)
+        args = states + (batch, lr_t, rng)
+        if aot is None and _perfwatch.capture_on() and \
+                sig not in self._perf_aot_failed:
+            # AOT-capture the program this step would jit anyway:
+            # same lower+compile work (the trace still counts
+            # executor.xla_traces), but through the AOT API the
+            # executable exposes cost_analysis/memory_analysis —
+            # the per-executable accounting the performance plane
+            # and perf.mfu read
+            from .. import iowatch as _iowatch
+            try:
+                # the same lower+compile the jit path would pay —
+                # goodput charges it to the compile bucket
+                with _iowatch.account('compile'):
+                    aot = self._fused.lower(*args).compile()
+            except Exception:
+                self._perf_aot_failed.add(sig)
+                aot = None
+            else:
+                _perfwatch.register_executable(
+                    'fit_step', sig, aot,
+                    num_devices=self._mesh_plan.num_devices
+                    if self._mesh_plan is not None else 1)
+                self._fused_aot[sig] = aot
+        return [args, aot, sig, params, aux, health]
+
+    def _fused_dispatch(self, data_batch, metric, call):
+        """Call the executable (``perf.phase.dispatch``) and thread its
+        results back into the module's state (``perf.phase.step_commit``:
+        metric and health accumulators, parameters, aux states and
+        outputs into the executor's arrays, letting go of the donated
+        inputs, and — with the performance plane on — the memory
+        ledger's and ``note_step``'s bookkeeping).  ``call`` is
+        :meth:`_fused_call`'s list and is emptied: the step's inputs
+        (some four hundred donated arrays for a ResNet-50) are then
+        this frame's to release, inside ``step_commit``, and not
+        whenever the caller's frame unwinds."""
+        from .. import perfwatch as _perfwatch
+        exec_ = self._exec_group.execs[0]
+        args, aot, sig, params, aux, health = call
+        del call[:]
         with instrument.span('module.fused_step', cat='executor'):
-            states = (params, frozen, aux, self._fused_opt_state)
-            if metric is not None:
-                states = states + (metric.device_state(),)
-            if health is not None:
-                states = states + (health.device_state(),)
-            args = states + (batch, lr_t, rng)
-            if aot is None and _perfwatch.capture_on() and \
-                    sig not in self._perf_aot_failed:
-                # AOT-capture the program this step would jit anyway:
-                # same lower+compile work (the trace still counts
-                # executor.xla_traces), but through the AOT API the
-                # executable exposes cost_analysis/memory_analysis —
-                # the per-executable accounting the performance plane
-                # and perf.mfu read
-                from .. import iowatch as _iowatch
-                try:
-                    # the same lower+compile the jit path would pay —
-                    # goodput charges it to the compile bucket
-                    with _iowatch.account('compile'):
-                        aot = self._fused.lower(*args).compile()
-                except Exception:
-                    self._perf_aot_failed.add(sig)
-                    aot = None
-                else:
-                    _perfwatch.register_executable(
-                        'fit_step', sig, aot,
-                        num_devices=self._mesh_plan.num_devices
-                        if self._mesh_plan is not None else 1)
-                    self._fused_aot[sig] = aot
             try:
                 with _perfwatch.phase('dispatch'):
                     if aot is not None:
@@ -926,31 +962,33 @@ class Module(BaseModule):
                 # instead of a bare stack trace
                 _perfwatch.on_error(exc, 'fit_step', sig)
                 raise
+        with _perfwatch.phase('step_commit'):
             res = list(res)
             if health is not None:
                 health.set_device_state(res.pop())
             if metric is not None:
                 metric.set_device_state(res.pop())
             outs, new_params, new_aux, self._fused_opt_state = res
-        if _perfwatch.enabled():
-            # donated buffers (params/aux, donate_argnums 0/2) retire
-            # from the memory ledger NOW — their finalizers later see
-            # retired entries, so nothing double-counts
-            for v in params.values():
-                _perfwatch.ledger_donate(v)
-            for v in aux.values():
-                _perfwatch.ledger_donate(v)
-            for o in outs:
-                _perfwatch.ledger_alloc('fit.outputs', o)
-        if _perfwatch.capture_on():
-            rows = data_batch.data[0].shape[0] if data_batch.data else 0
-            _perfwatch.note_step('fit_step', sig, rows)
-        for n, v in new_params.items():
-            exec_.arg_dict[n]._set_data(v)
-        for n, v in new_aux.items():
-            exec_.aux_dict[n]._set_data(v)
-        exec_.outputs = [NDArray(o, exec_._ctx) for o in outs]
-        self._params_dirty = True
+            if _perfwatch.enabled():
+                # donated buffers (params/aux, donate_argnums 0/2) retire
+                # from the memory ledger NOW — their finalizers later see
+                # retired entries, so nothing double-counts
+                for v in params.values():
+                    _perfwatch.ledger_donate(v)
+                for v in aux.values():
+                    _perfwatch.ledger_donate(v)
+                for o in outs:
+                    _perfwatch.ledger_alloc('fit.outputs', o)
+            if _perfwatch.capture_on():
+                rows = data_batch.data[0].shape[0] if data_batch.data else 0
+                _perfwatch.note_step('fit_step', sig, rows)
+            for n, v in new_params.items():
+                exec_.arg_dict[n]._set_data(v)
+            for n, v in new_aux.items():
+                exec_.aux_dict[n]._set_data(v)
+            exec_.outputs = [NDArray(o, exec_._ctx) for o in outs]
+            self._params_dirty = True
+            del args, params, aux
 
     # -- warm-start compilation (docs/performance.md cold vs warm) ---------
     def _warm_start(self, eval_metric=None, data_sig=None):

@@ -130,24 +130,29 @@ def make_fit_step(symbol: Symbol, functional_opt, data_names=(),
             return outs, aux_upd
 
         from ..executor import mirror_wrap
-        (outs, aux_upd), vjp_fn = jax.vjp(mirror_wrap(fwd), params)
-        # zero cotangents: loss layers inject their gradient via
-        # custom_vjp, the reference's SoftmaxOutput backward contract
-        cots = ([jnp.zeros_like(o) for o in outs],
-                jax.tree_util.tree_map(jnp.zeros_like, aux_upd))
-        grads = vjp_fn(cots)[0]
+        # the three parts of the step as scopes in every op's op_name
+        # metadata (trace-time only; no HLO instruction changes)
+        with jax.named_scope('forward_backward'):
+            (outs, aux_upd), vjp_fn = jax.vjp(mirror_wrap(fwd), params)
+            # zero cotangents: loss layers inject their gradient via
+            # custom_vjp, the reference's SoftmaxOutput backward contract
+            cots = ([jnp.zeros_like(o) for o in outs],
+                    jax.tree_util.tree_map(jnp.zeros_like, aux_upd))
+            grads = vjp_fn(cots)[0]
         new_aux = dict(aux)
         new_aux.update({k: v.astype(aux[k].dtype)
                         for k, v in aux_upd.items()})
-        new_params, new_opt = functional_opt.update(params, grads,
-                                                    opt_state, lr_t)
+        with jax.named_scope('optimizer'):
+            new_params, new_opt = functional_opt.update(params, grads,
+                                                        opt_state, lr_t)
         new_metric = None
         if metric_fn is not None:
             # metric deltas from the UNCAST label (class ids above 256
             # are not exactly representable in bf16) and the raw outputs
-            deltas = metric_fn(raw_batch[metric_label], outs[0])
-            new_metric = jax.tree_util.tree_map(
-                lambda s, d: s + d, metric_state, deltas)
+            with jax.named_scope('metric'):
+                deltas = metric_fn(raw_batch[metric_label], outs[0])
+                new_metric = jax.tree_util.tree_map(
+                    lambda s, d: s + d, metric_state, deltas)
         new_health = None
         if health_action is not None:
             from .. import health as _health

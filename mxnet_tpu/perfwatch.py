@@ -34,9 +34,13 @@ centrally in ``cluster_status.json``/``.prom``):
    ``MXTPU_PEAK_FLOPS`` override, else :func:`device_peaks` per device
    kind) and ``perf.steps_per_sec`` from a rolling window; the
    :func:`phase` context manager attributes wall time to the loop's
-   seams (``feed_wait``, ``dispatch``, ``window_wait``,
-   ``metric_drain``, ``device_wait``) as ``perf.phase.*`` histograms and
-   — under profiling — trace spans.  ``MXTPU_STEP_SAMPLE=N`` fully
+   seams (``feed_wait``, ``step_prep``, ``dispatch``, ``window_wait``,
+   ``callbacks``, ``metric_drain``, ``epoch_end``, ``device_wait``; on
+   the feed thread ``feed_fetch`` and ``feed_stage``) as
+   ``perf.phase.*`` histograms, under the :func:`fit_step` root of each
+   iteration; every one is also a ``mxtpu.``-prefixed annotation in a
+   running ``jax.profiler`` trace, on the device planes' clock, and —
+   under profiling — a Chrome span.  ``MXTPU_STEP_SAMPLE=N`` fully
    syncs every Nth step (``perf.step_latency`` histogram,
    ``perf.host_syncs`` counter, a ``perf.step`` span with phase
    children) for honest device-step latency without re-introducing
@@ -494,15 +498,30 @@ _NULL_PHASE = instrument.NULL_CTX
 
 def phase(name):
     """Attribute the wrapped region's wall time to step phase ``name``
-    (``perf.phase.<name>`` histogram; a span too under profiling).
-    The shared no-op when the plane is off.  Backed by
-    ``instrument.hist_span`` — the single time_ns phase clock shared
+    (``perf.phase.<name>`` histogram; a ``mxtpu.perf.phase.<name>``
+    annotation in a running ``jax.profiler`` trace; a Chrome span too
+    under profiling).  The shared no-op when the plane is off.  Backed
+    by ``instrument.hist_span`` — the single time_ns phase clock shared
     with the input-pipeline plane's ``iowatch.stage.*``, so a
     perf.phase child can never stick out of its perf.step parent by
     clock skew (check_trace validates the nesting)."""
     if not _on:
         return _NULL_PHASE
     return instrument.hist_span('perf.phase.' + name, cat='phase')
+
+
+def fit_step(step_num):
+    """The root span of one fit-loop iteration (``perf.fit_step``), from
+    asking the iterator for the batch to the return of the last
+    batch-end callback, so consecutive roots tile the fit thread's time
+    inside an epoch and a root's self time is what no ``phase`` names.
+    Same seam as :func:`phase`; in a ``jax.profiler`` trace it is a step
+    annotation carrying ``step_num``, the identifier its children share.
+    The shared no-op when the plane is off."""
+    if not _on:
+        return _NULL_PHASE
+    return instrument.hist_span('perf.fit_step', cat='fit',
+                                step_num=step_num)
 
 
 def sample_tick():

@@ -380,6 +380,61 @@ def test_disabled_path_zero_events():
     assert snap['timers'] == {}
 
 
+def test_hist_span_sinks_one_region_three_ways(monkeypatch):
+    """hist_span: a histogram sample, a Chrome span under profiling, and
+    a jax.profiler annotation named ``mxtpu.`` + the series that is
+    entered first and left last; with ``step_num`` a step annotation;
+    a cancelled region leaves a mark in the profiler and nothing else."""
+    import jax
+    seen = []
+
+    class Fake(object):
+        def __init__(self, name, **kwargs):
+            self.name, self.kwargs = name, kwargs
+
+        def __enter__(self):
+            seen.append(('enter', self.name, time.time_ns()))
+
+        def __exit__(self, *exc):
+            seen.append(('exit', self.name, time.time_ns()))
+
+        def set_metadata(self, **kwargs):
+            seen.append(('metadata', self.name, kwargs))
+
+    class FakeStep(Fake):
+        def __init__(self, name, **kwargs):
+            super().__init__('step:' + name, **kwargs)
+            seen.append(('step_num', kwargs['step_num']))
+
+    monkeypatch.setattr(jax.profiler, 'TraceAnnotation', Fake)
+    monkeypatch.setattr(jax.profiler, 'StepTraceAnnotation', FakeStep)
+    instrument.set_profiling(True)
+    with instrument.hist_span('perf.phase.seam_t'):
+        time.sleep(0.002)
+    (_, name, entered), (_, _, left) = seen
+    assert name == 'mxtpu.perf.phase.seam_t'
+    event, = [e for e in instrument.trace_events()
+              if e['name'] == 'perf.phase.seam_t']
+    assert entered // 1000 <= event['ts']
+    assert event['ts'] + event['dur'] <= left // 1000 + 1
+    hist = instrument.metrics_snapshot()['histograms']['perf.phase.seam_t']
+    assert hist['count'] == 1 and hist['sum'] >= 0.002
+    del seen[:]
+    with instrument.hist_span('perf.root_t', cat='fit', step_num=41) as root:
+        root.cancel()
+    assert seen[0] == ('step_num', 41)
+    assert [s[:2] for s in seen[1:]] == [
+        ('enter', 'step:mxtpu.perf.root_t'),
+        ('metadata', 'step:mxtpu.perf.root_t'),
+        ('exit', 'step:mxtpu.perf.root_t')]
+    assert seen[2][2] == {'cancelled': 1}
+    assert 'perf.root_t' not in instrument.metrics_snapshot()['histograms']
+    assert not [e for e in instrument.trace_events()
+                if e['name'] == 'perf.root_t']
+    # the shared no-op takes the same call
+    assert instrument.NULL_CTX.__enter__().cancel() is None
+
+
 def test_disabled_span_overhead_guard():
     """Off-path span entry must stay allocation-free.  The baseline is
     an inlined ideal zero-overhead context manager — a flag check

@@ -385,6 +385,312 @@ def test_check_trace_rejects_childless_perf_step(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# The fit iteration's span tree on the profiler's clock (ISSUE 26)
+# ---------------------------------------------------------------------------
+
+ROOT = 'mxtpu.perf.fit_step'
+# the root's children on the fit thread, and the feed thread's own
+IN_ROOT = ('feed_wait', 'step_prep', 'dispatch', 'step_commit', 'window_wait',
+           'callbacks')
+ON_FEED = ('feed_fetch', 'feed_stage')
+
+
+def _traced_fit(tmp_path, batches=8, epochs=2):
+    """A small ``Module.fit`` under ``jax.profiler`` with the plane on.
+    Returns the ``mxtpu.`` spans of ``/host:CPU`` as
+    ``{line index: [(name without the prefix, start, end, stats)]}`` and
+    the metrics snapshots taken round the fit."""
+    import glob
+    import warnings
+    import jax
+    rng = np.random.RandomState(17)
+    X, Y = _cls_data(rng, 8 * batches)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    perfwatch.set_enabled(True)
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for _ in range(3):
+            with perfwatch.phase('device_wait'):
+                pass
+        before = instrument.metrics_snapshot()
+        _fit({'MXTPU_PERFWATCH': '1'}, X, Y, bs=8, num_epoch=epochs)
+        after = instrument.metrics_snapshot()
+    finally:
+        jax.profiler.stop_trace()
+        perfwatch.set_enabled(False)
+    path, = glob.glob(str(tmp_path / '**' / '*.xplane.pb'), recursive=True)
+    profile = jax.profiler.ProfileData.from_file(path)
+    lines = {}
+    with warnings.catch_warnings():
+        warnings.simplefilter('ignore', DeprecationWarning)
+        for plane in profile.planes:
+            if plane.name != '/host:CPU':
+                # the program's spans are host spans and nothing else
+                assert not any(e.name.startswith('mxtpu.')
+                               for line in plane.lines
+                               for e in line.events), plane.name
+                continue
+            for index, line in enumerate(plane.lines):
+                found = [(e.name[len('mxtpu.'):], e.start_ns,
+                          e.start_ns + e.duration_ns, dict(e.stats))
+                         for e in line.events
+                         if e.name.startswith('mxtpu.')]
+                if found:
+                    lines[index] = sorted(found, key=lambda s: s[1])
+    return lines, before, after
+
+
+def _named(spans, name):
+    return [s for s in spans if s[0] == name]
+
+
+def _inside(span, outer):
+    return outer[1] <= span[1] and span[2] <= outer[2]
+
+
+def test_profiler_trace_holds_the_fit_span_tree(tmp_path):
+    """While a jax.profiler session runs, every hist_span of a live
+    plane is an ``mxtpu.``-prefixed annotation on /host:CPU, on the
+    line of the thread that did the work: the root of each iteration
+    with its step number, each child inside its root on the fit
+    thread, the feed spans on the feed thread, the epoch's end outside
+    any root."""
+    lines, _, _ = _traced_fit(tmp_path)
+    fit_line, = [i for i, spans in lines.items()
+                 if _named(spans, 'perf.fit_step')]
+    fit = lines[fit_line]
+    roots = _named(fit, 'perf.fit_step')
+    # the ask that found the iterator exhausted, once an epoch, cannot be
+    # withdrawn from the trace: it is marked, and counts nowhere
+    stubs = [r for r in roots if r[3].get('cancelled')]
+    assert [r[3]['step_num'] for r in stubs] == [8, 16]
+    roots = [r for r in roots if r not in stubs]
+    assert [r[3]['step_num'] for r in roots] == list(range(16))
+    assert len(_named(fit, 'perf.phase.device_wait')) == 3
+    for name in IN_ROOT:
+        children = _named(fit, 'perf.phase.' + name)
+        assert children, name
+        for child in children:
+            holders = [r for r in roots if _inside(child, r)]
+            if name == 'window_wait' and not holders:
+                # the epoch's end drains the window too
+                assert any(_inside(child, e)
+                           for e in _named(fit, 'perf.phase.epoch_end'))
+                continue
+            if name == 'feed_wait' and not holders:
+                # the wait that ended in "no more batches"
+                assert any(_inside(child, r) for r in stubs)
+                continue
+            assert len(holders) == 1, (name, child)
+    # one of each a root, in the loop's order; an epoch's first root has
+    # no window wait (nothing is in flight yet)
+    for number, root in enumerate(roots):
+        order = [s[0].rsplit('.', 1)[1] for s in fit
+                 if s is not root and _inside(s, root) and
+                 s[0].rsplit('.', 1)[1] in IN_ROOT]
+        assert order == [name for name in IN_ROOT
+                         if name != 'window_wait' or number % 8], order
+    # the Speedometer's drain nests inside the callbacks that ran it
+    drains = _named(fit, 'perf.phase.metric_drain')
+    assert drains
+    for drain in drains:
+        assert any(_inside(drain, c)
+                   for c in _named(fit, 'perf.phase.callbacks') +
+                   _named(fit, 'perf.phase.epoch_end')), drain
+    ends = _named(fit, 'perf.phase.epoch_end')
+    assert len(ends) == 2
+    for end in ends:
+        assert not any(r[1] < end[2] and r[2] > end[1] for r in roots)
+    # the feed thread: its own line, parentless, and nothing of it on
+    # the fit thread
+    feed_line, = [i for i, spans in lines.items() if i != fit_line]
+    for name in ON_FEED:
+        assert _named(lines[feed_line], 'perf.phase.' + name), name
+        assert not _named(fit, 'perf.phase.' + name)
+    assert not _named(lines[feed_line], 'perf.fit_step')
+    assert {s[0] for s in lines[feed_line]} == \
+        {'perf.phase.' + name for name in ON_FEED}
+
+
+def test_fit_step_roots_count_cover_and_tile(tmp_path):
+    """Per step the children's sum does not exceed the root's length,
+    the root's histogram counts what fit.batches counts, and the roots
+    of an epoch tile it: their sum is within 2% of first start to last
+    end."""
+    lines, before, after = _traced_fit(tmp_path, batches=16)
+    fit, = [spans for spans in lines.values()
+            if _named(spans, 'perf.fit_step')]
+    roots = [r for r in _named(fit, 'perf.fit_step')
+             if not r[3].get('cancelled')]
+
+    def moved(kind, name, field=None):
+        new, old = after[kind].get(name), before[kind].get(name)
+        if field is None:
+            return new - (old or 0)
+        return new[field] - (old[field] if old else 0)
+
+    assert moved('counters', 'fit.batches') == 32 == len(roots)
+    assert moved('histograms', 'perf.fit_step', 'count') == 32
+    assert moved('histograms', 'perf.phase.step_prep', 'count') == 32
+    assert moved('histograms', 'perf.phase.callbacks', 'count') == 32
+    assert moved('histograms', 'perf.phase.epoch_end', 'count') == 2
+    for root in roots:
+        children = sum(s[2] - s[1] for s in fit
+                       if s[0].rsplit('.', 1)[1] in IN_ROOT and
+                       _inside(s, root))
+        assert 0 < children <= root[2] - root[1]
+    # the same on the histograms' own clock, over the whole fit
+    assert sum(moved('histograms', 'perf.phase.' + name, 'sum')
+               for name in IN_ROOT if name != 'window_wait') <= \
+        moved('histograms', 'perf.fit_step', 'sum')
+    for epoch in (roots[:16], roots[16:]):
+        covered = sum(r[2] - r[1] for r in epoch)
+        extent = epoch[-1][2] - epoch[0][1]
+        assert covered <= extent
+        assert covered >= 0.98 * extent, (covered, extent)
+
+
+def test_planes_off_enter_no_profiler_annotation(monkeypatch):
+    """With every plane off the seam hands out the shared no-op: a fit
+    builds and enters no jax.profiler annotation at all."""
+    import jax
+
+    def refuse(*args, **kwargs):
+        raise AssertionError('a profiler annotation with the planes off')
+
+    monkeypatch.setattr(jax.profiler, 'TraceAnnotation', refuse)
+    monkeypatch.setattr(jax.profiler, 'StepTraceAnnotation', refuse)
+    assert perfwatch.phase('dispatch') is instrument.NULL_CTX
+    assert perfwatch.fit_step(3) is instrument.NULL_CTX
+    rng = np.random.RandomState(19)
+    X, Y = _cls_data(rng, 32)
+    _fit({}, X, Y, bs=8, num_epoch=2)
+    instrument.set_metrics(True)        # the registry alone is no plane
+    try:
+        _fit({}, X, Y, bs=8, num_epoch=1)
+        hist = instrument.metrics_snapshot().get('histograms') or {}
+        assert not [k for k in hist if k.startswith('perf.')]
+    finally:
+        instrument.set_metrics(False)
+    # and with the plane on, the same patch is reached
+    perfwatch.set_enabled(True)
+    with pytest.raises(AssertionError):
+        perfwatch.phase('dispatch')
+
+
+def test_fused_step_names_its_nodes_in_op_name():
+    """jax.named_scope round every operator and round the step's three
+    parts: the node's name is in the op_name of its forward and of its
+    backward ops, in the lowered text and in the compiled HLO, and the
+    text without debug info (what the fuse pins compare) has none of
+    it."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.parallel.train_step import (make_fit_step,
+                                               make_sgd_momentum,
+                                               _PlainUpdate)
+    net = mx.sym.Variable('data')
+    net = mx.sym.Convolution(net, num_filter=4, kernel=(3, 3), pad=(1, 1),
+                             name='sconv0')
+    net = mx.sym.BatchNorm(net, name='sbn0')
+    net = mx.sym.Pooling(net, kernel=(2, 2), stride=(2, 2),
+                         pool_type='max', name='spool0')
+    net = mx.sym.FullyConnected(mx.sym.Flatten(net), num_hidden=10,
+                                name='sfc')
+    net = mx.sym.SoftmaxOutput(net, name='softmax')
+    arg_shapes, _, aux_shapes = net.infer_shape(data=(4, 3, 8, 8))
+    vals = {n: jnp.ones(s, jnp.float32)
+            for n, s in zip(net.list_arguments(), arg_shapes)}
+    aux = {n: jnp.ones(s, jnp.float32)
+           for n, s in zip(net.list_auxiliary_states(), aux_shapes)}
+    raw = make_fit_step(net, _PlainUpdate(make_sgd_momentum(
+        lr=0.05, momentum=0.9, wd=0.0, rescale_grad=0.25)),
+        data_names=(), _raw=True,
+        metric_fn=lambda label, pred: {'n': jnp.sum(label)},
+        metric_label='softmax_label')
+    batch = {k: vals.pop(k) for k in ('data', 'softmax_label')}
+    opt = {k: jnp.zeros_like(v) for k, v in vals.items()}
+
+    def step(params, aux, opt, metric, batch, rng):
+        return raw(params, {}, aux, opt, metric, batch, jnp.float32(0.1),
+                   rng)
+
+    lowered = jax.jit(step).lower(vals, aux, opt, {'n': jnp.float32(0)},
+                                  batch, jax.random.PRNGKey(0))
+    assert 'spool0' not in lowered.as_text()
+    located = set(re.findall(r'loc\("([^"]*)"',
+                             lowered.as_text(debug_info=True)))
+    compiled = set(re.findall(r'op_name="([^"]*)"',
+                              lowered.compile().as_text()))
+    for names in (located, compiled):
+        for node in ('Pooling/spool0', 'BatchNorm/sbn0',
+                     'Convolution/sconv0'):
+            forward = [n for n in names
+                       if node in n and 'transpose(' not in n]
+            backward = [n for n in names
+                        if node in n and 'transpose(' in n]
+            assert forward and backward, node
+            assert all('forward_backward/' in n
+                       for n in forward + backward)
+        assert any('/optimizer/' in n for n in names)
+        assert any('/metric/' in n for n in names)
+
+
+def test_check_trace_learns_the_fit_step_root(tmp_path):
+    """A perf.fit_step root contains the phases that meet it on its
+    thread, and roots do not overlap; a real profiled fit passes."""
+    def span(name, ts, dur, tid=1):
+        return {'name': name, 'ph': 'X', 'pid': 1, 'tid': tid, 'ts': ts,
+                'dur': dur}
+
+    good = [span('perf.fit_step', 1000, 500),
+            span('perf.phase.feed_wait', 1000, 10),
+            span('perf.phase.callbacks', 1400, 101),    # rounding
+            span('perf.phase.metric_drain', 1410, 50),
+            span('perf.fit_step', 1500, 500),
+            span('perf.phase.dispatch', 1600, 100),
+            span('perf.phase.epoch_end', 2100, 50),     # outside any root
+            span('perf.phase.feed_stage', 1400, 300, tid=2)]
+    assert check_trace.validate_events(good) == []
+    straddling = good + [span('perf.phase.window_wait', 1450, 100)]
+    errors = check_trace.validate_events(straddling)
+    assert len(errors) == 2 and all('sticks out' in e for e in errors)
+    early = good + [span('perf.phase.window_wait', 900, 200)]
+    assert 'sticks out' in check_trace.validate_events(early)[0]
+    overlapping = good + [span('perf.fit_step', 1900, 300)]
+    assert 'overlap' in check_trace.validate_events(overlapping)[0]
+    begun = [{'name': 'perf.fit_step', 'ph': 'B', 'pid': 1, 'tid': 1,
+              'ts': 1000}]
+    assert check_trace.validate_events(begun)
+
+    rng = np.random.RandomState(23)
+    X, Y = _cls_data(rng, 32)
+    instrument.set_profiling(True)
+    try:
+        _fit({'MXTPU_PERFWATCH': '1'}, X, Y, bs=8, num_epoch=2)
+        path = str(tmp_path / 'root_trace.json')
+        instrument.dump_trace(path)
+    finally:
+        instrument.set_profiling(False)
+    assert check_trace.validate_file(path) == []
+    with open(path) as f:
+        events = json.load(f)['traceEvents']
+    roots = [e for e in events if e.get('name') == 'perf.fit_step']
+    assert len(roots) == 8
+    for root in roots:
+        inside = [e['name'] for e in events
+                  if e.get('tid') == root['tid'] and
+                  e.get('name', '').startswith('perf.phase.') and
+                  root['ts'] <= e['ts'] and
+                  e['ts'] + e['dur'] <= root['ts'] + root['dur'] + 2]
+        for name in ('step_prep', 'dispatch', 'step_commit'):
+            assert 'perf.phase.' + name in inside
+
+
+# ---------------------------------------------------------------------------
 # OOM forensics
 # ---------------------------------------------------------------------------
 
@@ -562,6 +868,8 @@ def test_knobs_off_overhead_guard():
          lambda: _floor_hook()),
         ('phase', lambda: perfwatch.phase('dispatch'),
          lambda: _floor_hook('dispatch')),
+        ('fit_step', lambda: perfwatch.fit_step(7),
+         lambda: _floor_hook(7)),
         ('note_step', lambda: perfwatch.note_step('fit_step', None),
          lambda: _floor_hook('fit_step', None)),
         ('ledger_alloc', lambda: perfwatch.ledger_alloc('s', None),

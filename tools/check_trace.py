@@ -9,10 +9,13 @@ Exits nonzero when any file is malformed: not JSON, no ``traceEvents``
 list, or any event missing the fields Perfetto/chrome://tracing need
 (``name``/``ph``/``pid`` everywhere; ``ts``/``tid`` on data events;
 numeric non-negative ``dur`` on complete events).  Performance-plane
-events (``perf.step`` sampled-step spans, ``perf.phase.*`` phase
-attribution) are additionally structure-checked: a ``perf.step`` span
-with no phase child inside its interval on its own thread is rejected —
-a merged multi-rank trace where the breakdown was lost is not honest.
+events (``perf.step`` sampled-step spans, ``perf.fit_step`` iteration
+roots, ``perf.phase.*`` phase attribution) are additionally
+structure-checked: a ``perf.step`` span with no phase child inside its
+interval on its own thread is rejected — a merged multi-rank trace
+where the breakdown was lost is not honest — and so is a
+``perf.fit_step`` root that a phase on its thread sticks out of, or
+that overlaps the next root.
 Request-attribution spans (``serve.request``/``serve.req.*``/
 ``serve.flush`` from MXTPU_SERVEWATCH) are ledger-checked: a request's
 six exclusive buckets must sum to its e2e span within tolerance, and
@@ -30,6 +33,7 @@ under tier-1.
 """
 from __future__ import annotations
 
+import bisect
 import json
 import sys
 
@@ -77,7 +81,7 @@ def validate_events(events):
             if not isinstance(dur, (int, float)) or dur < 0:
                 err('complete event needs non-negative numeric dur')
         if isinstance(e.get('name'), str) and \
-                (e['name'] == 'perf.step' or
+                (e['name'] in ('perf.step', 'perf.fit_step') or
                  e['name'].startswith('perf.phase.')) and ph != 'X':
             err('performance-plane event must be a complete (X) span')
     errors.extend(_validate_perf_steps(events))
@@ -144,12 +148,24 @@ def _validate_rank_alignment(events):
     return []
 
 
+# a Chrome span's ts and dur are each floored to a microsecond from one
+# nanosecond clock, so a child that ends with its parent can read up to
+# a microsecond past it at either end
+_NEST_TOL_US = 2
+
+
 def _validate_perf_steps(events):
     """Every ``perf.step`` sampled-step span must contain at least one
     ``perf.phase.*`` child on the same pid/tid inside its interval —
-    the step-time breakdown the span exists to carry."""
+    the step-time breakdown the span exists to carry.
+
+    Every ``perf.fit_step`` root (one iteration of the fit loop) must
+    contain the ``perf.phase.*`` spans that meet it on its own pid/tid:
+    a phase that sticks out of its root, or two roots that overlap,
+    is a span tree whose self times mean nothing."""
     steps = []
     phases = []
+    roots = []
     for e in events:
         if not isinstance(e, dict) or e.get('ph') != 'X':
             continue
@@ -162,12 +178,33 @@ def _validate_perf_steps(events):
         key = (e.get('pid'), e.get('tid'))
         if name == 'perf.step':
             steps.append((key, ts, ts + dur))
+        elif name == 'perf.fit_step':
+            roots.append((key, ts, ts + dur))
         elif name.startswith('perf.phase.'):
-            phases.append((key, ts, ts + dur))
+            phases.append((key, ts, ts + dur, name))
     errors = []
+    by_thread = {}
+    for key, t0, t1 in roots:
+        by_thread.setdefault(key, []).append((t0, t1))
+    for key, spans in by_thread.items():
+        spans.sort()
+        for (t0, t1), (u0, _) in zip(spans, spans[1:]):
+            if u0 < t1 - _NEST_TOL_US:
+                errors.append('perf.fit_step roots at ts=%s and ts=%s '
+                              '(pid/tid %s) overlap' % (t0, u0, key))
+    for key, p0, p1, name in phases:
+        spans = by_thread.get(key, ())
+        # the root that starts last at or before the phase, and the next
+        at = bisect.bisect_right(spans, (p0 + _NEST_TOL_US, float('inf')))
+        for t0, t1 in spans[max(at - 1, 0):at + 1]:
+            if p0 < t1 - _NEST_TOL_US and p1 > t0 + _NEST_TOL_US and \
+                    (p0 < t0 - _NEST_TOL_US or p1 > t1 + _NEST_TOL_US):
+                errors.append('%s span [%s, %s] sticks out of the '
+                              'perf.fit_step root [%s, %s] it meets '
+                              '(pid/tid %s)' % (name, p0, p1, t0, t1, key))
     for key, t0, t1 in steps:
         if not any(pk == key and p0 >= t0 and p1 <= t1
-                   for pk, p0, p1 in phases):
+                   for pk, p0, p1, _ in phases):
             errors.append('perf.step span at ts=%s (pid/tid %s) has no '
                           'perf.phase.* child inside its interval'
                           % (t0, key))
