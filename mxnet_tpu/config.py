@@ -107,11 +107,6 @@ _register('MXTPU_DISABLE_PALLAS', False, _bool,
           'Force pure-XLA fallbacks instead of Pallas kernels.')
 _register('MXTPU_FORCE_PALLAS_INTERPRET', False, _bool,
           'Run Pallas kernels in interpreter mode (CPU testing).')
-_register('MXTPU_POOL_SELECT_SCATTER', False, _bool,
-          'Revert 2-D max pooling to the lax.reduce_window path whose '
-          'backward is select_and_scatter (serialized scatter on '
-          'TPU).  Default off: shifted-view pooling with an int8 '
-          'argmax backward (ops/nn.py _max_pool_firstmax).')
 _register('MXTPU_ASSUME_TPU', False, _bool,
           'Dispatch to Pallas kernel paths even when no TPU device is '
           'attached — for AOT cross-lowering to TPU on a CPU host '

@@ -18,8 +18,6 @@ from data shapes.
 """
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -242,86 +240,6 @@ def _pool_out_dim(x, k, p, s, convention):
     return (x + 2 * p - k) // s + 1
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
-def _max_pool_firstmax(data, kernel, stride, pads, in_shape, dtype_name):
-    """2-D max pooling whose backward routes the gradient to the FIRST
-    maximal element of each window (the reference select_and_scatter
-    semantics) WITHOUT lax.select_and_scatter — which lowers to a
-    serialized scatter on TPU.  Forward: ky*kx shifted strided views,
-    max tree.  Backward: per-tap masks from a saved int8 argmax map,
-    placed back by lax.pad with interior padding (the exact transpose
-    of a strided slice) — pure elementwise + pad ops, one XLA fusion
-    each way, and the residual is the int8 map instead of x and y.
-    """
-    out, _ = _max_pool_firstmax_fwd(data, kernel, stride, pads,
-                                    in_shape, dtype_name)
-    return out
-
-
-def _mp_views(data, kernel, stride, pads):
-    neg = jnp.asarray(-jnp.inf, data.dtype)
-    padded = jnp.pad(data, ((0, 0), (0, 0)) + tuple(pads),
-                     constant_values=neg)
-    ky, kx = kernel
-    sy, sx = stride
-    h, w = padded.shape[2], padded.shape[3]
-    oh = (h - ky) // sy + 1
-    ow = (w - kx) // sx + 1
-    views = []
-    for dy in range(ky):
-        for dx in range(kx):
-            views.append(jax.lax.slice(
-                padded, (0, 0, dy, dx),
-                (padded.shape[0], padded.shape[1],
-                 dy + (oh - 1) * sy + 1, dx + (ow - 1) * sx + 1),
-                (1, 1, sy, sx)))
-    return views, padded.shape, (oh, ow)
-
-
-def _max_pool_firstmax_fwd(data, kernel, stride, pads, in_shape,
-                           dtype_name):
-    views, padded_shape, _ = _mp_views(data, kernel, stride, pads)
-    out = views[0]
-    idx = jnp.zeros(views[0].shape, jnp.int8)
-    for t, v in enumerate(views[1:], start=1):
-        # strict > keeps the FIRST tap on ties; the isnan terms make
-        # NaN propagate exactly like HLO maximum (NaN wins and sticks)
-        better = (v > out) | (jnp.isnan(v) & ~jnp.isnan(out))
-        out = jnp.where(better, v, out)
-        idx = jnp.where(better, jnp.int8(t), idx)
-    return out, idx
-
-
-def _max_pool_firstmax_bwd(kernel, stride, pads, in_shape, dtype_name,
-                           res, g):
-    idx = res
-    ky, kx = kernel
-    sy, sx = stride
-    padded_h = in_shape[2] + pads[0][0] + pads[0][1]
-    padded_w = in_shape[3] + pads[1][0] + pads[1][1]
-    g32 = g.astype(jnp.float32)
-    acc = jnp.zeros((in_shape[0], in_shape[1], padded_h, padded_w),
-                    jnp.float32)
-    oh, ow = g.shape[2], g.shape[3]
-    for t in range(ky * kx):
-        dy, dx = divmod(t, kx)
-        m = jnp.where(idx == t, g32, 0.0)
-        # transpose of the strided slice: interior padding re-expands
-        # the stride, edge padding restores the tap offset
-        acc = acc + jax.lax.pad(
-            m, jnp.float32(0.0),
-            ((0, 0, 0), (0, 0, 0),
-             (dy, padded_h - dy - ((oh - 1) * sy + 1), sy - 1),
-             (dx, padded_w - dx - ((ow - 1) * sx + 1), sx - 1)))
-    dx_full = acc[:, :, pads[0][0]:padded_h - pads[0][1],
-                  pads[1][0]:padded_w - pads[1][1]]
-    return (dx_full.astype(dtype_name),)
-
-
-_max_pool_firstmax.defvjp(_max_pool_firstmax_fwd,
-                          _max_pool_firstmax_bwd)
-
-
 def _pooling_apply(attrs, inputs, is_train, rng):
     data = inputs[0]
     pool_type = attrs.get('pool_type', 'max')
@@ -348,16 +266,11 @@ def _pooling_apply(attrs, inputs, is_train, rng):
     strides = (1, 1) + stride
     padding = [(0, 0), (0, 0)] + pads
     if pool_type == 'max':
-        from .. import config
-        # <= 25 taps (2x2/3x3/5x5): the unrolled strided-slice form
-        # emits kernel-area slices fwd + pad/where pairs bwd, which
-        # bloats HLO and compile time for big windows — those route to
-        # reduce_window/select_and_scatter instead.
-        if nd == 2 and int(np.prod(kernel)) <= 25 and \
-                not config.get('MXTPU_POOL_SELECT_SCATTER'):
-            out = _max_pool_firstmax(data, kernel, stride, tuple(pads),
-                                     data.shape, str(data.dtype))
-            return [out], {}
+        # The compiler's own pair: reduce_window, whose gradient is
+        # select_and_scatter (a window's gradient goes to its first
+        # maximal element).  Measured on the v5e against hand-written
+        # forms that save an int8 argmax map (PERF.md section 6,
+        # PR 27): the step is 7 to 12 ms shorter with this one.
         init = -jnp.inf
         out = jax.lax.reduce_window(data, init, jax.lax.max, window, strides,
                                     padding)

@@ -152,9 +152,9 @@ def test_attention_cpu_short_seq_uses_reference():
 
 
 def test_max_pool_large_window_routes_to_reduce_window():
-    """Advice r4: >25-tap windows go through reduce_window, not the
-    unrolled firstmax form (HLO-size/compile-time blowup) — and the
-    result is still correct."""
+    """Advice r4: >25-tap windows go through reduce_window (since PR 27
+    every window does; the unrolled firstmax form they had to avoid is
+    gone) — and the result is still correct."""
     import jax
     x = mx.sym.Variable('x')
     y = mx.sym.Pooling(x, kernel=(11, 11), stride=(4, 4),

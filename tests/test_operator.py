@@ -387,49 +387,195 @@ def test_slice_assign_ops():
     assert np.allclose(nd._CrossDeviceCopy(nd.array(x)).asnumpy(), x)
 
 
-def test_shifted_maxpool_matches_select_and_scatter(monkeypatch):
-    """The shifted-view max pooling (default) must match the
-    reduce_window/select_and_scatter path exactly — forward AND
-    gradient, including tie windows (both route to the FIRST maximal
-    element)."""
+# The eight max-pool geometries of ISSUE 27: (name, attrs, input H x W).
+_MAXPOOL_CASES = [
+    ('3x3s2p1', {'kernel': (3, 3), 'stride': (2, 2), 'pad': (1, 1)},
+     (10, 10)),
+    ('3x3s2p0_odd', {'kernel': (3, 3), 'stride': (2, 2)}, (9, 11)),
+    ('3x3s2_full', {'kernel': (3, 3), 'stride': (2, 2),
+                    'pooling_convention': 'full'}, (9, 10)),
+    ('2x2s2', {'kernel': (2, 2), 'stride': (2, 2)}, (9, 8)),
+    ('3x3s1p1', {'kernel': (3, 3), 'stride': (1, 1), 'pad': (1, 1)},
+     (8, 7)),
+    ('3x2s2x1', {'kernel': (3, 2), 'stride': (2, 1)}, (9, 7)),
+    ('2x2s3_gaps', {'kernel': (2, 2), 'stride': (3, 3)}, (10, 11)),
+    ('5x5s2_full', {'kernel': (5, 5), 'stride': (2, 2), 'pad': (1, 1),
+                    'pooling_convention': 'full'}, (12, 11)),
+]
+_MAXPOOL_PARAMS = [pytest.param(attrs, hw, dtype, id='%s-%s' % (name, dtype))
+                   for name, attrs, hw in _MAXPOOL_CASES
+                   for dtype in ('float32', 'bfloat16')]
+
+
+def _maxpool_input(hw, dtype):
+    import jax.numpy as jnp
+    rng = np.random.RandomState(0)
+    x = rng.randn(2, 3, *hw).astype(np.float32)
+    # force ties: quantize so equal maxima are common (exact in bf16 too)
+    return jnp.asarray(np.round(x * 2) / 2, dtype)
+
+
+def _maxpool_output_grad(shape, dtype, whole):
+    """A random output gradient, so a misrouted term shows.  ``whole``
+    draws small whole numbers, whose sums over overlapping windows are
+    exact in any order and in bfloat16."""
+    import jax.numpy as jnp
+    rng = np.random.RandomState(1)
+    g = rng.randint(-8, 9, shape) if whole else rng.randn(*shape)
+    return jnp.asarray(g, dtype)
+
+
+def _firstmax_pool_reference(attrs, x, g=None):
+    """FROZEN copy of the max pooling this repo ran until PR 27
+    (``ops/nn.py _max_pool_firstmax``), the reference for what
+    ``Pooling`` must compute: the forward as a max tree over ky*kx
+    shifted strided views (NaN wins and sticks, like HLO maximum), and
+    the backward that routes a window's gradient to its FIRST maximal
+    element and sums overlapping windows in float32, one plane a tap.
+    Returns the output, and the input's gradient under ``g``."""
     import jax
     import jax.numpy as jnp
+    from mxnet_tpu.ops.nn import _pool_out_dim
+    (ky, kx), (sy, sx) = attrs['kernel'], attrs['stride']
+    pad = attrs.get('pad', (0, 0))
+    pads = []
+    for i in range(2):
+        out_d = _pool_out_dim(x.shape[2 + i], attrs['kernel'][i], pad[i],
+                              attrs['stride'][i],
+                              attrs.get('pooling_convention', 'valid'))
+        needed = (out_d - 1) * attrs['stride'][i] + attrs['kernel'][i] \
+            - x.shape[2 + i]
+        pads.append((pad[i], max(needed - pad[i], pad[i])))
+    padded = jnp.pad(x, ((0, 0), (0, 0)) + tuple(pads),
+                     constant_values=jnp.asarray(-jnp.inf, x.dtype))
+    padded_h, padded_w = padded.shape[2:]
+    oh = (padded_h - ky) // sy + 1
+    ow = (padded_w - kx) // sx + 1
+    out = idx = None
+    for t in range(ky * kx):
+        dy, dx = divmod(t, kx)
+        v = jax.lax.slice(
+            padded, (0, 0, dy, dx),
+            (x.shape[0], x.shape[1], dy + (oh - 1) * sy + 1,
+             dx + (ow - 1) * sx + 1), (1, 1, sy, sx))
+        if out is None:
+            out, idx = v, jnp.zeros(v.shape, jnp.int8)
+            continue
+        better = (v > out) | (jnp.isnan(v) & ~jnp.isnan(out))
+        out = jnp.where(better, v, out)
+        idx = jnp.where(better, jnp.int8(t), idx)
+    if g is None:
+        return np.asarray(out, np.float32), None
+    g32 = g.astype(jnp.float32)
+    acc = jnp.zeros(x.shape[:2] + (padded_h, padded_w), jnp.float32)
+    for t in range(ky * kx):
+        dy, dx = divmod(t, kx)
+        acc = acc + jax.lax.pad(
+            jnp.where(idx == t, g32, 0.0), jnp.float32(0.0),
+            ((0, 0, 0), (0, 0, 0),
+             (dy, padded_h - dy - ((oh - 1) * sy + 1), sy - 1),
+             (dx, padded_w - dx - ((ow - 1) * sx + 1), sx - 1)))
+    grad = acc[:, :, pads[0][0]:padded_h - pads[0][1],
+               pads[1][0]:padded_w - pads[1][1]].astype(x.dtype)
+    return np.asarray(out, np.float32), np.asarray(grad, np.float32)
+
+
+def _maxpool(attrs):
     from mxnet_tpu.ops.nn import _pooling_apply
+    attrs = dict(attrs, pool_type='max')
+    return lambda data: _pooling_apply(attrs, [data], True, None)[0][0]
 
-    rng = np.random.RandomState(0)
-    x = rng.randn(2, 3, 9, 9).astype(np.float32)
-    # force ties: quantize so equal maxima are common
-    x = np.round(x * 2) / 2
-    attrs_cases = [
-        {'kernel': (3, 3), 'stride': (2, 2), 'pool_type': 'max'},
-        {'kernel': (2, 2), 'stride': (2, 2), 'pool_type': 'max'},
-        {'kernel': (3, 3), 'stride': (1, 1), 'pad': (1, 1),
-         'pool_type': 'max'},
-        {'kernel': (3, 3), 'stride': (2, 2), 'pool_type': 'max',
-         'pooling_convention': 'full'},
-    ]
-    for attrs in attrs_cases:
-        def run(env):
-            monkeypatch.setenv('MXTPU_POOL_SELECT_SCATTER', env)
-            f = lambda d: _pooling_apply(attrs, [d], True, None)[0][0]
-            out = f(jnp.asarray(x))
-            g = jax.grad(lambda d: jnp.sum(f(d) ** 2))(jnp.asarray(x))
-            return np.asarray(out), np.asarray(g)
 
-        out_new, g_new = run('0')
-        out_ref, g_ref = run('1')
-        np.testing.assert_allclose(out_new, out_ref, err_msg=str(attrs))
-        np.testing.assert_allclose(g_new, g_ref, err_msg=str(attrs))
+def _maxpool_fwd_and_grad(attrs, x, whole_g):
+    import jax
+    out, vjp = jax.vjp(_maxpool(attrs), x)
+    g = _maxpool_output_grad(out.shape, x.dtype, whole_g)
+    return (np.asarray(out, np.float32),
+            np.asarray(vjp(g)[0], np.float32), g)
 
-    # forward NaN propagation matches HLO maximum semantics (gradient
-    # routing under NaN is unspecified in both implementations)
-    xn = x.copy()
+
+@pytest.mark.parametrize('attrs,hw,dtype', _MAXPOOL_PARAMS)
+def test_maxpool_matches_firstmax_reference(attrs, hw, dtype):
+    """Max pooling (reduce_window, whose gradient is select_and_scatter)
+    against the frozen shifted-view reference: forward AND gradient,
+    exactly, including tie windows (on the CPU both route to the FIRST
+    maximal element)."""
+    x = _maxpool_input(hw, dtype)
+    out, grad, g = _maxpool_fwd_and_grad(attrs, x, whole_g=True)
+    out_ref, grad_ref = _firstmax_pool_reference(attrs, x, g)
+    np.testing.assert_array_equal(out, out_ref)
+    assert grad_ref.any()
+    np.testing.assert_array_equal(grad, grad_ref)
+
+
+@pytest.mark.parametrize('attrs,hw,dtype', _MAXPOOL_PARAMS)
+def test_maxpool_gradient_sums_overlaps_like_float32(attrs, hw, dtype):
+    """Under a real-valued output gradient the sum over overlapping
+    windows stays within rounding of the reference's float32 sum: the
+    order of a float32 sum may differ, and on the CPU a bfloat16
+    select_and_scatter rounds after every term (at most
+    ceil(ky/sy)*ceil(kx/sx) of them; on the v5e it rounds once,
+    PERF.md section 6, PR 27)."""
+    x = _maxpool_input(hw, dtype)
+    _, grad, g = _maxpool_fwd_and_grad(attrs, x, whole_g=False)
+    _, grad_ref = _firstmax_pool_reference(attrs, x, g)
+    if dtype == 'float32':
+        np.testing.assert_allclose(grad, grad_ref, rtol=1e-6, atol=1e-6)
+    else:
+        terms = -(-attrs['kernel'][0] // attrs['stride'][0]) * \
+            -(-attrs['kernel'][1] // attrs['stride'][1])
+        np.testing.assert_allclose(grad, grad_ref, rtol=terms * 2.0 ** -8,
+                                   atol=terms * 2.0 ** -8)
+
+
+def test_maxpool_forward_propagates_nan():
+    """Forward NaN propagation matches HLO maximum semantics (gradient
+    routing under NaN is unspecified)."""
+    import jax.numpy as jnp
+    xn = np.array(_maxpool_input((9, 9), 'float32'))
     xn[0, 0, 4, 4] = np.nan
-    attrs = {'kernel': (3, 3), 'stride': (2, 2), 'pool_type': 'max'}
-    outs = {}
-    for env in ('0', '1'):
-        monkeypatch.setenv('MXTPU_POOL_SELECT_SCATTER', env)
-        outs[env] = np.asarray(_pooling_apply(
-            attrs, [jnp.asarray(xn)], True, None)[0][0])
-    np.testing.assert_allclose(outs['0'], outs['1'])
-    assert np.isnan(outs['0']).any()
+    attrs = {'kernel': (3, 3), 'stride': (2, 2)}
+    out = np.asarray(_maxpool(attrs)(jnp.asarray(xn)))
+    out_ref, _ = _firstmax_pool_reference(attrs, jnp.asarray(xn))
+    np.testing.assert_allclose(out, out_ref)
+    assert np.isnan(out).any()
+
+
+def _walk_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else (value,)):
+                sub = getattr(sub, 'jaxpr', sub)
+                if hasattr(sub, 'eqns'):
+                    yield from _walk_eqns(sub)
+
+
+@pytest.mark.parametrize('kernel', [(3, 3), (7, 7)])
+def test_maxpool_backward_is_select_and_scatter(kernel):
+    """What made the max-pool backward a fifth of the step on the chip
+    cannot come back unseen: one select_and_scatter, no float32 value
+    of the padded input's spatial size, no interior padding."""
+    import jax
+    import jax.numpy as jnp
+    pool = _maxpool({'kernel': kernel, 'stride': (2, 2), 'pad': (1, 1)})
+    in_shape = (2, 8, 16, 16)
+
+    def bwd(x):
+        out, vjp = jax.vjp(pool, x)
+        return vjp(jnp.ones_like(out))[0]
+    jaxpr = jax.make_jaxpr(bwd)(jnp.zeros(in_shape, jnp.bfloat16))
+    assert jaxpr.out_avals[0].shape == in_shape
+    assert jaxpr.out_avals[0].dtype == jnp.bfloat16
+    eqns = list(_walk_eqns(jaxpr.jaxpr))
+    assert [eqn.primitive.name for eqn in eqns].count(
+        'select_and_scatter_add') == 1
+    for eqn in eqns:
+        for var in eqn.outvars:
+            assert not (var.aval.dtype == jnp.float32 and
+                        tuple(var.aval.shape[-2:]) in ((18, 18), (16, 16))
+                        ), eqn
+        if eqn.primitive.name == 'pad':
+            assert all(interior == 0 for _, _, interior
+                       in eqn.params['padding_config']), eqn
