@@ -62,21 +62,15 @@ def _build_graph_fn(symbol: Symbol, is_train: bool, monitor_re=None,
         instrument.inc('executor.graph_builds')
     nodes = symbol.topo_nodes()
     out_entries = symbol._outputs
+    units = _mirror_stage_units(nodes, out_entries) \
+        if is_train and monitor_re is None else \
+        [([(i, n)], None, None) for i, n in enumerate(nodes)
+         if not n.is_variable]
 
-    def fn(arg_values: Dict[str, jnp.ndarray],
-           aux_values: Dict[str, jnp.ndarray], rng):
-        entry_vals: Dict[Tuple[int, int], jnp.ndarray] = {}
-        aux_updates: Dict[str, jnp.ndarray] = {}
-        monitored: Dict[str, jnp.ndarray] = {}
-        for i, node in enumerate(nodes):
-            if node.is_variable:
-                if node.name in arg_values:
-                    entry_vals[(id(node), 0)] = arg_values[node.name]
-                elif node.name in aux_values:
-                    entry_vals[(id(node), 0)] = aux_values[node.name]
-                else:
-                    raise MXNetError('unbound variable %s' % node.name)
-                continue
+    def run(members, entry_vals, aux_updates, monitored, rng):
+        """Apply the op nodes ``members`` ((index, node) pairs) in order,
+        reading and writing ``entry_vals``."""
+        for i, node in members:
             op = node.opdef()
             ins = [entry_vals[(id(n), x)] for n, x in node.inputs]
             node_rng = jax.random.fold_in(rng, i) if op.takes_rng else rng
@@ -100,12 +94,96 @@ def _build_graph_fn(symbol: Symbol, is_train: bool, monitor_re=None,
                     slot = aux_nms.index(local_name)
                     var_node = node.inputs[n_main + slot][0]
                     aux_updates[var_node.name] = val
+
+    def fn(arg_values: Dict[str, jnp.ndarray],
+           aux_values: Dict[str, jnp.ndarray], rng):
+        entry_vals: Dict[Tuple[int, int], jnp.ndarray] = {}
+        aux_updates: Dict[str, jnp.ndarray] = {}
+        monitored: Dict[str, jnp.ndarray] = {}
+        for node in nodes:
+            if not node.is_variable:
+                continue
+            if node.name in arg_values:
+                entry_vals[(id(node), 0)] = arg_values[node.name]
+            elif node.name in aux_values:
+                entry_vals[(id(node), 0)] = aux_values[node.name]
+            else:
+                raise MXNetError('unbound variable %s' % node.name)
+        for members, taken, given in units:
+            if taken is None:
+                run(members, entry_vals, aux_updates, monitored, rng)
+                continue
+
+            # one mirror stage: kept are its inputs, its inside is
+            # computed again in the backward pass
+            def stage(values, rng, members=members, taken=taken,
+                      given=given):
+                local, aux_local = dict(zip(taken, values)), {}
+                run(members, local, aux_local, None, rng)
+                return [local[e] for e in given], aux_local
+
+            outs, aux_local = jax.checkpoint(stage)(
+                [entry_vals[e] for e in taken], rng)
+            entry_vals.update(zip(given, outs))
+            aux_updates.update(aux_local)
         outputs = [entry_vals[(id(n), x)] for n, x in out_entries]
         if monitor_re is not None:
             return outputs, aux_updates, monitored
         return outputs, aux_updates
 
     return fn
+
+
+def _mirror_stage(node):
+    return node._extra_attr.get('mirror_stage') or \
+        node._extra_attr.get('__mirror_stage__')
+
+
+def _mirror_stage_units(nodes, out_entries):
+    """The op nodes of a graph in order, as ``(members, taken, given)``:
+    a run of consecutive op nodes that carry the same ``mirror_stage``
+    attribute (``mx.AttrScope(mirror_stage=...)``, the reference's
+    attribute for its memory-mirror pass, ``graph_executor.cc``) is one
+    unit with the entries it takes from outside and those it gives to
+    later nodes or to the graph's outputs; every other op node is a unit
+    of its own with ``taken`` None."""
+    ops = [(i, n) for i, n in enumerate(nodes) if not n.is_variable]
+    units, run = [], []
+    for item in ops:
+        stage = _mirror_stage(item[1])
+        if run and stage != _mirror_stage(run[-1][1]):
+            units.append(run)
+            run = []
+        if stage is None:
+            units.append([item])
+        else:
+            run.append(item)
+    if run:
+        units.append(run)
+    used_later = {}     # entry -> index of the last op node that reads it
+    for i, node in ops:
+        for n, x in node.inputs:
+            used_later[(id(n), x)] = i
+    for n, x in out_entries:
+        used_later[(id(n), x)] = len(nodes)
+    out = []
+    for members in units:
+        if _mirror_stage(members[0][1]) is None:
+            out.append((members, None, None))
+            continue
+        inside = {id(n) for _, n in members}
+        taken, given = [], []
+        for _, node in members:
+            for n, x in node.inputs:
+                if id(n) not in inside and (id(n), x) not in taken:
+                    taken.append((id(n), x))
+        last = members[-1][0]
+        for _, node in members:
+            for j in range(node.num_outputs()):
+                if used_later.get((id(node), j), -1) > last:
+                    given.append((id(node), j))
+        out.append((members, taken, given))
+    return out
 
 
 def mirror_wrap(f):

@@ -69,6 +69,9 @@ class Initializer(object):
             self._init_zero(name, arr)
         elif name.endswith('moving_avg'):
             self._init_zero(name, arr)
+        elif name.endswith(('expert_load', 'expert_count')):
+            # SparseExperts' counting states (its expert_bias is a bias)
+            self._init_zero(name, arr)
         elif 'begin_state' in name:
             self._init_zero(name, arr)
         elif name.endswith('parameters'):

@@ -53,6 +53,7 @@ __all__ = [
     'drop_metric', 'drop_labeled_metrics',
     'hist_delta', 'hist_merge', 'HistogramWindow',
     'inc', 'set_gauge', 'observe', 'observe_hist', 'timed', 'hist_span',
+    'add_device_source', 'take_device_sources',
     'decision', 'recent_decisions', 'on_decision', 'remove_decision_sink',
     'count_traces', 'count_trace', 'trace_redirect',
     'metrics_snapshot', 'dump_metrics', 'reset_metrics',
@@ -867,6 +868,42 @@ def observe(name, seconds):
 def observe_hist(name, value, exemplar=None):
     if _metrics_on:
         histogram(name).observe(value, exemplar)
+
+
+# ---------------------------------------------------------------------------
+# Counters computed on the device
+# ---------------------------------------------------------------------------
+
+# What a compiled step counts itself (an operator's auxiliary states: the
+# assignments a SparseExperts layer routed) stays on the device step after
+# step and becomes counters here only where the fit loop waits for the
+# device anyway: the metric drain (``metric.EvalMetric._drain_device``)
+# takes every source's arrays into its one batched sync and then lets the
+# source write its counters.  No step gains a host sync.
+_device_sources = []              # weak references to bound methods
+
+
+def add_device_source(method):
+    """``method()`` returns ``(arrays, apply)`` or None: device arrays
+    for the drain's sync, and the function that reads them afterwards."""
+    import weakref
+    _device_sources.append(weakref.WeakMethod(method))
+
+
+def take_device_sources():
+    if not _metrics_on or not _device_sources:
+        return ()
+    taken, alive = [], []
+    for ref in _device_sources:
+        method = ref()
+        if method is None:
+            continue
+        alive.append(ref)
+        got = method()
+        if got is not None:
+            taken.append(got)
+    _device_sources[:] = alive
+    return taken
 
 
 # ---------------------------------------------------------------------------

@@ -148,19 +148,24 @@ class EvalMetric(object):
         from . import iowatch as _iowatch
         from . import perfwatch as _perfwatch
         from .engine import sync
+        # counters the step computed on the device ride the same sync
+        counted = instrument.take_device_sources()
         # one completion barrier for the whole batch of states.  The
         # goodput ledger charges it to metric_drain — exactly one
         # ledger event per counted host sync, so the exclusive-bucket
         # invariant is checkable against the sync-budget counters
         with _perfwatch.phase('metric_drain'), \
                 _iowatch.account('metric_drain'):
-            sync([x for _, s, n in pending for x in (s, n)] + list(extra))
+            sync([x for _, s, n in pending for x in (s, n)] + list(extra) +
+                 [x for arrays, _ in counted for x in arrays])
         if pending:
             instrument.inc('metric.host_syncs')
         elif extra:
             instrument.inc('health.host_syncs')
         for metric, s, n in pending:
             metric._apply_drained(s, n)
+        for _, apply in counted:
+            apply()
         # applied last: the divergence action may raise, and the metric
         # sums above must land first so the raise site sees them
         _health._piggyback_apply(extra)

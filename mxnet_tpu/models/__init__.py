@@ -4,6 +4,7 @@ from . import mlp, lenet, alexnet, vgg, resnet, inception_bn, inception_v3
 from . import googlenet, resnext, inception_resnet_v2
 from . import lstm_lm
 from . import transformer_lm
+from . import lfm2_moe
 from . import ssd
 
 _MODELS = {
@@ -29,6 +30,7 @@ _MODELS = {
                                                    **kw),
     'lstm_lm': lstm_lm.get_symbol,
     'transformer_lm': transformer_lm.get_symbol,
+    'lfm2_moe': lfm2_moe.get_symbol,
     'ssd-vgg16': ssd.get_symbol,
     'ssd-vgg16-train': ssd.get_symbol_train,
 }

@@ -85,6 +85,7 @@ class Module(BaseModule):
         # same way the metric fold key does; the ref is the per-fit
         # monitor whose device state the step threads
         self._fused_health_key = None
+        self._aux_counted = None    # nodes with OpDef.aux_counters
         self._health_ref = None
         # warm-start AOT executables for the fused step, keyed on the
         # batch signature (compile_cache.batch_sig); pending holds the
@@ -735,6 +736,37 @@ class Module(BaseModule):
         self._fused_opt_state = opt_state
         self._overlay_updater_states()
         self._fused_unavailable = False
+        if instrument.metrics_enabled() and self._aux_counted is None:
+            # nodes whose auxiliary states are counts (OpDef.aux_counters)
+            self._aux_counted = []
+            for n in self._symbol.topo_nodes():
+                if n.is_variable or not n.opdef().aux_counters:
+                    continue
+                local = n.opdef().aux_names(n.attrs)
+                self._aux_counted.append(
+                    (n.opdef().aux_counters, local,
+                     [v.name for v, _ in n.inputs[-len(local):]], {}))
+            if self._aux_counted:
+                instrument.add_device_source(self._aux_counter_source)
+
+    def _aux_counter_source(self):
+        """For the metric drain (``instrument.take_device_sources``): the
+        counting auxiliary states as they stand after the last dispatched
+        step, and the function that turns them into counters once the
+        drain has waited for them."""
+        if not self.binded or self._fused is None:
+            return None
+        aux = self._exec_group.execs[0].aux_dict
+        arrays = [aux[name].handle for _, _, names, _ in self._aux_counted
+                  for name in names]
+
+        def apply():
+            for write, local, names, seen in self._aux_counted:
+                now = {k: np.asarray(aux[name].handle)
+                       for k, name in zip(local, names)}
+                write(now, seen.get('before'))
+                seen['before'] = now
+        return arrays, apply
 
     def _build_fit_shardings(self, trainable, frozen, exec_, opt_state):
         """The exact sharding pytrees for this fused program: per-name
@@ -811,6 +843,24 @@ class Module(BaseModule):
             if name in self._fused_opt_state:
                 upd.states[idx] = self._functional_opt.state_to_updater(
                     name, self._fused_opt_state[name])
+
+    def fused_step_hlo(self):
+        """The optimized HLO text of every fused fit step this module
+        holds as a compiled executable, by batch signature: those of a
+        warm start, and those the performance plane captured
+        (``MXTPU_PERFWATCH``); a step that ran through ``jit`` alone is
+        not held.  Every instruction's ``op_name`` carries the scopes
+        ``<Operator>/<node>`` and ``forward_backward`` / ``optimizer`` /
+        ``metric``, which is how a device trace's op events find their
+        operator."""
+        return {sig: aot.as_text() for sig, aot in self._fused_aot.items()}
+
+    def fused_optimizer_state(self):
+        """The fused fit step's optimizer state as the step holds it on
+        the device: parameter name -> the optimizer's state of that
+        parameter (Adam: ``(mean, variance)``).  None before the first
+        fused step.  Read it; the next step donates these buffers."""
+        return self._fused_opt_state
 
     def _run_fused(self, data_batch, metric=None):
         """One fused step on ``data_batch``: prepare, dispatch, commit."""

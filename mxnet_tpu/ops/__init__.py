@@ -8,6 +8,7 @@ reference's static registration at library load
 from .registry import get_op, list_ops, register, register_simple, alias, OpDef
 from . import tensor  # noqa: F401
 from . import nn  # noqa: F401
+from . import lm  # noqa: F401
 from . import optim  # noqa: F401
 from . import rnn_op  # noqa: F401
 from . import vision  # noqa: F401

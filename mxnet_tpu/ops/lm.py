@@ -1,0 +1,392 @@
+"""Language-model layer operators: RMSNorm, RotaryEmbedding, SwiGLU,
+GatedShortConv and SparseExperts.
+
+Beyond the reference's 2017 op set: what a sparse decoder-only language
+model (``models/lfm2_moe.py``) needs of a Symbol graph, each with shape
+inference so that ``Module``, ``simple_bind`` and the JSON round trip see
+it.  Statistics (norms, the router) are computed in float32 whatever the
+compute dtype; matrix products take their inputs' dtype and accumulate in
+float32.  ``models/lfm2_moe_reference.py`` is the plain float32 statement
+of the same equations, and ``tests/test_lfm2_moe.py`` holds each op to it.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from .registry import register, register_simple
+from .nn import _complete
+
+
+# ---------------------------------------------------------------------------
+# RMSNorm: y = x / sqrt(mean(x^2) + eps) * gamma over the last axis
+# ---------------------------------------------------------------------------
+
+def _rms_norm_apply(attrs, inputs, is_train, rng):
+    x, gamma = inputs
+    xf = x.astype(jnp.float32)
+    scale = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) +
+                          float(attrs.get('eps', 1e-5)))
+    return [(xf * scale * gamma.astype(jnp.float32)).astype(x.dtype)], {}
+
+
+def _rms_norm_complete(attrs, in_shapes):
+    if in_shapes[0] is not None:
+        _complete(in_shapes, 1, (in_shapes[0][-1],))
+    return in_shapes
+
+
+register('RMSNorm', _rms_norm_apply,
+         input_names=lambda attrs: ['data', 'gamma'],
+         num_outputs=lambda attrs: 1,
+         complete_shapes=_rms_norm_complete,
+         attr_defaults={'eps': 1e-5}, hint='rmsnorm',
+         doc='Root-mean-square norm over the last axis, statistics in '
+             'float32.')
+
+
+# ---------------------------------------------------------------------------
+# RotaryEmbedding over (..., T, D) at positions 0..T-1, half-split pairing
+# ---------------------------------------------------------------------------
+
+def _rotary(x, theta=10000.0):
+    t, d = x.shape[-2:]
+    half = d // 2
+    inv_freq = float(theta) ** (-jnp.arange(half, dtype=jnp.float32) *
+                                2.0 / d)
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+register_simple('RotaryEmbedding', _rotary,
+                attr_defaults={'theta': 10000.0}, hint='rotary',
+                doc='Rotary position embedding of (..., T, D) at positions '
+                    '0..T-1; element i turns with element i + D/2.')
+
+
+# ---------------------------------------------------------------------------
+# SwiGLU: silu(gate) * up, the gated feed-forward's elementwise middle
+# ---------------------------------------------------------------------------
+
+register_simple('SwiGLU', lambda gate, up: jax.nn.silu(gate) * up,
+                ninputs=2, input_names=['gate', 'up'], hint='swiglu',
+                doc='silu(gate) * up.')
+
+
+# ---------------------------------------------------------------------------
+# GatedShortConv: what lies between the two projections of a gated short
+# convolution.  data (N, T, 3C) = [B, C, u]; g = B * u; c_t = sum_j
+# k_j g_{t-j} per channel (depthwise, causal, zeros before the start);
+# output C * c.  weight (C, taps).  Written as shifted multiply-adds, which
+# XLA fuses into one pass over the activations: a grouped convolution with
+# one channel a group has no work for the MXU.
+# ---------------------------------------------------------------------------
+
+def _gated_short_conv_apply(attrs, inputs, is_train, rng):
+    bcu, kernel = inputs
+    b, c, u = jnp.split(bcu, 3, axis=-1)
+    g = b * u
+    t = g.shape[1]
+    mixed = kernel[:, 0] * g
+    for j in range(1, kernel.shape[1]):
+        mixed = mixed + kernel[:, j] * \
+            jnp.pad(g, ((0, 0), (j, 0), (0, 0)))[:, :t]
+    return [c * mixed], {}
+
+
+def _gated_short_conv_complete(attrs, in_shapes):
+    if in_shapes[0] is not None:
+        _complete(in_shapes, 1, (in_shapes[0][-1] // 3, int(attrs['kernel'])))
+    return in_shapes
+
+
+register('GatedShortConv', _gated_short_conv_apply,
+         input_names=lambda attrs: ['data', 'weight'],
+         num_outputs=lambda attrs: 1,
+         complete_shapes=_gated_short_conv_complete,
+         attr_defaults={'kernel': 3}, hint='gatedshortconv',
+         doc='Gate, causal depthwise convolution along T, gate: '
+             '(N, T, 3C) -> (N, T, C).')
+
+
+# ---------------------------------------------------------------------------
+# SparseExperts: this device's share of a layer of routed experts.
+#
+# The router scores every token against all ``num_experts`` experts
+# (sigmoid, in float32), chooses ``experts_per_tok`` by score plus the
+# selection bias, and weighs them by their scores normalised over the
+# chosen.  The op is told which experts it holds (``experts_held`` =
+# (first, count)); it sorts the assignments by expert, those that landed
+# on other devices' experts last, takes the rows at the head of that order
+# into a buffer, runs three grouped matrix products over the buffer, and
+# combines.  What the absent experts would have added is left out: under
+# expert parallelism their devices add it.
+#
+# The buffer is what a device with static shapes receives into: four times
+# the share a balanced router sends to the held experts (``_room``), each
+# expert's rows together and starting on a multiple of ``align`` rows.  The
+# products run over all of it whatever arrived, the rows that hold no
+# assignment as part of their expert's group and weighed by nothing, and a
+# group's first row is a tile's first row, so a step costs the same however
+# the router's choices fall.  A step that sends more than the buffer holds
+# takes the other branch of a ``cond``, the same computation over a buffer
+# with room for every assignment: no token is ever dropped whatever the
+# imbalance, and ``expert_count`` says how often that happened.
+# ---------------------------------------------------------------------------
+
+def _collect(rows, slots, k):
+    """Each token's sum over its k assignments of the buffer's ``rows``;
+    ``slots`` (T * k,) is each assignment's row, or past the last row for
+    an assignment that is not in the buffer."""
+    room = rows.shape[0]
+    taken = jnp.take(rows, jnp.minimum(slots, room - 1), axis=0)
+    taken = jnp.where((slots < room)[:, None], taken, 0)
+    return taken.reshape(-1, k, rows.shape[-1]).sum(axis=1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x, tokens, slots, k):
+    """The rows of ``x`` (T, H) that the buffer's rows hold: ``tokens`` is
+    each buffer row's token."""
+    return jnp.take(x, tokens, axis=0)
+
+
+def _dispatch_fwd(x, tokens, slots, k):
+    return _dispatch(x, tokens, slots, k), (tokens, slots)
+
+
+def _dispatch_bwd(k, res, g):
+    # the transpose of a gather by a one-to-one map is a gather by its
+    # inverse: no scatter
+    tokens, slots = res
+    return (_collect(g, slots, k), None, None)
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine(ys, tokens, slots, k):
+    """Each token's sum over its k assignments of the buffer's rows."""
+    return _collect(ys, slots, k)
+
+
+def _combine_fwd(ys, tokens, slots, k):
+    return _combine(ys, tokens, slots, k), (tokens, slots)
+
+
+def _combine_bwd(k, res, g):
+    tokens, slots = res
+    return (jnp.take(g, tokens, axis=0), None, None)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs`` (M, K) rows in consecutive groups against ``rhs`` (G, K, N),
+    group ``g`` with ``rhs[g]``.  ``jax.lax.ragged_dot``: XLA's own grouped
+    product on the TPU, with its transposes for both gradients."""
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=lhs.dtype)
+
+
+def route(x, router, bias, k, normalise, scaling):
+    """Chosen experts (T, k) and their float32 weights (T, k)."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32).T,
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    weights = jnp.take_along_axis(scores, chosen, axis=1)
+    if normalise:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
+    return chosen, weights * scaling
+
+
+def _held(attrs):
+    first, count = attrs['experts_held']
+    return int(first), int(count)
+
+
+def _room(assignments, held, experts):
+    """``(rows, whole, align)``: the rows of the two buffers the held
+    experts' products may run over, four times what a balanced router
+    sends to ``held`` of ``experts`` and one that holds every assignment
+    however they fall, and the multiple of rows each expert's rows start
+    on: 512 (a multiple of the grouped product's row tile on the TPU) or,
+    for a small layer, what costs at most a quarter of the first buffer."""
+    share = 4 * -(-assignments * held // experts)
+    align = min(512, max(1, min(share, assignments) // (4 * held)))
+    align = 1 << (align.bit_length() - 1)
+    whole = -(-(assignments + held * align) // align) * align
+    return min(-(-share // align) * align, whole), whole, align
+
+
+def _buffered(room, align, k, floats, ints):
+    """The held experts' part of the layer over a buffer of ``room`` rows
+    that holds every assignment that landed on them."""
+    x, weights, w1, w3, w2 = floats
+    key, order, inverse, group_sizes = ints
+    count = group_sizes.shape[0]
+    with jax.named_scope('dispatch'):
+        # expert e has ``padded[e]`` rows of the buffer, up to ``ends[e]``,
+        # and fills the first ``group_sizes[e]``; ``shift[e]`` is how far
+        # its first row lies past its first place in the sorted order
+        padded = -(-group_sizes // align) * align
+        ends = jnp.cumsum(padded)
+        shift = (ends - padded) - (jnp.cumsum(group_sizes) - group_sizes)
+        row = jnp.arange(room)
+        expert = jnp.minimum((row[:, None] >= ends[None, :]).sum(axis=1),
+                             count - 1)
+        filled = row - (ends - padded)[expert] < group_sizes[expert]
+        assignment = jnp.take(order, jnp.clip(row - shift[expert], 0,
+                                              order.shape[0] - 1))
+        tokens = assignment // k
+        slots = jnp.where(key < count,
+                          inverse + shift[jnp.minimum(key, count - 1)], room)
+        xs = _dispatch(x, tokens, slots, k)
+        # sizes that cover the buffer: the last group takes its rest
+        covering = jnp.concatenate([padded[:-1],
+                                    room - padded[:-1].sum()[None]])
+    with jax.named_scope('experts'):
+        hidden = jax.nn.silu(grouped_matmul(xs, w1, covering)) * \
+            grouped_matmul(xs, w3, covering)
+        ys = grouped_matmul(hidden, w2, covering)
+    with jax.named_scope('combine'):
+        gate = jnp.take(weights.reshape(-1), assignment)[:, None]
+        ys = jnp.where(filled[:, None], ys.astype(jnp.float32) * gate, 0)
+        return _combine(ys.astype(x.dtype), tokens, slots, k)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _either_buffer(rooms, k, fits, floats, ints):
+    """``_buffered`` over ``rooms[0]`` rows where the step ``fits`` them
+    and over ``rooms[1]`` where it does not (``rooms[2]`` is the
+    alignment).  The backward pass computes the taken branch's forward
+    pass again inside its own branch, so that nothing a branch keeps has
+    to exist for both."""
+    return jax.lax.cond(
+        fits, *(functools.partial(_buffered, room, rooms[2], k)
+                for room in rooms[:2]), floats, ints)
+
+
+def _either_buffer_fwd(rooms, k, fits, floats, ints):
+    return _either_buffer(rooms, k, fits, floats, ints), (fits, floats, ints)
+
+
+def _either_buffer_bwd(rooms, k, res, g):
+    fits, floats, ints = res
+
+    def backward(room, floats, ints, g):
+        return jax.vjp(lambda *f: _buffered(room, rooms[2], k, f, ints),
+                       *floats)[1](g)
+
+    return (None,
+            jax.lax.cond(fits, *(functools.partial(backward, room)
+                                 for room in rooms[:2]), floats, ints, g),
+            None)
+
+
+_either_buffer.defvjp(_either_buffer_fwd, _either_buffer_bwd)
+
+
+def _sparse_experts_apply(attrs, inputs, is_train, rng):
+    x, router, w1, w3, w2, bias, _, count_so_far = inputs
+    k = int(attrs['experts_per_tok'])
+    first, count = _held(attrs)
+    with jax.named_scope('router'):
+        chosen, weights = route(x, router, bias, k,
+                                bool(attrs['norm_topk_prob']),
+                                float(attrs['routed_scaling_factor']))
+    with jax.named_scope('dispatch'):
+        local = chosen.reshape(-1) - first
+        mine = (local >= 0) & (local < count)
+        # absent experts' assignments sort last
+        key = jnp.where(mine, local, count)
+        order = jnp.argsort(key, stable=True)
+        inverse = jnp.argsort(order)
+        group_sizes = jnp.bincount(key, length=count + 1)[:count] \
+            .astype(jnp.int32)
+    room, whole, align = _room(chosen.size, count, int(attrs['num_experts']))
+    floats = (x, weights, w1, w3, w2)
+    ints = (key, order, inverse, group_sizes)
+    if room < whole:
+        fits = (-(-group_sizes // align) * align).sum() <= room
+        y = _either_buffer((room, whole, align), k, fits, floats, ints)
+    else:
+        fits = True
+        y = _buffered(whole, align, k, floats, ints)
+    load = group_sizes.astype(jnp.float32)
+    held = jnp.sum(mine).astype(jnp.float32)
+    step = jnp.stack([jnp.float32(chosen.size), held, held - load.sum(),
+                      1 - jnp.float32(fits)])
+    return [y], {'expert_load': load,
+                 'expert_count': count_so_far.astype(jnp.float32) + step}
+
+
+def _sparse_experts_counters(now, before):
+    """The layer's counts since the last drain into the registry, and how
+    uneven the last step's load was over the experts held."""
+    from .. import instrument
+    count = now['expert_count'] - (before['expert_count'] if before else 0)
+    instrument.inc('moe.assignments', int(count[0]))
+    instrument.inc('moe.assignments_held', int(count[1]))
+    instrument.inc('moe.tokens_dropped', int(count[2]))
+    instrument.inc('moe.steps_over_capacity', int(count[3]))
+    load = now['expert_load']
+    if load.sum() > 0:
+        instrument.observe_hist('moe.load_max_over_mean',
+                                float(load.max() / load.mean()))
+
+
+def _sparse_experts_complete(attrs, in_shapes):
+    if in_shapes[0] is None:
+        return in_shapes
+    hidden = in_shapes[0][-1]
+    _, count = _held(attrs)
+    _complete(in_shapes, 1, (int(attrs['num_experts']), hidden))
+    if in_shapes[2] is not None:
+        width = in_shapes[2][2]
+    elif attrs.get('expert_hidden') is not None:
+        width = int(attrs['expert_hidden'])
+    else:
+        return in_shapes
+    _complete(in_shapes, 2, (count, hidden, width))
+    _complete(in_shapes, 3, (count, hidden, width))
+    _complete(in_shapes, 4, (count, width, hidden))
+    return in_shapes
+
+
+def _sparse_experts_aux_shapes(attrs, in_shapes):
+    return [(int(attrs['num_experts']),), (_held(attrs)[1],), (4,)]
+
+
+register('SparseExperts', _sparse_experts_apply,
+         input_names=lambda attrs: ['data', 'router_weight', 'w1_weight',
+                                    'w3_weight', 'w2_weight'],
+         num_outputs=lambda attrs: 1,
+         aux_names=lambda attrs: ['expert_bias', 'expert_load',
+                                  'expert_count'],
+         aux_shape=_sparse_experts_aux_shapes,
+         complete_shapes=_sparse_experts_complete,
+         keep_dtype=('router_weight',),
+         aux_counters=_sparse_experts_counters,
+         attr_defaults={'num_experts': None, 'experts_held': None,
+                        'experts_per_tok': 1, 'expert_hidden': None,
+                        'norm_topk_prob': True,
+                        'routed_scaling_factor': 1.0},
+         hint='sparseexperts',
+         doc='The held experts\' part of a routed SwiGLU expert layer: '
+             'data (T, H) -> (T, H).  Auxiliary states: expert_bias '
+             '(num_experts,), added to the scores for the choice only and '
+             'never trained; expert_load (held,), the assignments each held '
+             'expert received in the last step; expert_count (4,), running '
+             'totals of assignments routed, assignments that landed on held '
+             'experts, tokens dropped (always 0), and steps that sent the '
+             'held experts more than their buffer holds.')

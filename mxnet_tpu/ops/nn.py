@@ -296,7 +296,7 @@ register('Pooling', _pooling_apply,
 # ---------------------------------------------------------------------------
 
 _ACTS = {'relu': jax.nn.relu, 'sigmoid': jax.nn.sigmoid, 'tanh': jnp.tanh,
-         'softrelu': jax.nn.softplus}
+         'softrelu': jax.nn.softplus, 'silu': jax.nn.silu}
 
 register_simple('Activation',
                 lambda x, act_type='relu': _ACTS[act_type](x),
@@ -780,7 +780,7 @@ def _embedding_complete(attrs, in_shapes):
 register('Embedding', _embedding_apply,
          input_names=lambda attrs: ['data', 'weight'],
          num_outputs=lambda attrs: 1,
-         complete_shapes=_embedding_complete,
+         complete_shapes=_embedding_complete, keep_dtype=('data',),
          attr_defaults={'dtype': 'float32'}, hint='embedding')
 
 
@@ -905,6 +905,15 @@ def _flash_attention_apply(attrs, inputs, is_train, rng):
     # ring attention over the mesh axis instead of a local kernel.
     from ..parallel.sp import current_sp_axis, current_sp_mode
     axis = current_sp_axis()
+    if k.shape[1] != q.shape[1]:
+        # grouped queries: fewer key-value heads than query heads
+        if axis is not None:
+            raise NotImplementedError('FlashAttention: grouped-query '
+                                      'attention under sequence parallelism')
+        from .pallas_attention import gqa_attention
+        return [gqa_attention(q, k, v, causal=causal,
+                              scale=float(scale) if scale is not None
+                              else None)], {}
     if axis is not None:
         from ..parallel.ring import ring_attention, full_attention
         if scale is not None:

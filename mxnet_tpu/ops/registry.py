@@ -89,6 +89,8 @@ class OpDef:
                  arg_order: Optional[List[str]] = None,
                  aux_shape: Optional[Callable] = None,
                  dynamic_scalars: tuple = (),
+                 keep_dtype: tuple = (),
+                 aux_counters: Optional[Callable] = None,
                  doc: str = ''):
         self.name = name
         self.apply = apply_fn
@@ -126,6 +128,17 @@ class OpDef:
         # imperative_invoke).  Only attrs used purely arithmetically in
         # apply() belong here (no Python control flow on the value).
         self.dynamic_scalars = tuple(dynamic_scalars)
+        # input names whose variables a mixed-precision step must NOT cast
+        # to the compute dtype (make_fit_step): Embedding's token ids
+        # (bf16 rounds whole numbers above 256), SparseExperts' router
+        # (its product is float32 by definition)
+        self.keep_dtype = tuple(keep_dtype)
+        # (now, before) -> None: writes instrument counters from a node's
+        # auxiliary states (op-local name -> numpy array) at this metric
+        # drain and at the one before (None at the first); how an op's
+        # device-side counts reach the registry without a sync of their
+        # own (instrument.add_device_source, Module._aux_counter_source)
+        self.aux_counters = aux_counters
         self.doc = doc
 
     def canon_attrs(self, attrs: dict) -> dict:

@@ -1,4 +1,12 @@
-"""Mixture-of-Experts with expert parallelism over a device mesh.
+"""Mixture-of-Experts with expert parallelism over a device mesh: the
+top-1, fixed-capacity form (tokens over an expert's capacity are DROPPED,
+dispatch is a dense ``(T, E, C)`` one-hot), as plain JAX functions that no
+Symbol or ``Module`` reaches.  What trains through ``Module.fit`` is the
+``SparseExperts`` operator (``ops/lm.py``: top-k, no token dropped, grouped
+products over sorted assignments), which computes one device's share and
+has no exchange yet; this module keeps the one thing that operator lacks,
+the ``all_to_all`` exchange over an ``expert`` mesh axis (ROADMAP.md, Reach
+item 1), and its dry run in ``__graft_entry__.dryrun_multichip``.
 
 An extension beyond the 2017-era reference (SURVEY.md §2.4 lists expert
 parallelism as absent there), included because the TPU-native framework

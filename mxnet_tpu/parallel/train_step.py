@@ -106,7 +106,14 @@ def make_fit_step(symbol: Symbol, functional_opt, data_names=(),
     from ..fuse import apply_fuse_passes
     symbol = apply_fuse_passes(symbol, True)
     graph_fn = _build_graph_fn(symbol, True)
-    data_names = tuple(data_names)
+    # inputs an operator wants in their own dtype (OpDef.keep_dtype):
+    # Embedding's token ids, which bf16 would round above 256, and the
+    # float32 router of SparseExperts
+    uncast = {n.inputs[i][0].name
+              for n in symbol.topo_nodes() if not n.is_variable
+              for i, name in enumerate(n.opdef().input_names(n.attrs))
+              if name in n.opdef().keep_dtype and n.inputs[i][0].is_variable}
+    data_names = tuple(n for n in data_names if n not in uncast)
 
     def step(params, frozen, aux, opt_state, batch, lr_t, rng,
              metric_state=None, health_state=None):
@@ -123,7 +130,7 @@ def make_fit_step(symbol: Symbol, functional_opt, data_names=(),
             if compute_dtype is not None:
                 merged = {k: (v.astype(compute_dtype)
                               if jnp.issubdtype(v.dtype, jnp.floating)
-                              else v)
+                              and k not in uncast else v)
                           for k, v in merged.items()}
             merged.update(batch)
             outs, aux_upd = graph_fn(merged, aux, rng)

@@ -46,9 +46,12 @@ def test_monitor_taps():
 
 
 def test_profiler_chrome_trace(tmp_path):
-    from mxnet_tpu import profiler
+    from mxnet_tpu import instrument, profiler
     f = str(tmp_path / 'prof.json')
     profiler.profiler_set_config(filename=f)
+    # the dump holds every span of the process: drop what test files that
+    # ran earlier in this worker left behind (a serving test's requests)
+    instrument.clear_trace()
     with profiler.Scope('step'):
         nd.dot(nd.ones((64, 64)), nd.ones((64, 64))).wait_to_read()
     profiler.dump_profile()
