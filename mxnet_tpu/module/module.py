@@ -86,6 +86,7 @@ class Module(BaseModule):
         # monitor whose device state the step threads
         self._fused_health_key = None
         self._aux_counted = None    # nodes with OpDef.aux_counters
+        self._aux_shapes = None     # and their inputs' bound shapes
         self._health_ref = None
         # warm-start AOT executables for the fused step, keyed on the
         # batch signature (compile_cache.batch_sig); pending holds the
@@ -330,6 +331,8 @@ class Module(BaseModule):
         self._label_shapes = [(n, tuple(s)) for n, s in label_shapes] \
             if label_shapes is not None else None
         self._exec_group.reshape(self._data_shapes, self._label_shapes)
+        if self._aux_counted:
+            self._aux_shapes = self._aux_counted_shapes()
 
     # -- dp×tp sharded fit (docs/parallel.md) ------------------------------
     def _set_parallel(self, mesh, partition=None):
@@ -737,17 +740,35 @@ class Module(BaseModule):
         self._overlay_updater_states()
         self._fused_unavailable = False
         if instrument.metrics_enabled() and self._aux_counted is None:
-            # nodes whose auxiliary states are counts (OpDef.aux_counters)
-            self._aux_counted = []
-            for n in self._symbol.topo_nodes():
-                if n.is_variable or not n.opdef().aux_counters:
-                    continue
-                local = n.opdef().aux_names(n.attrs)
-                self._aux_counted.append(
-                    (n.opdef().aux_counters, local,
-                     [v.name for v, _ in n.inputs[-len(local):]], {}))
+            self._aux_counted = self._find_aux_counted()
             if self._aux_counted:
+                self._aux_shapes = self._aux_counted_shapes()
                 instrument.add_device_source(self._aux_counter_source)
+
+    def _find_aux_counted(self):
+        """The nodes whose auxiliary states are counts
+        (``OpDef.aux_counters``): for each the writer, the states' op-local
+        and variable names, the node's attributes, its other inputs, and
+        what the last drain saw."""
+        found = []
+        for n in self._symbol.topo_nodes():
+            if n.is_variable or not n.opdef().aux_counters:
+                continue
+            local = n.opdef().aux_names(n.attrs)
+            found.append((n.opdef().aux_counters, local,
+                          [v.name for v, _ in n.inputs[-len(local):]],
+                          n.opdef().canon_attrs(n.attrs),
+                          n.inputs[:-len(local)], {}))
+        return found
+
+    def _aux_counted_shapes(self):
+        """The shapes of those nodes' other inputs as bound now."""
+        fed = [entries for *_, entries, _ in self._aux_counted]
+        bound = {k: v.shape for k, v in
+                 self._exec_group.execs[0].arg_dict.items()}
+        shapes = iter(sym.Symbol([e for entries in fed for e in entries])
+                      .infer_shape(**bound)[1])
+        return [[next(shapes) for _ in entries] for entries in fed]
 
     def _aux_counter_source(self):
         """For the metric drain (``instrument.take_device_sources``): the
@@ -757,14 +778,15 @@ class Module(BaseModule):
         if not self.binded or self._fused is None:
             return None
         aux = self._exec_group.execs[0].aux_dict
-        arrays = [aux[name].handle for _, _, names, _ in self._aux_counted
+        arrays = [aux[name].handle for _, _, names, *_ in self._aux_counted
                   for name in names]
 
         def apply():
-            for write, local, names, seen in self._aux_counted:
+            for (write, local, names, attrs, _, seen), shapes in zip(
+                    self._aux_counted, self._aux_shapes):
                 now = {k: np.asarray(aux[name].handle)
                        for k, name in zip(local, names)}
-                write(now, seen.get('before'))
+                write(now, seen.get('before'), attrs, shapes)
                 seen['before'] = now
         return arrays, apply
 

@@ -131,13 +131,16 @@ register('GatedShortConv', _gated_short_conv_apply,
 # The buffer is what a device with static shapes receives into: four times
 # the share a balanced router sends to the held experts (``_room``), each
 # expert's rows together and starting on a multiple of ``align`` rows.  The
-# products run over all of it whatever arrived, the rows that hold no
-# assignment as part of their expert's group and weighed by nothing, and a
-# group's first row is a tile's first row, so a step costs the same however
-# the router's choices fall.  A step that sends more than the buffer holds
-# takes the other branch of a ``cond``, the same computation over a buffer
-# with room for every assignment: no token is ever dropped whatever the
-# imbalance, and ``expert_count`` says how often that happened.
+# products' groups are the experts' own rows rounded up to ``align``: a
+# group's first row is a tile's first row, no tile is visited for two
+# experts, and the tiles past the last group are not visited at all, so the
+# products cost what arrived, rounded up to a tile an expert, while the
+# gathers into and out of the buffer cost the buffer.  The rows past the
+# last group are whatever the device's memory held: ``filled`` masks them
+# before anything weighs them.  A step that sends more than the buffer
+# holds takes the other branch of a ``cond``, the same computation over a
+# buffer with room for every assignment: no token is ever dropped whatever
+# the imbalance, and ``expert_count`` says how often that happened.
 # ---------------------------------------------------------------------------
 
 def _collect(rows, slots, k):
@@ -228,6 +231,11 @@ def _room(assignments, held, experts):
     return min(-(-share // align) * align, whole), whole, align
 
 
+def _aligned(sizes, align):
+    """Each group's rows rounded up to a multiple of ``align``."""
+    return -(-sizes // align) * align
+
+
 def _buffered(room, align, k, floats, ints):
     """The held experts' part of the layer over a buffer of ``room`` rows
     that holds every assignment that landed on them."""
@@ -238,7 +246,7 @@ def _buffered(room, align, k, floats, ints):
         # expert e has ``padded[e]`` rows of the buffer, up to ``ends[e]``,
         # and fills the first ``group_sizes[e]``; ``shift[e]`` is how far
         # its first row lies past its first place in the sorted order
-        padded = -(-group_sizes // align) * align
+        padded = _aligned(group_sizes, align)
         ends = jnp.cumsum(padded)
         shift = (ends - padded) - (jnp.cumsum(group_sizes) - group_sizes)
         row = jnp.arange(room)
@@ -251,16 +259,16 @@ def _buffered(room, align, k, floats, ints):
         slots = jnp.where(key < count,
                           inverse + shift[jnp.minimum(key, count - 1)], room)
         xs = _dispatch(x, tokens, slots, k)
-        # sizes that cover the buffer: the last group takes its rest
-        covering = jnp.concatenate([padded[:-1],
-                                    room - padded[:-1].sum()[None]])
     with jax.named_scope('experts'):
-        hidden = jax.nn.silu(grouped_matmul(xs, w1, covering)) * \
-            grouped_matmul(xs, w3, covering)
-        ys = grouped_matmul(hidden, w2, covering)
+        # the rows past ``ends[-1]`` are in no group: unvisited, unwritten
+        hidden = jax.nn.silu(grouped_matmul(xs, w1, padded)) * \
+            grouped_matmul(xs, w3, padded)
+        ys = grouped_matmul(hidden, w2, padded)
     with jax.named_scope('combine'):
         gate = jnp.take(weights.reshape(-1), assignment)[:, None]
-        ys = jnp.where(filled[:, None], ys.astype(jnp.float32) * gate, 0)
+        # masked before it is weighed: what a row that holds nothing reads
+        # must not reach the gate's gradient as 0 x anything
+        ys = jnp.where(filled[:, None], ys.astype(jnp.float32), 0) * gate
         return _combine(ys.astype(x.dtype), tokens, slots, k)
 
 
@@ -317,7 +325,7 @@ def _sparse_experts_apply(attrs, inputs, is_train, rng):
     floats = (x, weights, w1, w3, w2)
     ints = (key, order, inverse, group_sizes)
     if room < whole:
-        fits = (-(-group_sizes // align) * align).sum() <= room
+        fits = _aligned(group_sizes, align).sum() <= room
         y = _either_buffer((room, whole, align), k, fits, floats, ints)
     else:
         fits = True
@@ -330,9 +338,10 @@ def _sparse_experts_apply(attrs, inputs, is_train, rng):
                  'expert_count': count_so_far.astype(jnp.float32) + step}
 
 
-def _sparse_experts_counters(now, before):
-    """The layer's counts since the last drain into the registry, and how
-    uneven the last step's load was over the experts held."""
+def _sparse_experts_counters(now, before, attrs, in_shapes):
+    """The layer's counts since the last drain into the registry, how
+    uneven the last step's load was over the experts held, and the share
+    of its buffer's rows that the last step's products visited."""
     from .. import instrument
     count = now['expert_count'] - (before['expert_count'] if before else 0)
     instrument.inc('moe.assignments', int(count[0]))
@@ -343,6 +352,12 @@ def _sparse_experts_counters(now, before):
     if load.sum() > 0:
         instrument.observe_hist('moe.load_max_over_mean',
                                 float(load.max() / load.mean()))
+    room, whole, align = _room(
+        in_shapes[0][0] * int(attrs['experts_per_tok']), load.shape[0],
+        int(attrs['num_experts']))
+    visited = float(_aligned(load, align).sum())
+    instrument.observe_hist('moe.rows_visited_share',
+                            visited / (room if visited <= room else whole))
 
 
 def _sparse_experts_complete(attrs, in_shapes):
@@ -389,4 +404,7 @@ register('SparseExperts', _sparse_experts_apply,
              'expert received in the last step; expert_count (4,), running '
              'totals of assignments routed, assignments that landed on held '
              'experts, tokens dropped (always 0), and steps that sent the '
-             'held experts more than their buffer holds.')
+             'held experts more than their buffer holds.  The buffer is '
+             'four times a balanced router\'s share; the grouped products '
+             'run over each held expert\'s assignments rounded up to a row '
+             'tile and over no other row of it.')

@@ -133,11 +133,13 @@ class OpDef:
         # (bf16 rounds whole numbers above 256), SparseExperts' router
         # (its product is float32 by definition)
         self.keep_dtype = tuple(keep_dtype)
-        # (now, before) -> None: writes instrument counters from a node's
-        # auxiliary states (op-local name -> numpy array) at this metric
-        # drain and at the one before (None at the first); how an op's
-        # device-side counts reach the registry without a sync of their
-        # own (instrument.add_device_source, Module._aux_counter_source)
+        # (now, before, attrs, in_shapes) -> None: writes instrument
+        # counters from a node's auxiliary states (op-local name -> numpy
+        # array) at this metric drain and at the one before (None at the
+        # first), given the node's attributes and the shapes of its other
+        # inputs; how an op's device-side counts reach the registry without
+        # a sync of their own (instrument.add_device_source,
+        # Module._aux_counter_source)
         self.aux_counters = aux_counters
         self.doc = doc
 

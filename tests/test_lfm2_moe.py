@@ -169,9 +169,21 @@ def case_experts_over_the_buffer(rng):
     return case_experts(rng, (4, 3), favour=1.0, experts=32)
 
 
+def case_experts_one_held_receives_nothing(rng):
+    # the selection bias keeps every token off the second held expert
+    return case_experts(rng, (4, 3), favour=jnp.array([0.0, -100.0, 0.0]),
+                        experts=32)
+
+
+def case_experts_a_hundredth_of_the_layer(rng):
+    # and nearly every token off all three: 3 of the 256 assignments land
+    return case_experts(rng, (4, 3), favour=-0.15, experts=32)
+
+
 CASES = [case_rms_norm, case_rotary, case_swiglu, case_silu, case_short_conv,
          case_attention, case_experts, case_experts_in_the_buffer,
-         case_experts_over_the_buffer]
+         case_experts_over_the_buffer, case_experts_one_held_receives_nothing,
+         case_experts_a_hundredth_of_the_layer]
 
 
 def apply_op(name, attrs, inputs, aux):
@@ -210,6 +222,7 @@ def test_operator_forward_and_gradients_agree_with_the_reference(case, dtype):
     assert out.dtype == dtype
     assert rel(out, want) < limit
     for got, wanted in zip(grads, grads_want):
+        assert bool(jnp.isfinite(got).all())
         assert rel(got, wanted) < 2 * limit
 
 
@@ -609,6 +622,141 @@ def test_the_buffer_is_four_balanced_shares_and_a_step_over_it_is_counted():
         assert (held > 96) == over and steps_over == float(over)
 
 
+# -- the products run over the rows that hold something ---------------------
+
+def case_experts_filling_the_buffer(rng):
+    # expert 5, held alone, is every token's first choice: 64 assignments
+    # into a buffer of 64 rows
+    name, attrs, inputs, aux, reference = case_experts(rng, (5, 1))
+    aux[0] = jnp.zeros((16,)).at[5].set(100.0)
+    return name, attrs, inputs, aux, reference
+
+
+def visited_share_so_far():
+    """(observations, their sum) of ``moe.rows_visited_share``."""
+    h = instrument.metrics_snapshot().get('histograms', {}).get(
+        'moe.rows_visited_share', {'count': 0, 'sum': 0.0})
+    return np.array([h['count'], h['sum']])
+
+
+def experts_loss(inputs, attrs, aux, cotangent):
+    out = apply_op('SparseExperts', attrs, inputs, aux)[0][0]
+    return jnp.sum(out * cotangent), out
+
+
+@pytest.mark.parametrize('case, rows, full', [
+    (case_experts_in_the_buffer, 96, False),
+    (case_experts_a_hundredth_of_the_layer, 96, False),
+    (case_experts_over_the_buffer, 280, False),
+    (case_experts_filling_the_buffer, 64, True)],
+    ids=['in_the_buffer', 'a_hundredth', 'over_the_buffer', 'filling_it'])
+def test_grouped_products_are_given_each_experts_aligned_rows_and_no_more(
+        monkeypatch, case, rows, full):
+    from mxnet_tpu.ops import lm
+    name, attrs, inputs, aux, _ = case(np.random.default_rng(11))
+    given, plain = [], lm.grouped_matmul
+
+    def recording(lhs, rhs, group_sizes):
+        given.append((lhs.shape[0], np.asarray(group_sizes)))
+        return plain(lhs, rhs, group_sizes)
+
+    monkeypatch.setattr(lm, 'grouped_matmul', recording)
+    cotangent = draw(np.random.default_rng(12), inputs[0].shape)
+    # eagerly, so that a ``cond``'s branch sees numbers: forward, then
+    # forward and backward (which computes the taken branch again)
+    with jax.disable_jit():
+        load = np.asarray(apply_op(name, attrs, inputs, aux)[1]['expert_load'])
+        jax.grad(lambda *xs: experts_loss(xs, attrs, aux, cotangent)[0],
+                 tuple(range(5)))(*inputs)
+    align = lm._room(N * T * 4, attrs['experts_held'][1],
+                     attrs['num_experts'])[2]
+    want = np.ceil(load / align) * align
+    assert len(given) >= 6
+    for buffer_rows, sizes in given:
+        assert buffer_rows == rows
+        np.testing.assert_array_equal(sizes, want)
+    assert want.sum() <= rows and (want.sum() == rows) == full
+    # and the share the drain reports is these rows over that buffer
+    was = instrument.metrics_enabled()
+    instrument.set_metrics(True)
+    try:
+        before = visited_share_so_far()
+        lm._sparse_experts_counters(
+            {'expert_load': load, 'expert_count': np.zeros(4)}, None,
+            get_op(name).canon_attrs(attrs), [inputs[0].shape])
+        count, share = visited_share_so_far() - before
+    finally:
+        instrument.set_metrics(was)
+    assert count == 1 and share == pytest.approx(want.sum() / rows)
+
+
+def poisoned(plain):
+    """``plain`` with every row past the groups' last, which the chip's
+    product does not write, read back as NaN: in the product and in its
+    transpose by the rows.  The transpose by the weights reads the groups'
+    rows alone, as the chip's does."""
+    def within(rows, sizes, beyond=0):
+        return jnp.where((jnp.arange(rows.shape[0]) < sizes.sum())[:, None],
+                         rows, beyond)
+
+    def spoil(rows, sizes):
+        return within(rows, sizes, jnp.nan)
+
+    @jax.custom_vjp
+    def product(lhs, rhs, sizes):
+        return spoil(plain(within(lhs, sizes), rhs, sizes), sizes)
+
+    def forward(lhs, rhs, sizes):
+        return product(lhs, rhs, sizes), (lhs, rhs, sizes)
+
+    def backward(kept, g):
+        lhs, rhs, sizes = kept
+        by_rows, by_weights = jax.vjp(
+            lambda l, r: plain(l, r, sizes), within(lhs, sizes), rhs)[1](
+                within(g, sizes))
+        return spoil(by_rows, sizes), by_weights, None
+
+    product.defvjp(forward, backward)
+    return product
+
+
+@pytest.mark.parametrize('dtype', DTYPES, ids=['float32', 'bfloat16'])
+@pytest.mark.parametrize('case', [
+    case_experts_in_the_buffer, case_experts_over_the_buffer,
+    case_experts_a_hundredth_of_the_layer],
+    ids=['in_the_buffer', 'over_the_buffer', 'a_hundredth'])
+def test_rows_the_products_do_not_write_reach_no_output_and_no_gradient(
+        monkeypatch, case, dtype):
+    """The CPU's product writes zeros past its groups and would hide what
+    the chip's leaves there."""
+    from mxnet_tpu.ops import lm
+    name, attrs, inputs, aux, _ = case(np.random.default_rng(11))
+    inputs = [x if i == 1 else x.astype(dtype) for i, x in enumerate(inputs)]
+    cotangent = draw(np.random.default_rng(12), inputs[0].shape)
+
+    def run():
+        grads, out = jax.grad(
+            lambda *xs: experts_loss(xs, attrs, aux, cotangent),
+            tuple(range(5)), has_aux=True)(*inputs)
+        return [out] + list(grads)
+
+    clean = run()
+    spoilt = []
+    plain = lm.grouped_matmul
+
+    def counting(lhs, rhs, sizes):
+        spoilt.append(lhs.shape[0])
+        return poisoned(plain)(lhs, rhs, sizes)
+
+    monkeypatch.setattr(lm, 'grouped_matmul', counting)
+    dirty = run()
+    assert len(spoilt) >= 6
+    for got, want in zip(dirty, clean):
+        assert bool(jnp.isfinite(got).all())
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+
+
 # -- what the step had to learn ---------------------------------------------
 
 def test_mirror_stages_group_the_blocks_and_leave_the_gradient_alone(model):
@@ -661,6 +809,7 @@ def test_device_counters_reach_the_registry_only_at_a_drain(model):
     instrument.set_metrics(True)
     try:
         before = instrument.metrics_snapshot()['counters']
+        visited_before, visited_seen = tuple(visited_share_so_far()), []
         data = mx.io.NDArrayIter(
             np.tile(model['tokens'], (3, 1)).astype(np.float32),
             np.tile(model['labels'], (3, 1)).astype(np.float32),
@@ -673,8 +822,9 @@ def test_device_counters_reach_the_registry_only_at_a_drain(model):
                         for k, v in model['args'].items()},
             aux_params={k: mx.nd.array(np.asarray(v))
                         for k, v in model['aux'].items()},
-            batch_end_callback=lambda p: seen.append(
-                instrument.counter_value('moe.assignments')))
+            batch_end_callback=lambda p: (
+                seen.append(instrument.counter_value('moe.assignments')),
+                visited_seen.append(tuple(visited_share_so_far()))))
         after = instrument.metrics_snapshot()
         moved = {k: after['counters'].get(k, 0) - before.get(k, 0)
                  for k in ('moe.assignments', 'moe.assignments_held',
@@ -690,8 +840,41 @@ def test_device_counters_reach_the_registry_only_at_a_drain(model):
             before.get('moe.steps_over_capacity', 0)
         uneven = after['histograms']['moe.load_max_over_mean']
         assert uneven['count'] >= 4 and uneven['sum'] / uneven['count'] > 1
+        # the rows the last step's products visited over the buffer's 320
+        # (every expert held, each expert's rows from a multiple of 4),
+        # one observation a layer and drain, none between the drains
+        assert visited_seen == [visited_before] * 3
+        count, share = visited_share_so_far() - visited_before
+        loads = [aux.asnumpy() for name, aux in module.get_params()[1].items()
+                 if name.endswith('_expert_load')]
+        assert count >= 4 and count % 4 == 0
+        assert share / count == pytest.approx(np.mean(
+            [(np.ceil(load / 4) * 4).sum() / 320 for load in loads]))
+        assert 0.8 < share / count <= 1
     finally:
         instrument.set_metrics(was)
+
+
+def test_the_counters_are_handed_the_nodes_attributes_and_input_shapes():
+    symbol = models.get_symbol('lfm2_moe', seq_len=T, **SIZES)
+    module = mx.mod.Module(symbol)
+    module.bind(data_shapes=[('data', (N, T))],
+                label_shapes=[('softmax_label', (N, T))])
+    module._aux_counted = module._find_aux_counted()
+    assert len(module._aux_counted) == 4
+    for (write, local, names, attrs, _, _), shapes in zip(
+            module._aux_counted, module._aux_counted_shapes()):
+        assert write is get_op('SparseExperts').aux_counters
+        assert local == ['expert_bias', 'expert_load', 'expert_count']
+        assert names == [names[0][:-len('expert_bias')] + n for n in local]
+        assert attrs['experts_per_tok'] == 4 and attrs['num_experts'] == 16
+        assert [tuple(s) for s in shapes] == [
+            (N * T, HIDDEN), (16, HIDDEN), (16, HIDDEN, 48), (16, HIDDEN, 48),
+            (16, 48, HIDDEN)]
+    # other shapes are bound: the rows follow
+    module.reshape([('data', (1, T))], [('softmax_label', (1, T))])
+    assert [tuple(shapes[0]) for shapes in module._aux_shapes] == \
+        [(T, HIDDEN)] * 4
 
 
 def test_initializer_knows_the_expert_layers_states():
