@@ -219,15 +219,18 @@ def run(ctx):
     log('first step\'s log-probabilities against the reference\'s: error '
         '%.4f of their spread (at most %.2f), row agreement %.4f (at least '
         '%.2f)' % (error, LOG_PROB_ERROR_MAX, agreement, ROW_AGREEMENT_MIN))
-    correct = bool(error <= LOG_PROB_ERROR_MAX and
-                   agreement >= ROW_AGREEMENT_MIN and
-                   np.isfinite(loss_last) and loss_last < loss_first)
+    compared = {
+        'log_prob_error': {'value': error, 'most': LOG_PROB_ERROR_MAX},
+        'row_agreement': {'value': agreement, 'least': ROW_AGREEMENT_MIN},
+        'loss_last_over_first': {'value': loss_last / loss_first,
+                                 'under': 1.0}}
+    correct = all(harness.holds(entry) for entry in compared.values())
     if compiled_inside:
         raise BenchmarkError('%d program(s) compiled inside the window'
                              % compiled_inside)
     result = {
         'correct': correct, 'attempted': steps, 'failed': 0,
-        't0': state['t0'],
+        't0': state['t0'], 'compared': compared,
         'end_to_end': {'fit_samples_per_s': batch * steps / window},
         'devices': devices,
     }

@@ -9,6 +9,17 @@ any later callback, nothing compiled inside), on token sequences: a step is
 next-token labels, fed the MXNet way as float32, from a ring of seeded host
 batches handed out in turn.  One sample is one sequence.
 
+The seed draws every weight, the vocabulary's order and every token, and
+nothing else: each expert layer's selection bias starts at zero and is
+balanced in set-up, before the reference's first step, by the rule the
+published model trains under (``use_expert_bias``: Wang et al. 2024,
+arXiv:2408.15664, section 3: ``b_i += u * sign(mean(c) - c_i)`` after a
+batch, ``c`` the assignments each expert received), over the ring's own
+batches (``balance_bias``).  The run ends with no result unless the held
+experts then receive their share of every layer's assignments over the ring
+(``check_balance``): the step's time follows that share, and it is the
+deployment's eighth, not the seed's lot.
+
 ``correct`` holds the timed program's first step to the configuration's
 plain reference (``benchmark/reference_lfm2_moe.py``: forward pass, loss,
 ``jax.grad`` and ``adam_step`` in float32) under the same parameters and
@@ -16,9 +27,10 @@ batch: the log-probabilities of every token over the vocabulary's slice;
 the assignments each expert layer counted on its held experts; every
 parameter's gradient as the optimizer was given it (Adam's mean after one
 update is a tenth of it); every parameter after the update against the
-reference's Adam on that gradient; the tokens dropped to zero; and the
-loss of the window's last step, which is on the first step's batch again,
-to the first.
+reference's Adam on that gradient; after the window the selection bias bit
+for bit what set-up made and every trained array moved from what the seed
+drew; the tokens dropped to zero; and the loss of the window's last step,
+which is on the first step's batch again, to the first.
 
 With ``--trace 1`` the slice is ``trace_steps`` steps of epoch 1 between
 two drains of the device and of the metric (the program writes its
@@ -222,7 +234,7 @@ def make_weights(symbol, input_shapes, seed):
     """``(arg_params, aux_params)`` as name -> float32 device array: every
     ``*_weight`` normal with variance 1 / fan-in (the second axis, also of
     the experts' stacked matrices), every ``*_gamma`` one, the selection
-    bias normal with standard deviation 0.1, the counting states zero."""
+    bias zero (``balance_bias`` sets it) and the counting states zero."""
     import jax
     import jax.numpy as jnp
     arg_shapes, _, aux_shapes = symbol.infer_shape(**input_shapes)
@@ -237,10 +249,7 @@ def make_weights(symbol, input_shapes, seed):
         if name.endswith('_weight'):
             return jax.random.normal(key, shape, jnp.float32) * \
                 np.float32(1.0 / np.sqrt(shape[1]))
-        if name.endswith('_expert_bias'):
-            return jax.random.normal(key, shape, jnp.float32) * \
-                np.float32(0.1)
-        if name.endswith(('_expert_load', '_expert_count')):
+        if name.endswith(('_expert_bias', '_expert_load', '_expert_count')):
             return jnp.zeros(shape, jnp.float32)
         raise ValueError('benchmark/drivers/fit_lm.py does not know how to '
                          'make %r' % name)
@@ -254,6 +263,157 @@ def make_weights(symbol, input_shapes, seed):
 
     made = make_all(jax.random.PRNGKey(int(seed) % (2 ** 31 - 1)))
     return ({n: made[n] for n in args}, {n: made[n] for n in aux})
+
+
+def bias_name(layer):
+    return 'l%d_moe_expert_bias' % layer
+
+
+def balance_bias(reference_lm, params, ring, config, passes, step,
+                 bias=None):
+    """Each expert layer's selection bias as the published balancing rule
+    leaves it on the ring's batches, and the load it then gives.
+
+    The rule (Wang et al. 2024, arXiv:2408.15664, section 3): after a batch
+    ``b_i += u * sign(mean(c) - c_i)``, ``c`` the assignments each of the
+    layer's experts received in it; here from ``bias`` (name -> array; zero
+    where None) over ``passes`` batches, the ring's in turn, with ``u``
+    falling from ``step['start']`` by the factor ``step['decay']`` a pass
+    down to ``step['floor']``.  The bias enters nothing but the choice of
+    experts, so a layer's router sees the same input whatever its own bias
+    is: the layers are balanced one after another, each on the input that
+    the layers before it give under the bias they were left with, and a
+    pass of one layer costs a ``top_k`` over the stored products with its
+    router (``route``'s own choice: the largest of sigmoid plus bias), not
+    a forward pass.  The model is the plain reference's, piece by piece
+    (``layer``, ``rms_norm``, ``short_conv``, ``attention``,
+    ``expert_layer``), in float32 at the default matmul precision; only
+    forward passes, one a batch of the ring and what leads up to a router
+    twice.
+
+    Returns ``(bias, load)``: name -> ``(num_experts,)`` float32 on the
+    device, and layer index -> ``(len(ring), num_experts)`` assignments each
+    expert receives from each batch under the returned bias."""
+    import jax
+    import jax.numpy as jnp
+    experts = int(config['num_experts'])
+    eps = config['norm_eps']
+    start, decay, floor = (np.float32(step[k])
+                           for k in ('start', 'decay', 'floor'))
+
+    def counted(logits, b):
+        """``reference_lm.route``'s choice from the router's products, and
+        how many assignments each expert then receives."""
+        _, chosen = jax.lax.top_k(jax.nn.sigmoid(logits) + b,
+                                  config['num_experts_per_tok'])
+        return jnp.zeros(experts, jnp.float32).at[chosen.reshape(-1)].add(1)
+
+    def to_router(x, p):
+        """``reference_lm.layer`` as far as the feed-forward's input."""
+        n, t, _ = x.shape
+        z = reference_lm.rms_norm(x, p['op_norm_gamma'], eps)
+        if 'conv_weight' in p:
+            op = reference_lm.short_conv(z, p['conv_in_weight'],
+                                         p['conv_weight'],
+                                         p['conv_out_weight'])
+        else:
+            op = reference_lm.attention(
+                z, p['q_weight'], p['k_weight'], p['v_weight'],
+                p['o_weight'], p['q_norm_gamma'], p['k_norm_gamma'], config)
+        h = x + op
+        return h, reference_lm.rms_norm(h, p['ff_norm_gamma'],
+                                        eps).reshape(n * t, -1)
+
+    @jax.jit
+    def router_products(x, p):
+        return to_router(x, p)[1] @ p['router_weight'].T
+
+    @jax.jit
+    def balanced(logits, b):
+        def one(b, i):
+            c = counted(jax.lax.dynamic_index_in_dim(
+                logits, i % logits.shape[0], keepdims=False), b)
+            u = jnp.maximum(start * decay ** i.astype(jnp.float32), floor)
+            return b + u * jnp.sign(jnp.mean(c) - c), None
+        b, _ = jax.lax.scan(one, b, jnp.arange(passes, dtype=jnp.int32))
+        return b, jax.lax.map(lambda rows: counted(rows, b), logits)
+
+    @jax.jit
+    def past_experts(x, p, b):
+        h, z = to_router(x, p)
+        y, _ = reference_lm.expert_layer(
+            z, p['router_weight'], b, p['experts_w1_weight'],
+            p['experts_w3_weight'], p['experts_w2_weight'], config)
+        return h + y.reshape(h.shape)
+
+    dense_layer = jax.jit(
+        lambda x, p, kind: reference_lm.layer(x, p, kind, True, config)[0],
+        static_argnames='kind')
+    # one activation a batch of the ring stays on the device between the
+    # layers (134 MB each at 16384 tokens), and a layer's router products
+    # (4 MB each); what leads up to the router is computed a second time
+    # past the balanced layer rather than kept, so that set-up's peak
+    # stays under the program's
+    xs = [params['embed_weight'][jnp.asarray(tokens, jnp.int32)]
+          for tokens in ring]
+    out, load = {}, {}
+    for i, kind in enumerate(config['layer_types']):
+        dense = i < config['num_dense_layers']
+        prefix = 'l%d_' % i
+        p = {k[len(prefix):]: params[k]
+             for k in reference_lm.layer_param_names(i, kind, dense)
+             if k in params}
+        if dense:
+            for at, x in enumerate(xs):     # in place: the old one goes
+                xs[at] = dense_layer(x, p, kind)
+            continue
+        b = (bias or {}).get(bias_name(i))
+        b = jnp.zeros(experts, jnp.float32) if b is None else \
+            jnp.asarray(b, jnp.float32)
+        out[bias_name(i)], load[i] = balanced(
+            jnp.stack([router_products(x, p) for x in xs]), b)
+        if i + 1 < len(config['layer_types']):
+            for at, x in enumerate(xs):
+                xs[at] = past_experts(x, p, out[bias_name(i)])
+    return out, load
+
+
+def check_balance(load, config, band):
+    """Ends the run unless, over the ring's batches together, the held
+    experts of every expert layer receive their share of its assignments,
+    ``held / num_experts``, within ``band`` (two factors of that share): a
+    guard against a balance that did not happen, since the step's time
+    follows the share.  Logs each layer's share and its fullest expert over
+    the mean, which is held to nothing (where a layer sends every
+    occurrence of the most frequent token one way, no bias divides them).
+    Returns the shares by layer."""
+    first, count = config['experts_held']
+    even = float(count) / config['num_experts']
+    shares = {}
+    for layer, by_batch in sorted(load.items()):
+        by_batch = np.asarray(by_batch, np.float64)
+        whole = by_batch.sum(axis=0)
+        held = by_batch[:, first:first + count].sum(axis=1) / \
+            by_batch.sum(axis=1)
+        shares[layer] = float(whole[first:first + count].sum() / whole.sum())
+        log('layer %d, the bias balanced: held experts receive %.2f%% of the '
+            'ring\'s assignments (single batches %.2f%% to %.2f%%; even is '
+            '%.2f%%), the fullest of all %d experts %.3f times the mean, of '
+            'the held %.3f' % (
+                layer, 100 * shares[layer], 100 * held.min(),
+                100 * held.max(), 100 * even, len(whole),
+                whole.max() / whole.mean(),
+                whole[first:first + count].max() / whole.mean()))
+    outside = {layer: share for layer, share in shares.items()
+               if not band[0] * even <= share <= band[1] * even}
+    if outside:
+        raise BenchmarkError(
+            'the selection bias is not balanced: the held experts\' share '
+            'of the ring\'s assignments is outside %.2f%% to %.2f%% in %s'
+            % (100 * band[0] * even, 100 * band[1] * even, ', '.join(
+                'layer %d (%.2f%%)' % (layer, 100 * share)
+                for layer, share in sorted(outside.items()))))
+    return shares
 
 
 def reference_step(reference_lm, params, tokens, labels, config):
@@ -344,12 +504,28 @@ def run(ctx):
         'parameter arrays' % (sequences, length, len(host),
                               len(arg_params)))
 
+    # the selection bias, balanced on the ring by its published rule; the
+    # reference's first step, the program's and the window all run under it
+    ref_config = reference_config(config)
+    started = time.perf_counter()
+    bias, load = balance_bias(reference_lm, arg_params,
+                              [data for data, _ in host], ref_config,
+                              int(cell['balance_passes']),
+                              cell['balance_step'])
+    check_balance(load, ref_config, cell['held_share_band'])
+    if set(bias) != {k for k in aux_params if k.endswith('_expert_bias')}:
+        raise BenchmarkError('the reference\'s expert layers are not the '
+                             'program\'s: %s' % sorted(bias))
+    aux_params.update(bias)
+    bias_made = {k: np.array(v) for k, v in bias.items()}
+    log('the selection bias balanced over the ring, %d passes a layer: '
+        '%.1f s' % (int(cell['balance_passes']),
+                    time.perf_counter() - started))
+    del load
+
     # the plain reference's first step: forward pass, loss and gradients.
     # What the comparison needs goes to the host; the chip keeps nothing
-    ref_config = reference_config(config)
-    everything = dict(arg_params)
-    everything.update({k: v for k, v in aux_params.items()
-                       if k.endswith('_expert_bias')})
+    everything = dict(arg_params, **bias)
     started = time.perf_counter()
     log_prob_reference, load_reference, loss_reference, gradients = \
         reference_step(reference_lm, everything, host[0][0], host[0][1],
@@ -358,7 +534,8 @@ def run(ctx):
     load_reference = {k: np.asarray(v) for k, v in load_reference.items()}
     loss_reference = float(loss_reference) / tokens_a_step
     gradients = {k: np.asarray(v) for k, v in gradients.items()}
-    before = {k: np.array(v) for k, v in arg_params.items()}   # copies
+    # copies: the first step's comparison reads them, and the window's end
+    before = {k: np.array(v) for k, v in arg_params.items()}
     label_first = host[0][1].reshape(-1)
     log('the reference\'s first step (forward, loss, gradients): %.1f s'
         % (time.perf_counter() - started))
@@ -395,9 +572,8 @@ def run(ctx):
                     layer: aux[name + '_expert_count'].asnumpy()
                     for name, layer in moe}
                 state['update_first'] = update_readings(
-                    reference_lm, adam,
-                    before, gradients, {k: v.asnumpy() for k, v in
-                                        after.items()},
+                    reference_lm, adam, dict(before), gradients,
+                    {k: v.asnumpy() for k, v in after.items()},
                     module.fused_optimizer_state())
             return
         if param.nbatch == 0:
@@ -478,31 +654,51 @@ def run(ctx):
             leaves.items(), key=lambda kv: -kv[1][0])[:5]:
         log('  gradient_error %.4f, update_error %.2e: %s'
             % (gradient, moved, key))
-    counts_agree = True
+    apart = 0.0
     for _, layer in moe:
         routed, held, dropped, _ = state['count_first'][layer]
         want = float(load_reference[layer].sum())
-        counts_agree = counts_agree and abs(held - want) <= max(
-            HELD_ASSIGNMENTS_APART_MAX * want, HELD_ASSIGNMENTS_APART_FLOOR)
+        apart = max(apart, abs(held - want) / max(
+            want, HELD_ASSIGNMENTS_APART_FLOOR / HELD_ASSIGNMENTS_APART_MAX))
         log('layer %d, first step: %d assignments routed, %d on held '
             'experts (the reference: %d, %.2f%% of the layer\'s), %d tokens '
             'dropped' % (layer, routed, held, want,
                          100.0 * want / max(routed, 1), dropped))
-    _, aux_last = module.get_params()
+    arg_last, aux_last = module.get_params()
+    last = dict(arg_last, **aux_last)
+    bias_moved = sorted(k for k, v in bias_made.items()
+                        if not np.array_equal(last[k].asnumpy(), v))
+    unmoved = sorted(k for k, v in before.items()
+                     if np.array_equal(last[k].asnumpy(), v))
+    log('after the window: the selection bias bit for bit what set-up made '
+        'in %d of %d layers, %d of %d trained arrays moved%s'
+        % (len(bias_made) - len(bias_moved), len(bias_made),
+           len(before) - len(unmoved), len(before),
+           '  REFUSED: ' + ', '.join(bias_moved + unmoved)
+           if bias_moved or unmoved else ''))
     totals = np.sum([aux_last[name + '_expert_count'].asnumpy()
                      for name, _ in moe], axis=0) if moe else np.zeros(4)
     log('in all: %d assignments routed, %d on held experts (%.2f%%), %d '
         'tokens dropped; %d times a layer was sent more than its buffer holds'
         % (totals[0], totals[1], 100.0 * totals[1] / max(totals[0], 1),
            totals[2], totals[3]))
-    correct = bool(not refused and counts_agree and totals[2] == 0 and
-                   np.isfinite(loss_last) and loss_last < loss_first)
+    # every number compared, beside its limit
+    compared = {key: {'value': readings[key], LIMITS[key][1]: LIMITS[key][0]}
+                for key in sorted(readings)}
+    compared['held_assignments_apart'] = {
+        'value': apart, 'most': HELD_ASSIGNMENTS_APART_MAX}
+    compared['tokens_dropped'] = {'value': float(totals[2]), 'most': 0.0}
+    compared['bias_moved'] = {'value': float(len(bias_moved)), 'most': 0.0}
+    compared['arrays_unmoved'] = {'value': float(len(unmoved)), 'most': 0.0}
+    compared['loss_last_over_first'] = {'value': loss_last / loss_first,
+                                        'under': 1.0}
+    correct = all(harness.holds(entry) for entry in compared.values())
     if compiled_inside:
         raise BenchmarkError('%d program(s) compiled inside the window'
                              % compiled_inside)
     result = {
         'correct': correct, 'attempted': steps, 'failed': 0,
-        't0': state['t0'],
+        't0': state['t0'], 'compared': compared,
         'end_to_end': {'fit_samples_per_s': sequences * steps / window},
         'devices': jax.devices()[:1],
     }

@@ -2,6 +2,7 @@
 memory reading, the traced slice and the arithmetic of the data-defined
 per-layer metrics."""
 import glob
+import math
 import os
 import shutil
 import time
@@ -179,6 +180,21 @@ class SliceTrace(object):
                                      os.path.getsize(paths[0]) / 2.0 ** 20))
         return trace_reduce.reduce_profile(
             trace_reduce.load(paths[0]), SLICE_SPAN, chips=self.chips)
+
+
+def holds(entry):
+    """Whether one number compared keeps its limit.  A driver gives each as
+    ``{'value': v, <kind>: limit}``, the kind ``most`` (at most), ``least``
+    (at least) or ``under`` (less than); ``correct`` is all of them, and
+    the result line carries them under ``compared``."""
+    value = entry['value']
+    if not math.isfinite(value):
+        return False
+    if 'most' in entry:
+        return value <= entry['most']
+    if 'least' in entry:
+        return value >= entry['least']
+    return value < entry['under']
 
 
 def span(name):

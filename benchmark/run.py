@@ -9,7 +9,9 @@ its driver, a module under ``benchmark/drivers``), the configuration's
 file through the manifest, each per-layer metric in
 ``benchmark/layer_metrics/<metric>.json``.  The last line of standard
 output is one JSON object: ``correct``, ``attempted``, ``failed``,
-``metrics``, ``device`` and, traced, ``breakdown``.
+``metrics``, ``device``, traced ``breakdown``, and last ``compared``: every
+number that decided ``correct`` beside its limit, which are also the last
+lines of standard error.
 
 ``--trace 0`` leaves every observability plane of the program off, as a
 user runs it, and reports the cell's end-to-end metrics.  ``--trace 1``
@@ -119,6 +121,17 @@ def main(argv=None):
         line['device'] = dict(ctx.device)
         line.pop('breakdown', None)
         line['rehearsal'] = True
+    # every number compared beside its limit: the line's last key and the
+    # last lines of standard error
+    line['compared'] = {name: {k: float(v) for k, v in entry.items()}
+                        for name, entry in result['compared'].items()}
+    for name, entry in line['compared'].items():
+        kind = next(k for k in entry if k != 'value')
+        print('compared %s: %r (%s %r)%s' % (
+            name, entry['value'], kind, entry[kind],
+            '' if harness.holds(entry) else '  NOT HELD'),
+            file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
 
 

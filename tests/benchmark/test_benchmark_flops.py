@@ -45,11 +45,21 @@ def test_resnet50_forward_is_4_1_g_multiply_adds():
 # ResNet-50 4.1 G and 25.6 M (He et al.), Inception-v3 5.7 G and 23.8 M
 # (Szegedy et al. 2015, table 3 and the released model)
 PUBLISHED = {'resnet50': (4.1e9, 25.6e6), 'inception_v3': (5.7e9, 23.8e6)}
+# the configurations of the cells that the ``fit`` driver runs: this file is
+# that driver's (an ``image_shape``, ``flops.pinned``); another driver's
+# configurations are pinned in its own test file
+FIT_CONFIGS = sorted({cell['config'] for cell in SPEC['workloads']
+                      if manifest.load_cell(cell['name'])['driver'] == 'fit'})
 
 
-@pytest.mark.parametrize('entry', SPEC['configs'], ids=lambda c: c['name'])
+def test_every_fit_configuration_has_its_published_counts_here():
+    assert FIT_CONFIGS and set(FIT_CONFIGS) == set(PUBLISHED)
+
+
+@pytest.mark.parametrize('name', FIT_CONFIGS)
 def test_configuration_pins_the_published_model_and_the_program_builds_it(
-        entry):
+        name):
+    entry = manifest.config_entry(SPEC, name)
     config = manifest.load_config(SPEC, entry['name'])
     pinned = config['pinned']
     macs, parameters = PUBLISHED[entry['name']]

@@ -23,7 +23,7 @@ CELL = 'lfm2_moe_fit_8k'
 CONFIG = 'lfm2_24b_a2b'
 LM_METRICS = ['lm_step_mfu_pct', 'lm_step_device_ms', 'lm_moe_share_pct',
               'lm_experts_roofline_pct', 'lm_attention_roofline_pct',
-              'lm_shortconv_ms', 'lm_optimizer_ms']
+              'lm_shortconv_ms', 'lm_optimizer_ms', 'lm_held_share_pct']
 # the catalog's row for LFM2-24B-A2B (the guide's architectures.jsonl)
 PUBLISHED = {
     'conv_L_cache': 3, 'conv_bias': False, 'hidden_size': 2048,
@@ -55,6 +55,10 @@ def test_the_cell_and_its_metrics_are_in_the_manifest():
     body = manifest.load_cell(CELL)
     assert body['driver'] == 'fit_lm' and body['ring'] == 8
     assert (body['warmup_steps'], body['trace_steps']) == (4, 20)
+    # the balance's two keys and the guard's band, each with its reason
+    assert body['balance_passes'] >= len(body['balance_step']) == 3
+    assert body['held_share_band'][0] < 1 < body['held_share_band'][1]
+    assert body['balance_why'] and body['held_share_band_why']
     reported = [m['name'] for m in manifest.metrics_of(SPEC, 'per_layer',
                                                        CELL)]
     assert reported == LM_METRICS
@@ -68,10 +72,10 @@ def test_layer_metric_reads_nothing_from_a_slice_without_its_source(name):
     body = manifest.load_layer_metric(name)
     assert body['drivers'] == ['fit_lm'] and body['moves'] == \
         'fit_samples_per_s'
-    reader = manifest.load_module('readers', body['read']['reader'])
     # the parent has no scope, counter or text to read: nothing, no raise
-    assert reader.read({'trace': None, 'steps': 20.0, 'chips': 1.0,
-                        'device_kind': 'TPU v5 lite'}) is None
+    assert harness.evaluate(body, {
+        'trace': None, 'steps': 20.0, 'chips': 1.0, 'snap0': {}, 'snap1': {},
+        'device_kind': 'TPU v5 lite'}) is None
 
 
 def test_configuration_keeps_every_published_number_but_the_reduced(config):
@@ -276,20 +280,28 @@ def test_each_reader_reads_the_recorded_slice(recorded, name):
         'step_flops': 1e9,
         'lm': {'sequences': 2, 'attention': [(4, 2, 128, 64)],
                'assignments_held_per_step': 256.0, 'experts_held_total': 8,
-               'expert_width_in': 256, 'expert_width': 128}}
-    reader = manifest.load_module(
-        'readers', manifest.load_layer_metric(name)['read']['reader'])
-    value = reader.read(slice_)
+               'expert_width_in': 256, 'expert_width': 128},
+        # what the program counted over those three steps: 2 expert layers
+        # x 256 tokens x 4 a step, 128 of each layer's on held experts
+        'snap0': {'counters': {'moe.assignments': 2048,
+                               'moe.assignments_held': 250}},
+        'snap1': {'counters': {'moe.assignments': 8192,
+                               'moe.assignments_held': 1018}}}
+    value = harness.evaluate(manifest.load_layer_metric(name), slice_)
     assert value is not None and value > 0
+    if name == 'lm_held_share_pct':
+        assert value == 12.5
     if name.endswith('_pct'):
         assert value < 100
 
 
 def test_the_cell_is_the_one_issue_28_names(config):
+    # ISSUE 28's Adam but for the rate: since PR 31 a constant 5e-6, what a
+    # warm-up over 2000 updates to 3e-4 averages over the window's updates
     assert config['optimizer'] == {
-        'name': 'adam', 'learning_rate': 3e-4, 'beta1': 0.9, 'beta2': 0.95,
+        'name': 'adam', 'learning_rate': 5e-6, 'beta1': 0.9, 'beta2': 0.95,
         'epsilon': 1e-8, 'wd': 0.1}
-    # a constant rate, Module's own rescale_grad, the bias left as drawn
+    # a constant rate, Module's own rescale_grad, the bias as set-up left it
     assert 'lr_scheduler' not in config
     assert 'lr_scheduler' not in config['rehearsal']
     assert (config['seq_len'], config['per_chip_batch'],
@@ -343,9 +355,112 @@ def test_limits_hold_a_reading_at_the_limit_and_refuse_one_past_it():
     assert fit_lm.LIMITS['update_error_worst'][0] < 1.0
 
 
+# -- the balance set-up makes, at the rehearsal's sizes ---------------------
+
+@pytest.fixture(scope='module')
+def small(config):
+    """The configuration's rehearsal sizes, built: 16 experts of which 4 are
+    held, 2 x 64 tokens a step."""
+    import types
+    from benchmark import reference_lfm2_moe
+    from benchmark.drivers import fit_lm
+    sizes = harness.sizes(types.SimpleNamespace(config=config,
+                                                rehearsal=True))
+    tokens = (sizes['per_chip_batch'], sizes['seq_len'])
+    return types.SimpleNamespace(
+        sizes=sizes, symbol=harness.build_symbol(sizes),
+        shapes={'data': tokens, 'softmax_label': tokens},
+        config=fit_lm.reference_config(sizes), cell=manifest.load_cell(CELL),
+        reference=reference_lfm2_moe)
+
+
+def drawn_bias(seed, names, experts):
+    """What ``make_weights`` drew before PR 31: normal, 0.1."""
+    import numpy as np
+    rng = np.random.default_rng(seed)
+    return {name: rng.normal(0.0, 0.1, experts).astype(np.float32)
+            for name in sorted(names)}
+
+
+def balance(small, seed, passes=None, bias=None):
+    import numpy as np
+    from benchmark.drivers import fit_lm
+    batch, length = small.shapes['data']
+    host = fit_lm.make_batches(seed, small.cell['ring'], batch, length,
+                               small.sizes['vocab_size'],
+                               small.cell['zipf_exponent'])
+    args, aux = fit_lm.make_weights(small.symbol, small.shapes, seed)
+    names = [k for k in aux if k.endswith('_expert_bias')]
+    assert not any(np.asarray(aux[k]).any() for k in names)   # from zero
+    if bias == 'drawn':
+        bias = drawn_bias(seed, names, small.config['num_experts'])
+    return fit_lm.balance_bias(
+        small.reference, args, [data for data, _ in host], small.config,
+        small.cell['balance_passes'] if passes is None else passes,
+        small.cell['balance_step'], bias=bias)
+
+
+def held_share(load, config):
+    import numpy as np
+    first, count = config['experts_held']
+    return {layer: float(np.asarray(v)[:, first:first + count].sum() /
+                         np.asarray(v).sum()) for layer, v in load.items()}
+
+
+# three seeds whose first expert layer gives the held experts 23.6%, 9.6%
+# and 34.6% of the ring's assignments under a drawn bias (even is 25%)
+SEEDS_APART = (1, 8, 12)
+
+
+def test_the_balanced_bias_brings_every_seed_inside_the_band(small):
+    import numpy as np
+    from benchmark.drivers import fit_lm
+    band = small.cell['held_share_band']
+    lot, even = [], 4.0 / 16
+    for seed in SEEDS_APART:
+        _, load = balance(small, seed, passes=0, bias='drawn')
+        lot.append(held_share(load, small.config))
+        bias, load = balance(small, seed)
+        shares = fit_lm.check_balance(load, small.config, band)   # no raise
+        assert shares == pytest.approx(held_share(load, small.config))
+        assert sorted(shares) == [1, 2, 3, 4]
+        for layer, share in shares.items():
+            assert band[0] * even <= share <= band[1] * even, (seed, layer)
+        # the bias moved, every expert's, and it is the seed's alone
+        again, _ = balance(small, seed)
+        assert sorted(bias) == ['l%d_moe_expert_bias' % i for i in (1, 2, 3,
+                                                                    4)]
+        for name, value in bias.items():
+            assert np.asarray(value).shape == (16,) and \
+                np.asarray(value).all()
+            np.testing.assert_array_equal(np.asarray(value),
+                                          np.asarray(again[name]))
+    first = [shares[1] for shares in lot]
+    assert max(first) >= 2 * min(first), first
+    # the loads are the reference's own under that bias (its ``route``)
+    host = fit_lm.make_batches(seed, 8, 2, 64, 512, 1.0)
+    args, _ = fit_lm.make_weights(small.symbol, small.shapes, seed)
+    _, held = small.reference.forward(dict(args, **bias), host[0][0],
+                                      small.config)
+    for layer in shares:
+        np.testing.assert_array_equal(np.asarray(load[layer])[0, :4],
+                                      np.asarray(held[layer]))
+
+
+def test_a_bias_left_as_drawn_trips_the_check(small):
+    from benchmark.drivers import fit_lm
+    _, load = balance(small, SEEDS_APART[1], passes=0, bias='drawn')
+    with pytest.raises(harness.BenchmarkError,
+                       match=r'not balanced.*outside 23.00% to 27.00% in '
+                       r'layer 1 \(9\.\d\d%\)'):
+        fit_lm.check_balance(load, small.config,
+                             small.cell['held_share_band'])
+
+
 # -- the cell, rehearsed ----------------------------------------------------
 
 def test_the_cell_runs_end_to_end_at_its_rehearsal_sizes(tmp_path):
+    from benchmark.drivers import fit_lm
     environ = dict(os.environ, JAX_PLATFORMS='cpu',
                    JAX_COMPILATION_CACHE_DIR=str(tmp_path / 'cache'))
     lines = {}
@@ -361,11 +476,56 @@ def test_the_cell_runs_end_to_end_at_its_rehearsal_sizes(tmp_path):
         lines[trace] = json.loads(out[-1])
         assert '0 tokens dropped' in done.stdout
         assert 'inside the window: 0' in done.stdout
+        # the bias is what set-up made, every trained array moved, and each
+        # layer's balance was inside the band (it is logged only where the
+        # check did not end the run)
+        assert ('the selection bias bit for bit what set-up made in 4 of 4 '
+                'layers, 49 of 49 trained arrays moved\n') in done.stdout
+        assert done.stdout.count('the bias balanced: held experts') == 4
+        # every number compared beside its limit: the last lines of standard
+        # error and the line's last key
+        compared = lines[trace]['compared']
+        assert list(lines[trace])[-1] == 'compared'
+        last = done.stderr.strip().splitlines()[-len(compared):]
+        assert [l.split()[1].rstrip(':') for l in last] == list(compared)
+        assert all(l.startswith('compared ') and 'NOT HELD' not in l
+                   for l in last)
+        assert compared['bias_moved'] == {'value': 0.0, 'most': 0.0}
+        assert compared['arrays_unmoved'] == {'value': 0.0, 'most': 0.0}
+        assert set(fit_lm.LIMITS) < set(compared)
     for line in lines.values():
         assert line['correct'] is True and line['rehearsal'] is True
         assert line['failed'] == 0 and line['attempted'] >= 1
-        assert line['metrics'] == {}    # a CPU run gives no device number
+    # a CPU run gives no device number: only what the program counted
+    assert lines['0']['metrics'] == {}
+    assert list(lines['1']['metrics']) == ['lm_held_share_pct']
+    held = lines['1']['metrics']['lm_held_share_pct']
+    assert held['unit'] == '%' and 23.0 <= held['value'] <= 27.0
     assert lines['1']['attempted'] == 20
+
+
+def test_a_balance_that_did_not_happen_ends_the_run_with_no_result(tmp_path):
+    # the cell's file with no pass of the rule: the bias stays at zero, the
+    # drawn routers give the held experts their lot, and the check ends
+    # the run before the reference's first step
+    code = (
+        'import sys; sys.path.insert(0, %r)\n'
+        'from benchmark import manifest, run\n'
+        'load = manifest.load_cell\n'
+        'manifest.load_cell = lambda name: dict(load(name), '
+        'balance_passes=0)\n'
+        'run.main([\'--workload\', %r, \'--seed\', \'8\', \'--seconds\', '
+        '\'1\', \'--rehearse-cpu\'])\n' % (ROOT, CELL))
+    done = subprocess.run(
+        [sys.executable, '-c', code], cwd=ROOT,
+        env=dict(os.environ, JAX_PLATFORMS='cpu',
+                 JAX_COMPILATION_CACHE_DIR=str(tmp_path / 'cache')),
+        capture_output=True, text=True, timeout=600)
+    assert done.returncode != 0
+    assert 'the selection bias is not balanced' in done.stderr
+    assert 'the bias balanced: held experts receive' in done.stdout
+    assert not any(l.startswith('{') for l in done.stdout.splitlines())
+    assert 'compared ' not in done.stderr
 
 
 def test_without_a_chip_and_without_the_switch_the_cell_refuses():
@@ -376,3 +536,57 @@ def test_without_a_chip_and_without_the_switch_the_cell_refuses():
         text=True, timeout=300)
     assert done.returncode != 0
     assert 'no CPU fall-back' in done.stderr + done.stdout
+
+
+# -- the rest of a run with the timed path broken underneath ----------------
+# Each fault is planted in the program (or in what feeds it) by a line run
+# before ``run.main``; the harness's look for a chip is skipped by the
+# rehearsal switch, everything else is the run's own.  The run has to end
+# with a result whose ``correct`` is false, and by the numbers named.
+FAULTS = {
+    # the optimizer's update gives back the array and the state it was given
+    'a_step_that_returns_its_state_unchanged': ("""
+from mxnet_tpu import optimizer
+plain = optimizer.Adam.make_functional
+def make_functional(self, *args, **kwargs):
+    fo = plain(self, *args, **kwargs)
+    fo._update_one = lambda name, w, g, s, lr_t: (w, s)
+    return fo
+optimizer.Adam.make_functional = make_functional
+""", {'gradient_error_median', 'gradient_error_worst', 'arrays_unmoved',
+      'loss_last_over_first'}),
+    # the batch's second sequence never arrives: the first stands in its
+    # place, so the sum is over half of the batch, counted twice
+    'half_of_the_batch_left_out': ("""
+import mxnet_tpu as mx
+plain = mx.io.DataBatch
+def batch(data, label, **kwargs):
+    data, label = [d.copy() for d in data], [l.copy() for l in label]
+    data[0][1], label[0][1] = data[0][0], label[0][0]
+    return plain(data, label, **kwargs)
+mx.io.DataBatch = batch
+""", {'gradient_error_median'}),
+}
+
+
+@pytest.mark.parametrize('fault', sorted(FAULTS))
+def test_a_run_with_the_timed_path_broken_reads_not_correct(fault, tmp_path):
+    plant, names = FAULTS[fault]
+    code = ('import sys; sys.path.insert(0, %r)\n%s\n'
+            'from benchmark import run\n'
+            'run.main([\'--workload\', %r, \'--seed\', \'2147491111\', '
+            '\'--seconds\', \'1\', \'--rehearse-cpu\'])\n'
+            % (ROOT, plant, CELL))
+    done = subprocess.run(
+        [sys.executable, '-c', code], cwd=ROOT,
+        env=dict(os.environ, JAX_PLATFORMS='cpu',
+                 JAX_COMPILATION_CACHE_DIR=str(tmp_path / 'cache')),
+        capture_output=True, text=True, timeout=900)
+    assert done.returncode == 0, done.stdout[-3000:] + done.stderr[-3000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert line['correct'] is False
+    not_held = {name for name, entry in line['compared'].items()
+                if not harness.holds(entry)}
+    print(fault, {k: line['compared'][k]['value'] for k in not_held})
+    assert names <= not_held, (names, not_held)
+    assert done.stderr.count('NOT HELD') == len(not_held)
