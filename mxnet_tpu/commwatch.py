@@ -17,7 +17,7 @@ a cluster reports per-rank comm/step-time centrally for free):
 1. **Per-executable collective accounting** — :func:`analyze_executable`
    (invoked from every ``perfwatch.register_executable`` site: the
    warm-start AOT pool, the hot-path AOT capture in
-   ``Module._run_fused``, Predictor/Executor forwards, bench) walks the
+   ``Module._run_fused``, Predictor/Executor forwards) walks the
    compiled program's HLO text and records, per collective kind
    (all-reduce, all-gather, reduce-scatter, all-to-all,
    collective-permute), the instruction count, the payload bytes and the
@@ -34,7 +34,7 @@ a cluster reports per-rank comm/step-time centrally for free):
    :data:`ICI_PEAKS` beside it; ``MXTPU_PEAK_BW`` override) and
    publishes ``perf.comm_fraction`` = t_comm / (t_comm + t_compute) ∈
    [0, 1] — the number that says whether buying faster chips or a
-   fatter interconnect moves the bench.
+   fatter interconnect moves the step time.
 
 3. **Cross-rank step cadence** — every step's dispatch-to-dispatch
    interval lands in a ``comm.step_time`` histogram and every dist
@@ -296,7 +296,7 @@ def analyze_executable(kind, key, compiled, num_devices=1):
     in the tree).  Publishes per-program
     ``comm.<ckind>[<key>].{count,bytes}`` gauges, per-kind running
     totals (``comm.<ckind>.{count,bytes}`` — what the analytic checks
-    and bench report read without knowing program hashes), and keeps
+    read without knowing program hashes), and keeps
     the row for :func:`on_step`'s per-step attribution.  Idempotent per
     (kind, key); never raises; returns the row or None."""
     if not _on:

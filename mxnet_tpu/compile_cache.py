@@ -100,7 +100,7 @@ def resolve_cache_dir(checkout_default=False):
       code sets ``jax_compilation_cache_dir`` (``jax_owns_it`` True);
     - else ``MXTPU_COMPILE_CACHE``;
     - else :data:`CHECKOUT_CACHE_DIR` when ``checkout_default`` (the run
-      scripts: chip_smoke.py, bench.py, examples/train_imagenet.py), or
+      scripts: chip_smoke.py, examples/train_imagenet.py), or
       ``None`` (a plain ``import mxnet_tpu`` writes no cache).
     """
     env = os.environ.get('JAX_COMPILATION_CACHE_DIR')

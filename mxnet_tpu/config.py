@@ -100,9 +100,6 @@ _register('MXNET_CUDNN_AUTOTUNE_DEFAULT', True, _bool,
           'compilation, knob kept for compat (env_var.md:79).',
           effective=False)
 # -- TPU-stack additions ---------------------------------------------------
-_register('MXTPU_CONV_LAYOUT', 'NCHW', str,
-          'Internal conv layout (NCHW | NHWC). XLA lays out either '
-          'well on TPU; exposed for experimentation.')
 _register('MXTPU_DISABLE_PALLAS', False, _bool,
           'Force pure-XLA fallbacks instead of Pallas kernels.')
 _register('MXTPU_FORCE_PALLAS_INTERPRET', False, _bool,
@@ -119,8 +116,7 @@ _register('MXTPU_FUSE', '', str,
           "folding, dead-branch pruning, elementwise-epilogue fusion); "
           "'aggressive' = adds the folding/kernel rewrites (conv+BN "
           'weight folding, BN->relu->conv and BN->relu Pallas fusion, '
-          'NHWC region growth — rtol-level parity).  Unset: legacy '
-          'MXTPU_FUSE_BN_CONV mapping (set -> aggressive, else off).  '
+          'NHWC region growth — rtol-level parity).  Unset means off.  '
           'Per-pass counters land as fuse.pass.* when metrics are on; '
           'tools/check_fusion.py gates parity and the cost_analysis '
           'win.')
@@ -129,12 +125,6 @@ _register('MXTPU_FUSE_SKIP', '', str,
           'from the MXTPU_FUSE pipeline — per-pass disable for '
           'attribution/bisection (e.g. '
           "MXTPU_FUSE_SKIP=epilogue,nhwc_regions).")
-_register('MXTPU_FUSE_BN_CONV', False, _bool,
-          'LEGACY alias for the step-compiler knob: fuse '
-          'BatchNorm->relu->conv chains into the Pallas fused kernels '
-          'inside the compiled train step.  Equivalent to '
-          'MXTPU_FUSE=aggressive when MXTPU_FUSE is unset; prefer '
-          'MXTPU_FUSE.')
 _register('MXTPU_FUSED_FIT', True, _bool,
           'Module.fit fuses forward+backward+optimizer into one compiled '
           'program when the optimizer is functionally expressible. Set 0 '
@@ -466,8 +456,8 @@ _register('MXTPU_STEP_SAMPLE', 0, int,
           'syncs per epoch, metric.host_syncs untouched.  0 = never '
           'sample.  Requires MXTPU_PERFWATCH.')
 _register('MXTPU_PEAK_FLOPS', 0.0, float,
-          'Override the chip peak FLOP/s used as the perf.mfu / bench '
-          'MFU denominator.  0 = look the attached device kind up in '
+          'Override the chip peak FLOP/s used as the perf.mfu '
+          'denominator.  0 = look the attached device kind up in '
           'perfwatch.PEAKS; a kind that is not there (the CPU backend '
           'included) raises, so CPU runs that want an MFU set this.')
 # -- communication-attribution plane (docs/observability.md) ---------------

@@ -200,7 +200,7 @@ class ElasticCoordinator(object):
         if stamp:
             # the previous step() resolved a repair and a batch has
             # been dispatched since — this is the post-repair
-            # productive step the recovery_time_secs bench leg times
+            # productive step tools/check_elastic.py times recovery to
             instrument.set_gauge('elastic.post_repair_step_at',
                                  time.time())
         if fenced:

@@ -35,8 +35,7 @@ pass                level       rewrite
 
 ``MXTPU_FUSE=off|safe|aggressive`` selects the pass set (``off`` means
 byte-identical to the unfused program — the pipeline returns the input
-symbol object untouched); unset falls back to the legacy
-``MXTPU_FUSE_BN_CONV`` knob (mapped to ``aggressive``).
+symbol object untouched); unset means ``off``.
 ``MXTPU_FUSE_SKIP=name,name`` disables individual passes.  Every pass
 reports ``fuse.pass.<name>.{rewrites,nodes_removed}`` through perfwatch
 (:func:`perfwatch.note_fuse`), and ``tools/check_fusion.py`` gates the
@@ -69,7 +68,8 @@ and the normalized activation never materializes.  If any consumer is
 not a fusable conv the chain is left alone (the activation would
 materialize for that consumer anyway, making fusion traffic-neutral).
 
-Enabled for Module.fit / make_fit_step via ``MXTPU_FUSE_BN_CONV=1``.  The rewrite preserves parameter names,
+Enabled for Module.fit / make_fit_step via ``MXTPU_FUSE=aggressive``.
+The rewrite preserves parameter names,
 aux state and observable numerics (tests/test_fuse_bn_conv.py asserts
 fwd+bwd equality for every shape class).
 """
@@ -79,6 +79,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import config
 from .symbol import Symbol, Node
 
 __all__ = ['fuse_bn_relu_conv', 'fuse_bn_relu_conv1x1',
@@ -1163,20 +1164,15 @@ _MODES = ('off', 'safe', 'aggressive')
 
 
 def fuse_mode():
-    """Resolve the step-compiler mode from ``MXTPU_FUSE``; unset falls
-    back to the legacy ``MXTPU_FUSE_BN_CONV`` knob ('aggressive' when
-    set — the old knob enabled the aggressive-class rewrites).  An
-    unrecognized value raises loudly at program-build time: a
-    misspelled perf knob silently meaning 'off' is how trajectories go
-    blind."""
-    from . import config
-    raw = str(config.get('MXTPU_FUSE') or '').strip().lower()
-    if raw in _MODES:
-        return raw
-    if raw:
+    """Resolve the step-compiler mode from ``MXTPU_FUSE``; unset means
+    'off'.  An unrecognized value raises loudly at program-build time:
+    a misspelled perf knob silently meaning 'off' is how trajectories
+    go blind."""
+    raw = str(config.get('MXTPU_FUSE') or '').strip().lower() or 'off'
+    if raw not in _MODES:
         raise ValueError('MXTPU_FUSE must be off|safe|aggressive, '
                          'got %r' % raw)
-    return 'aggressive' if config.get('MXTPU_FUSE_BN_CONV') else 'off'
+    return raw
 
 
 def apply_fuse_passes(symbol: Symbol, is_train, mode=None) -> Symbol:
@@ -1190,7 +1186,6 @@ def apply_fuse_passes(symbol: Symbol, is_train, mode=None) -> Symbol:
         mode = fuse_mode()
     if mode == 'off':
         return symbol
-    from . import config
     skip = tuple(s.strip() for s in
                  str(config.get('MXTPU_FUSE_SKIP') or '').split(',')
                  if s.strip())

@@ -20,7 +20,7 @@ width, replacing the flat single-lane event buffer of the old
   latency SLOs) (executor cache hits vs. retraces,
   samples/sec, transfer bytes, per-phase wall time, device memory via
   ``memory_stats()``).  :func:`metrics_snapshot` returns it as a plain
-  dict; :func:`dump_metrics` writes the JSON next to a bench result.
+  dict; :func:`dump_metrics` writes it as JSON.
 - **Zero overhead when off** — module-level flags checked before any
   allocation: :func:`span` returns a shared no-op context manager and
   the :func:`inc`/:func:`set_gauge`/:func:`observe` helpers return

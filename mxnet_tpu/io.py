@@ -223,7 +223,7 @@ class DeviceFeedIter(DataIter):
         it, seen = data_iter, set()
         while it is not None and id(it) not in seen:
             seen.add(id(it))
-            # getattr: duck-typed iterators (bench synthetics) lack the
+            # getattr: duck-typed iterators (synthetic feeds) lack the
             # counting protocol; silencing them is still correct
             self._silenced.append(
                 (it, getattr(it, '_counts_io_batches', True)))

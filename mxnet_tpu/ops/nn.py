@@ -80,11 +80,6 @@ register('FullyConnected', _fc_apply,
 # lax.conv_general_dilated which XLA maps straight onto the MXU.
 # ---------------------------------------------------------------------------
 
-def _conv_layout():
-    from .. import config
-    return config.get('MXTPU_CONV_LAYOUT')
-
-
 def _conv_apply(attrs, inputs, is_train, rng):
     data, weight = inputs[0], inputs[1]
     no_bias = bool(attrs.get('no_bias', False))
@@ -101,26 +96,6 @@ def _conv_apply(attrs, inputs, is_train, rng):
     pad_pairs = [(p, q) for p, q in zip(
         pad, _tup(pad_hi, nd) if pad_hi else pad)]
     groups = int(attrs.get('num_group', 1))
-    if nd == 2 and _conv_layout() == 'NHWC':
-        # Internally run channels-last: the MXU-native layout.  Each conv
-        # is sandwiched in NCHW<->NHWC transposes; XLA's layout pass
-        # cancels the pairs between consecutive convs (elementwise ops in
-        # between are layout-agnostic), so the graph converges to
-        # channels-last end-to-end while the public API stays NCHW.
-        dn = jax.lax.conv_dimension_numbers(
-            (data.shape[0], data.shape[2], data.shape[3], data.shape[1]),
-            weight.shape[2:] + (weight.shape[1], weight.shape[0]),
-            ('NHWC', 'HWIO', 'NHWC'))
-        out = jax.lax.conv_general_dilated(
-            jnp.transpose(data, (0, 2, 3, 1)),
-            jnp.transpose(weight, (2, 3, 1, 0)),
-            window_strides=stride,
-            padding=pad_pairs, lhs_dilation=(1,) * nd,
-            rhs_dilation=dilate, dimension_numbers=dn,
-            feature_group_count=groups)
-        if not no_bias:
-            out = out + inputs[2].reshape((1, 1, 1, -1))
-        return [jnp.transpose(out, (0, 3, 1, 2))], {}
     dn = jax.lax.conv_dimension_numbers(
         data.shape, weight.shape,
         ('NCHW', 'OIHW', 'NCHW') if nd == 2 else ('NCW', 'OIW', 'NCW'))

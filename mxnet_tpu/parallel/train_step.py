@@ -284,7 +284,7 @@ def make_train_step(symbol: Symbol, optimizer_update: Callable,
                     compute_dtype=None):
     """Build ``step(params, aux, opt_state, batch, rng) ->
     (outputs, params, aux, opt_state)`` as one jitted program — the
-    bench/raw-API entry; a thin wrapper over :func:`make_fit_step` with
+    raw-API entry; a thin wrapper over :func:`make_fit_step` with
     no frozen params and the lr baked into ``optimizer_update``.
 
     ``batch_names`` is accepted for API stability (every non-batch arg

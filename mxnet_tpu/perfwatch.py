@@ -1,11 +1,10 @@
 """Performance-attribution plane — live MFU, step-time breakdown,
 device-memory ledger, OOM forensics.
 
-The ROADMAP's top perf item was blind: the only FLOPs/MFU accounting in
-the tree lived inline in ``bench.py``, so a normal training run exported
-no performance truth at all — no way to tell whether a step is
-compute-bound, feed-bound, or window-bound, and an HBM OOM died with a
-bare stack trace.  TensorFlow treats profiling/introspection as a
+Without it a normal training run exports no performance truth at all —
+no way to tell whether a step is compute-bound, feed-bound, or
+window-bound, and an HBM OOM dies with a bare stack trace.  TensorFlow
+treats profiling/introspection as a
 first-class mode of the same runtime (Abadi et al.,
 https://arxiv.org/pdf/1605.08695) and the MXNet paper leans on explicit
 memory accounting to hit its scaling curve (Chen et al.,
@@ -25,9 +24,7 @@ centrally in ``cluster_status.json``/``.prom``):
    ``xla.*`` gauges keyed by program signature, in the
    :func:`executables` table, and in the warmup manifest
    (``compile_cache.record_entry``) so a later process knows the cost
-   model before it compiles anything.  ``bench.py`` calls the same
-   :func:`extract_cost` / :func:`mfu` helpers instead of its former
-   inline copy.
+   model before it compiles anything.
 
 2. **Live MFU + step-time breakdown** — :func:`note_step` derives
    ``perf.mfu`` (executable FLOPs x steps/sec over the chip peak —
@@ -194,8 +191,7 @@ def activate_fit():
 def extract_cost(compiled):
     """``{'flops': F, 'bytes_accessed': B}`` from a compiled
     executable's ``cost_analysis()`` (zeros when the backend reports
-    none).  The single implementation
-    behind both the runtime gauges and ``bench.py``'s MFU line."""
+    none)."""
     out = {'flops': 0.0, 'bytes_accessed': 0.0}
     try:
         ca = compiled.cost_analysis()

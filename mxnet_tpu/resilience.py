@@ -67,11 +67,8 @@ import threading
 import time
 
 from . import config
-# top-level on purpose (fs and iowatch are jax-free): a lazy
-# in-function import would re-resolve the PACKAGE after bench.py's
-# module-shim loader has been torn down, dragging the full framework
-# (and jax) into a parent process that must stay backend-free until
-# the device probe clears
+# fs and iowatch are jax-free: importing them here keeps this module
+# usable from a process that must stay backend-free
 from . import fs
 from . import iowatch
 

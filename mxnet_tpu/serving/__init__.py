@@ -37,9 +37,8 @@ over its own disjoint device set).
   ``MXTPU_SERVE_DEADLINE_MS``) bound every wait with a typed
   :class:`DeadlineExceededError`, dropped at coalesce time — never
   executed dead (docs/serving.md "Failure semantics").
-- ``tools/serve_bench.py`` — open-/closed-loop load generator; the
-  ``serve_qps_at_p99_slo`` bench leg and the fleet's offline
-  calibrator.
+- ``tools/serve_bench.py`` — open-/closed-loop load generator and the
+  fleet's offline calibrator.
 - ``tools/check_serving.py`` / ``tools/check_fleet.py`` — end-to-end
   smokes (coalescing, bit-exact responses, shedding, hot reload; tp=2
   oracle parity, replica scaling, autoscale-on-load-step, priority

@@ -1,7 +1,7 @@
 package org.mxtpu
 
 /** GENERATED by tools/gen_scala_ops.py from the live op
-  * registry (228 ops) — do not edit.  Imperative invoke-
+  * registry (235 ops) — do not edit.  Imperative invoke-
   * by-name creators (NDArray.genericInvoke -> the C ABI
   * MXImperativeInvokeByName), the role of the reference
   * NDArrayMacro expansion.
@@ -43,6 +43,8 @@ object NDArrayOps {
     NDArray.genericInvoke("Flatten", inputs, attrs)
   def FullyConnected(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
     NDArray.genericInvoke("FullyConnected", inputs, attrs)
+  def GatedShortConv(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
+    NDArray.genericInvoke("GatedShortConv", inputs, attrs)
   def GridGenerator(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
     NDArray.genericInvoke("GridGenerator", inputs, attrs)
   def IdentityAttachKLSparseReg(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
@@ -73,12 +75,16 @@ object NDArrayOps {
     NDArray.genericInvoke("Pad", inputs, attrs)
   def Pooling(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
     NDArray.genericInvoke("Pooling", inputs, attrs)
+  def RMSNorm(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
+    NDArray.genericInvoke("RMSNorm", inputs, attrs)
   def RNN(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
     NDArray.genericInvoke("RNN", inputs, attrs)
   def ROIPooling(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
     NDArray.genericInvoke("ROIPooling", inputs, attrs)
   def Reshape(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
     NDArray.genericInvoke("Reshape", inputs, attrs)
+  def RotaryEmbedding(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
+    NDArray.genericInvoke("RotaryEmbedding", inputs, attrs)
   def SVMOutput(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
     NDArray.genericInvoke("SVMOutput", inputs, attrs)
   def SequenceLast(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
@@ -95,10 +101,14 @@ object NDArrayOps {
     NDArray.genericInvoke("SoftmaxActivation", inputs, attrs)
   def SoftmaxOutput(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
     NDArray.genericInvoke("SoftmaxOutput", inputs, attrs)
+  def SparseExperts(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
+    NDArray.genericInvoke("SparseExperts", inputs, attrs)
   def SpatialTransformer(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
     NDArray.genericInvoke("SpatialTransformer", inputs, attrs)
   def SwapAxis(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
     NDArray.genericInvoke("SwapAxis", inputs, attrs)
+  def SwiGLU(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
+    NDArray.genericInvoke("SwiGLU", inputs, attrs)
   def UpSampling(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
     NDArray.genericInvoke("UpSampling", inputs, attrs)
   def WarpCTC(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
@@ -325,6 +335,10 @@ object NDArrayOps {
     NDArray.genericInvoke("flip", inputs, attrs)
   def floor(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
     NDArray.genericInvoke("floor", inputs, attrs)
+  def fused_bn_relu(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
+    NDArray.genericInvoke("fused_bn_relu", inputs, attrs)
+  def fused_dot_epilogue(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
+    NDArray.genericInvoke("fused_dot_epilogue", inputs, attrs)
   def fused_scale_bias_dot(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
     NDArray.genericInvoke("fused_scale_bias_dot", inputs, attrs)
   def gamma(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =

@@ -1,7 +1,7 @@
 package org.mxtpu
 
 /** GENERATED by tools/gen_scala_ops.py from the live op
-  * registry (228 ops) — do not edit.  The role of the
+  * registry (235 ops) — do not edit.  The role of the
   * reference scala-package macro expansion
   * (SymbolImplMacros.scala): one typed creator per
   * registered operator, delegating to Symbol.create.
@@ -46,6 +46,8 @@ object SymbolOps {
     Symbol.create("Flatten", name)(inputs: _*)(attrs: _*)
   def FullyConnected(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
     Symbol.create("FullyConnected", name)(inputs: _*)(attrs: _*)
+  def GatedShortConv(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
+    Symbol.create("GatedShortConv", name)(inputs: _*)(attrs: _*)
   def GridGenerator(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
     Symbol.create("GridGenerator", name)(inputs: _*)(attrs: _*)
   def IdentityAttachKLSparseReg(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
@@ -76,12 +78,16 @@ object SymbolOps {
     Symbol.create("Pad", name)(inputs: _*)(attrs: _*)
   def Pooling(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
     Symbol.create("Pooling", name)(inputs: _*)(attrs: _*)
+  def RMSNorm(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
+    Symbol.create("RMSNorm", name)(inputs: _*)(attrs: _*)
   def RNN(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
     Symbol.create("RNN", name)(inputs: _*)(attrs: _*)
   def ROIPooling(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
     Symbol.create("ROIPooling", name)(inputs: _*)(attrs: _*)
   def Reshape(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
     Symbol.create("Reshape", name)(inputs: _*)(attrs: _*)
+  def RotaryEmbedding(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
+    Symbol.create("RotaryEmbedding", name)(inputs: _*)(attrs: _*)
   def SVMOutput(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
     Symbol.create("SVMOutput", name)(inputs: _*)(attrs: _*)
   def SequenceLast(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
@@ -98,10 +104,14 @@ object SymbolOps {
     Symbol.create("SoftmaxActivation", name)(inputs: _*)(attrs: _*)
   def SoftmaxOutput(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
     Symbol.create("SoftmaxOutput", name)(inputs: _*)(attrs: _*)
+  def SparseExperts(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
+    Symbol.create("SparseExperts", name)(inputs: _*)(attrs: _*)
   def SpatialTransformer(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
     Symbol.create("SpatialTransformer", name)(inputs: _*)(attrs: _*)
   def SwapAxis(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
     Symbol.create("SwapAxis", name)(inputs: _*)(attrs: _*)
+  def SwiGLU(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
+    Symbol.create("SwiGLU", name)(inputs: _*)(attrs: _*)
   def UpSampling(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
     Symbol.create("UpSampling", name)(inputs: _*)(attrs: _*)
   def WarpCTC(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
@@ -328,6 +338,10 @@ object SymbolOps {
     Symbol.create("flip", name)(inputs: _*)(attrs: _*)
   def floor(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
     Symbol.create("floor", name)(inputs: _*)(attrs: _*)
+  def fused_bn_relu(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
+    Symbol.create("fused_bn_relu", name)(inputs: _*)(attrs: _*)
+  def fused_dot_epilogue(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
+    Symbol.create("fused_dot_epilogue", name)(inputs: _*)(attrs: _*)
   def fused_scale_bias_dot(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
     Symbol.create("fused_scale_bias_dot", name)(inputs: _*)(attrs: _*)
   def gamma(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =

@@ -5,8 +5,7 @@ the sharding inspector (degradation records, warn-once, counter,
 explain_sharding rendering, mesh-free shapes mode), cross-rank step
 skew (compute_step_skew units + the health plane's laggard threshold),
 merged-trace clock alignment (merge_traces anchor shift + check_trace
-offset-inconsistency rejection), the check_perf comm fields, and the
-knobs-off overhead guard."""
+offset-inconsistency rejection), and the knobs-off overhead guard."""
 import json
 import logging
 import os
@@ -25,7 +24,6 @@ from mxnet_tpu.parallel.zero import zero_spec_for
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, 'tools'))
-import check_perf  # noqa: E402
 import check_trace  # noqa: E402
 import explain_sharding  # noqa: E402
 import merge_traces  # noqa: E402
@@ -470,56 +468,6 @@ def test_unanchored_lane_merges_unaligned(tmp_path):
             (e.get('args') or {}).get('aligned')]
     assert sync == []
     assert check_trace.validate_events(doc['traceEvents']) == []
-
-
-# ---------------------------------------------------------------------------
-# Satellite: check_perf comm fields
-# ---------------------------------------------------------------------------
-
-def test_check_perf_comm_fields_direction(tmp_path):
-    base = {'multichip_fit_ips': {'value': 7000.0, 'comm_fraction': 0.10,
-                                  'comm_bytes_per_step': 4862.0}}
-    p_base = tmp_path / 'base.json'
-    p_base.write_text(json.dumps(base))
-    assert check_perf.main([str(p_base), str(p_base)]) == 0
-    # comm_fraction GREW past tol+slack: regression even though
-    # throughput held (lower-is-better, direction-aware)
-    bad = {'multichip_fit_ips': {'value': 7000.0, 'comm_fraction': 0.40,
-                                 'comm_bytes_per_step': 4862.0}}
-    p_bad = tmp_path / 'bad.json'
-    p_bad.write_text(json.dumps(bad))
-    assert check_perf.main([str(p_base), str(p_bad)]) == 1
-    _, regs, _ = check_perf.compare(check_perf.load_legs(str(p_base)),
-                                    check_perf.load_legs(str(p_bad)))
-    assert ('multichip_fit_ips', 'comm_fraction') in \
-        {(leg, f) for leg, f, _, _ in regs}
-    # within the absolute slack: a wiggle never pages
-    ok = {'multichip_fit_ips': {'value': 7000.0, 'comm_fraction': 0.115,
-                                'comm_bytes_per_step': 4900.0}}
-    p_ok = tmp_path / 'ok.json'
-    p_ok.write_text(json.dumps(ok))
-    assert check_perf.main([str(p_base), str(p_ok)]) == 0
-
-
-def test_bench_report_comm_section(capsys):
-    import bench_report
-    state = {'multichip_fit_ips': {'value': 7246.8,
-                                   'comm_fraction': 0.74,
-                                   'comm_bytes_per_step': 4862.0}}
-    snap = {'gauges': {'perf.comm_fraction': 0.74,
-                       'comm.bytes_per_step': 4862.0,
-                       'comm.all_reduce.count': 8,
-                       'comm.all_reduce.bytes': 2260.0,
-                       'comm.all_reduce.wire_bytes': 4854.0,
-                       'comm.all_gather.count': 2,
-                       'comm.all_gather.bytes': 512.0,
-                       'comm.all_gather.wire_bytes': 256.0}}
-    bench_report.render_comm_split(state, snap)
-    out = capsys.readouterr().out
-    assert 'Communication plane' in out
-    assert 'all-reduce' in out and 'all-gather' in out
-    assert 'comm fraction 74.0%' in out
-    assert 'leg multichip_fit_ips' in out
 
 
 # ---------------------------------------------------------------------------

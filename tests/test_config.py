@@ -1,6 +1,7 @@
 """Env-var config registry tests (reference docs/how_to/env_var.md,
 dmlc::GetEnv call sites)."""
 import os
+import re
 import subprocess
 import sys
 
@@ -54,3 +55,46 @@ def test_naive_engine_env(tmp_path):
                           capture_output=True, text=True, timeout=120,
                           env=env)
     assert 'naive-ok' in proc.stdout, proc.stderr[-1500:]
+
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _sources(*dirs, suffixes=('.py', '.md')):
+    for d in dirs:
+        for dirpath, _, files in os.walk(os.path.join(REPO, d)):
+            for f in files:
+                if f.endswith(suffixes):
+                    path = os.path.join(dirpath, f)
+                    with open(path, encoding='utf-8') as fh:
+                        yield os.path.relpath(path, REPO), fh.read()
+
+
+def test_env_vars_doc_is_the_registry():
+    """docs/env_vars.md holds config.describe() verbatim: a knob that
+    is added or removed shows in the document or fails here."""
+    with open(os.path.join(REPO, 'docs', 'env_vars.md')) as f:
+        assert config.describe() in f.read(), \
+            'regenerate docs/env_vars.md from config.describe()'
+
+
+def test_every_knob_is_read_and_the_removed_names_stay_gone():
+    """Every registered MXTPU_* name is read by a config.get under
+    mxnet_tpu/ or tools/ (a knob nothing reads is a registration to
+    delete), and what was removed with the old benchmark stack is named
+    nowhere.  The names are assembled here so that this file does not
+    hold them either."""
+    code = '\n'.join(text for _, text in
+                     _sources('mxnet_tpu', 'tools', suffixes=('.py',)))
+    unread = [n for n in config.list_knobs() if n.startswith('MXTPU_')
+              and not re.search(r'''get\(\s*['"]%s['"]''' % n, code)]
+    assert unread == []
+    gone = ['MXTPU_FUSE_' + 'BN_CONV', 'MXTPU_CONV_' + 'LAYOUT',
+            'bench' + '.py', 'check_' + 'perf', 'bench_' + 'report']
+    pattern = re.compile('|'.join(
+        r'(?<![A-Za-z0-9_])%s' % re.escape(g) for g in gone))
+    named = sorted({(path, m.group(0)) for path, text in
+                    _sources('mxnet_tpu', 'tools', 'tests', 'examples',
+                             'docs')
+                    for m in pattern.finditer(text)})
+    assert named == []

@@ -104,7 +104,7 @@ def test_fused_gradients_match():
 
 
 def test_fit_step_knob(monkeypatch):
-    """MXTPU_FUSE_BN_CONV=1 routes make_fit_step through the rewrite
+    """MXTPU_FUSE=aggressive routes make_fit_step through the rewrite
     and parameters evolve identically to the unfused step."""
     from mxnet_tpu.parallel.train_step import (make_train_step,
                                                make_sgd_momentum,
@@ -121,9 +121,9 @@ def test_fit_step_knob(monkeypatch):
     results = {}
     for fuse_on in (False, True):
         if fuse_on:
-            monkeypatch.setenv('MXTPU_FUSE_BN_CONV', '1')
+            monkeypatch.setenv('MXTPU_FUSE', 'aggressive')
         else:
-            monkeypatch.delenv('MXTPU_FUSE_BN_CONV', raising=False)
+            monkeypatch.delenv('MXTPU_FUSE', raising=False)
         step = make_train_step(net, opt, ('data', 'softmax_label'),
                                donate=False)
         p, a, s = dict(params0), dict(aux), sgd_momentum_init(params0)
@@ -279,9 +279,9 @@ def test_eval_step_knob(monkeypatch):
     outs = {}
     for on in (False, True):
         if on:
-            monkeypatch.setenv('MXTPU_FUSE_BN_CONV', '1')
+            monkeypatch.setenv('MXTPU_FUSE', 'aggressive')
         else:
-            monkeypatch.delenv('MXTPU_FUSE_BN_CONV', raising=False)
+            monkeypatch.delenv('MXTPU_FUSE', raising=False)
         outs[on] = np.asarray(
             make_eval_step(net)(params, aux, batch, key)[0])
     np.testing.assert_allclose(outs[False], outs[True],
@@ -363,9 +363,9 @@ def test_eval_knob_applies_both_passes(monkeypatch):
     outs = {}
     for on in (False, True):
         if on:
-            monkeypatch.setenv('MXTPU_FUSE_BN_CONV', '1')
+            monkeypatch.setenv('MXTPU_FUSE', 'aggressive')
         else:
-            monkeypatch.delenv('MXTPU_FUSE_BN_CONV', raising=False)
+            monkeypatch.delenv('MXTPU_FUSE', raising=False)
         outs[on] = np.asarray(
             make_eval_step(net)(params, aux, batch, key)[0])
     np.testing.assert_allclose(outs[False], outs[True], rtol=1e-5,

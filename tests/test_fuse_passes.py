@@ -203,12 +203,9 @@ def test_pass_counters_pinned(monkeypatch):
 
 def test_mode_knob_semantics(monkeypatch):
     monkeypatch.delenv('MXTPU_FUSE', raising=False)
-    monkeypatch.delenv('MXTPU_FUSE_BN_CONV', raising=False)
     assert fuse.fuse_mode() == 'off'
-    monkeypatch.setenv('MXTPU_FUSE_BN_CONV', '1')
-    assert fuse.fuse_mode() == 'aggressive'   # legacy mapping
     monkeypatch.setenv('MXTPU_FUSE', 'safe')
-    assert fuse.fuse_mode() == 'safe'         # explicit knob wins
+    assert fuse.fuse_mode() == 'safe'
     monkeypatch.setenv('MXTPU_FUSE', 'bogus')
     with pytest.raises(ValueError):
         fuse.fuse_mode()
@@ -429,20 +426,18 @@ def test_check_fusion_smoke():
 # ---------------------------------------------------------------------------
 
 def _floor_hook():
-    """The inlined ideal off path: the two knob reads fuse_mode()
-    cannot avoid (MXTPU_FUSE, then the legacy alias)."""
-    if not (str(config.get('MXTPU_FUSE') or '').strip().lower()
-            or config.get('MXTPU_FUSE_BN_CONV')):
+    """The inlined ideal off path: the one knob read fuse_mode()
+    cannot avoid."""
+    if not str(config.get('MXTPU_FUSE') or '').strip().lower():
         return None
 
 
 def test_knobs_off_zero_surface_guard(monkeypatch):
-    """With both knobs unset apply_fuse_passes must stay knob-read
-    cheap (< 2x the inlined two-env-read floor) and return the input
+    """With the knob unset apply_fuse_passes must stay knob-read
+    cheap (< 2x the inlined one-env-read floor) and return the input
     object — program-build sites pay nothing for the pipeline's
     existence."""
     monkeypatch.delenv('MXTPU_FUSE', raising=False)
-    monkeypatch.delenv('MXTPU_FUSE_BN_CONV', raising=False)
     net = _net()
     assert fuse.apply_fuse_passes(net, True) is net
     n = 5000

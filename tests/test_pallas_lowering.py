@@ -141,21 +141,33 @@ def test_flash_attention_backward_verifies(b, h, t, d):
 
 
 def test_fused_resnet50_train_step_verifies(monkeypatch):
-    """The full MXTPU_FUSE_BN_CONV=1 train step — every rewritten conv
+    """The full MXTPU_FUSE=aggressive train step — every rewritten conv
     with its real shape class — must pass Mosaic verification, and the
     NHWC-region pass must keep fused chains channels-last (without it
     every fused node is sandwiched in NCHW<->NHWC activation
     transposes, 389 at bs=8, which custom calls cannot absorb as
     layouts; with it only foldable matmul/weight operand transposes
     and a couple of region boundaries remain, ~187)."""
-    monkeypatch.setenv('MXTPU_FUSE_BN_CONV', '1')
-    import bench
+    monkeypatch.setenv('MXTPU_FUSE', 'aggressive')
+    from mxnet_tpu import models
     from mxnet_tpu.parallel.train_step import (
         make_train_step, make_sgd_momentum, sgd_momentum_init)
     # bs=8: below that, small spatial*batch products fail the
     # kernels' block-divisibility guards and dispatch to XLA,
     # shrinking the kernel count
-    sym, params, aux, batch = bench._resnet50_setup(8)
+    sym = models.get_symbol('resnet-50', num_classes=1000,
+                            stem='space_to_depth')
+    dshape = (8, 3, 224, 224)
+    arg_shapes, _, aux_shapes = sym.infer_shape(data=dshape)
+    # only shapes and dtypes reach the lowering: no values are drawn
+    params = {name: jnp.zeros(shape, jnp.float32)
+              for name, shape in zip(sym.list_arguments(), arg_shapes)
+              if name not in ('data', 'softmax_label')}
+    aux = {name: jnp.ones(shape, jnp.float32)
+           for name, shape in zip(sym.list_auxiliary_states(),
+                                  aux_shapes)}
+    batch = {'data': jnp.zeros(dshape, jnp.bfloat16),
+             'softmax_label': jnp.zeros(dshape[:1], jnp.float32)}
     opt = make_sgd_momentum(lr=0.05, momentum=0.9, wd=1e-4,
                             rescale_grad=0.125)
     step = make_train_step(sym, opt, ('data', 'softmax_label'),

@@ -2,8 +2,8 @@
 per-executable XLA cost/memory accounting, live MFU + step-phase
 attribution, the device-memory ledger (alloc/donate/free with the
 donated-buffer double-count guard), sampled-step sync budget, OOM
-forensics, the check_perf regression gate, the check_trace perf-span
-validation, and the knobs-off overhead guard."""
+forensics, the check_trace perf-span validation, and the knobs-off
+overhead guard."""
 import gc
 import json
 import math
@@ -20,7 +20,6 @@ from mxnet_tpu import callback, instrument, perfwatch
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, 'tools'))
-import check_perf  # noqa: E402
 import check_trace  # noqa: E402
 
 
@@ -778,56 +777,6 @@ def test_on_error_ignores_non_oom():
     assert not perfwatch.is_oom(ValueError('shape mismatch'))
     assert perfwatch.is_oom(RuntimeError('RESOURCE_EXHAUSTED: ...'))
     assert perfwatch.is_oom(RuntimeError('Out of memory allocating'))
-
-
-# ---------------------------------------------------------------------------
-# check_perf regression gate
-# ---------------------------------------------------------------------------
-
-def test_check_perf_gate(tmp_path):
-    base = {'resnet50_train': {'value': 2303.1, 'mfu': 0.61,
-                               'ts': '2026-01-01T00:00:00'},
-            'health_overhead_pct': {'value': 1.5},
-            'warm_start_speedup': {'value': 12.0, 'warmup_secs': 3.2},
-            'legacy_leg': 123.0}
-    p_base = tmp_path / 'base.json'
-    p_base.write_text(json.dumps(base))
-    # self-comparison smoke: identical files never regress
-    assert check_perf.main([str(p_base), str(p_base)]) == 0
-    # throughput cliff, overhead blowup, warmup blowup => regression
-    bad = {'resnet50_train': {'value': 1500.0, 'mfu': 0.30},
-           'health_overhead_pct': {'value': 9.5},
-           'warm_start_speedup': {'value': 12.0, 'warmup_secs': 9.0},
-           'legacy_leg': 123.0}
-    p_bad = tmp_path / 'bad.json'
-    p_bad.write_text(json.dumps(bad))
-    assert check_perf.main([str(p_base), str(p_bad)]) == 1
-    rows, regs, _ = check_perf.compare(check_perf.load_legs(str(p_base)),
-                                       check_perf.load_legs(str(p_bad)))
-    regressed = {(leg, field) for leg, field, _, _ in regs}
-    assert ('resnet50_train', 'value') in regressed
-    assert ('resnet50_train', 'mfu') in regressed
-    assert ('health_overhead_pct', 'value') in regressed
-    assert ('warm_start_speedup', 'warmup_secs') in regressed
-    # within-tolerance wiggle on a lower-is-better leg passes
-    ok = dict(base)
-    ok['health_overhead_pct'] = {'value': 1.6}
-    p_ok = tmp_path / 'ok.json'
-    p_ok.write_text(json.dumps(ok))
-    assert check_perf.main([str(p_base), str(p_ok)]) == 0
-    # a missing leg warns by default, gates under --require-all
-    partial = {'resnet50_train': base['resnet50_train']}
-    p_part = tmp_path / 'partial.json'
-    p_part.write_text(json.dumps(partial))
-    assert check_perf.main([str(p_base), str(p_part)]) == 0
-    assert check_perf.main([str(p_base), str(p_part),
-                            '--require-all']) == 1
-    # the driver's one-line primary form is accepted too
-    prim = {'metric': 'resnet50_train_imgs_per_sec_per_chip',
-            'value': 2303.1, 'unit': 'images/sec'}
-    p_prim = tmp_path / 'prim.json'
-    p_prim.write_text(json.dumps(prim))
-    assert check_perf.main([str(p_prim), str(p_prim)]) == 0
 
 
 # ---------------------------------------------------------------------------

@@ -190,7 +190,7 @@ def test_make_zero_train_step_rejects_local_normalization(mesh):
 
 
 def test_zero_step_with_fusion_parity(mesh, monkeypatch):
-    """MXTPU_FUSE_BN_CONV composes with the sharded ZeRO step: fused
+    """MXTPU_FUSE=aggressive composes with the sharded ZeRO step: fused
     and unfused runs under the same shard_map must produce identical
     parameters (both use shard-local BN statistics, so they are
     directly comparable)."""
@@ -232,8 +232,8 @@ def test_zero_step_with_fusion_parity(mesh, monkeypatch):
     monkeypatch.setenv('MXTPU_FORCE_PALLAS_INTERPRET', '1')
 
     results = {}
-    for fuse in ('0', '1'):
-        monkeypatch.setenv('MXTPU_FUSE_BN_CONV', fuse)
+    for fuse in ('off', 'aggressive'):
+        monkeypatch.setenv('MXTPU_FUSE', fuse)
         step = make_zero_train_step(build(), mesh, 'dp', lr=0.1,
                                     rescale_grad=1.0 / batch_global,
                                     donate=False)
@@ -244,13 +244,13 @@ def test_zero_step_with_fusion_parity(mesh, monkeypatch):
 
     for k in params:
         np.testing.assert_allclose(
-            np.asarray(results['0'][0][k]),
-            np.asarray(results['1'][0][k]),
+            np.asarray(results['off'][0][k]),
+            np.asarray(results['aggressive'][0][k]),
             rtol=1e-5, atol=1e-6, err_msg=k)
     for k in aux:
         np.testing.assert_allclose(
-            np.asarray(results['0'][1][k]),
-            np.asarray(results['1'][1][k]),
+            np.asarray(results['off'][1][k]),
+            np.asarray(results['aggressive'][1][k]),
             rtol=1e-5, atol=1e-6, err_msg=k)
 
 

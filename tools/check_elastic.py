@@ -25,11 +25,9 @@ productive step (the ``elastic.post_repair_step_at`` gauge).
 
 Run from the repo root::
 
-    python tools/check_elastic.py [--mode spare|shrink|both] [--bench]
+    python tools/check_elastic.py [--mode spare|shrink|both]
 
-``--bench`` runs the shrink leg only and prints one JSON line
-(``{"recovery_time_secs": ...}``) for ``bench.py``.  Exit code 0 on
-success.
+Exit code 0 on success.
 """
 import argparse
 import json
@@ -321,8 +319,6 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument('--mode', choices=('spare', 'shrink', 'both'),
                     default='both')
-    ap.add_argument('--bench', action='store_true',
-                    help='shrink leg only; print {"recovery_time_secs"}')
     ap.add_argument('--worker', action='store_true',
                     help=argparse.SUPPRESS)
     args = ap.parse_args()
@@ -332,10 +328,6 @@ def main():
 
     port = 9850 + (os.getpid() * 13) % 60
     outdir = tempfile.mkdtemp(prefix='mxtpu_elastic_')
-    if args.bench:
-        rec = run_shrink(outdir, port)
-        print(json.dumps({'recovery_time_secs': round(rec, 3)}))
-        return 0
     if args.mode in ('shrink', 'both'):
         run_shrink(outdir, port)
     if args.mode in ('spare', 'both'):

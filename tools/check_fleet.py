@@ -67,13 +67,6 @@ tools/check_multichip.py), in which
    per replica; and ``explain_request --strict`` must accept the
    postmortem.
 
-``--bench`` emits the one-JSON-line contract
-(``{"qps_1r", "qps_2r", "scaling", "slo_ms",
-"replica_recovery_secs"}``) — the qps fields off the REAL-model sweep
-for bench.py's ``serve_fleet_qps`` leg, the recovery figure off the
-chaos leg's worst quarantine→replacement repair for the
-``replica_recovery_secs`` leg (lower is better).
-
 Run from the repo root::
 
     python tools/check_fleet.py
@@ -298,7 +291,7 @@ def _sweep(server, name, make_inputs, slo_ms, duration_s=1.2,
     return best or {'qps': 0.0, 'p99_ms': float('inf')}, sweep
 
 
-def leg_fleet_scaling(bench=False):
+def leg_fleet_scaling():
     from mxnet_tpu.serving import ModelServer
 
     # -- mechanics: simulated accelerator, deterministic on any host --
@@ -387,9 +380,6 @@ def leg_fleet_scaling(bench=False):
         'real-model fleet scaling %.2fx under the %.2fx bound (%s)' \
         % (scaling_real, floor, why)
     server.close(drain=False)
-    return {'qps_1r': round(r1['qps'], 1), 'qps_2r': round(r2['qps'], 1),
-            'scaling': round(scaling_real, 3),
-            'scaling_sim': round(scaling_sim, 3), 'slo_ms': slo_ms}
 
 
 # ---------------------------------------------------------------------------
@@ -573,8 +563,7 @@ def leg_chaos():
     semantics"): a supervised 2-replica fleet takes a worker KILL and a
     30s flush WEDGE mid-traffic and must lose NOTHING — every request
     resolves (served or typed), both corpses are quarantined and
-    replaced, and the p99 recovers.  Returns the worst
-    quarantine→replacement recovery time for the bench contract."""
+    replaced, and the p99 recovers."""
     from mxnet_tpu import instrument, resilience
     from mxnet_tpu.serving import (DeadlineExceededError, ModelServer,
                                    ReplicaQuarantinedError,
@@ -750,7 +739,6 @@ def leg_chaos():
     log('check_fleet: brownout ladder OK — up %r, down %r'
         % (levels, [a for a, _ in down]))
     server.close(drain=False)
-    return round(max(recoveries), 4)
 
 
 # ---------------------------------------------------------------------------
@@ -879,7 +867,7 @@ def leg_request_attribution():
 # Driver
 # ---------------------------------------------------------------------------
 
-def worker(bench=False):
+def worker():
     import jax
     jax.config.update('jax_platforms', 'cpu')
     import mxnet_tpu  # noqa: F401 - full package wiring
@@ -889,24 +877,20 @@ def worker(bench=False):
         'worker needs the 8-virtual-device XLA_FLAGS pin'
 
     leg_tp_parity()
-    res = leg_fleet_scaling(bench=bench)
+    leg_fleet_scaling()
     leg_autoscale()
     leg_priority()
-    res['replica_recovery_secs'] = leg_chaos()
+    leg_chaos()
     leg_request_attribution()
-    if bench:
-        print(json.dumps(res, sort_keys=True))
     log('check_fleet worker OK')
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument('--worker', action='store_true', help=argparse.SUPPRESS)
-    ap.add_argument('--bench', action='store_true',
-                    help='emit the one-JSON-line qps contract on stdout')
     args = ap.parse_args()
     if args.worker:
-        worker(bench=args.bench)
+        worker()
         return 0
 
     env = dict(os.environ)
@@ -915,8 +899,6 @@ def main():
     for k in ('MXTPU_MESH', 'MXTPU_PARTITION', 'MXTPU_PROFILE'):
         env.pop(k, None)
     cmd = [sys.executable, os.path.abspath(__file__), '--worker']
-    if args.bench:
-        cmd.append('--bench')
     out = subprocess.run(cmd, env=env, timeout=900,
                          capture_output=True, text=True)
     sys.stderr.write(out.stderr)
@@ -925,10 +907,6 @@ def main():
               file=sys.stderr)
         sys.stderr.write(out.stdout[-2000:])
         return 1
-    if args.bench:
-        line = [l for l in out.stdout.strip().splitlines()
-                if l.startswith('{')][-1]
-        print(line)
     print('check_fleet OK', file=sys.stderr)
     return 0
 

@@ -24,19 +24,14 @@ child process, five assertions:
 5. **Exposition** — the Prometheus text rendering carries ``fuse.*``
    series.
 
-Usage: ``python tools/check_fusion.py``; ``--bench`` runs a short
-fused-step timing leg instead and prints one JSON line
-``{"ips", "flops_per_batch", "bytes_per_batch", "bytes_drop_frac"}``
-(the ``fused_step_ips`` bench.py leg — a CPU-hermetic datapoint so the
-fusion win has a trajectory even before the next TPU window).  Exits
-nonzero on any failed assertion.  CPU-safe; run by
+Usage: ``python tools/check_fusion.py``.  Exits nonzero on any failed
+assertion.  CPU-safe; run by
 ``tests/test_fuse_passes.py`` under tier-1 and by hand after touching
 fuse.py, the Pallas kernel library, or the executor's program paths.
 """
 from __future__ import annotations
 
 import argparse
-import json
 import os
 import subprocess
 import sys
@@ -292,90 +287,32 @@ def _child(min_bytes_drop):
     return 0
 
 
-def _child_bench():
-    os.environ.setdefault('JAX_PLATFORMS', 'cpu')
-    import time
-    import jax
-    jax.config.update('jax_platforms', 'cpu')
-    sys.path.insert(0, _REPO)
-    from mxnet_tpu import instrument, perfwatch
-    instrument.set_metrics(True)
-
-    net = _build_model()
-    vals, aux = _init_values(net)
-    comp_off, _ = _lower_step(net, 'off', vals, aux)
-    row_off = perfwatch.register_executable('fit_step_off', 'ref',
-                                            comp_off)
-    comp, _ = _lower_step(net, 'aggressive', vals, aux)
-    row = perfwatch.register_executable('fit_step_fused', 'ref', comp)
-
-    step = jax.jit(_raw_step(net, 'aggressive'))
-    params = {k: v for k, v in vals.items()
-              if k not in ('data', 'softmax_label')}
-    opt = {k: jax.numpy.zeros_like(v) for k, v in params.items()}
-    a = dict(aux)
-    batch = {'data': vals['data'],
-             'softmax_label': vals['softmax_label']}
-    key = jax.random.PRNGKey(0)
-    # warm (compile), then measure
-    warm = step(params, a, opt, batch, key)
-    jax.block_until_ready(warm[1])
-    n = 30
-    t0 = time.perf_counter()
-    for _ in range(n):
-        _, params, a, opt = step(params, a, opt, batch, key)
-    jax.block_until_ready(params)
-    dt = time.perf_counter() - t0
-    bytes_off = row_off['bytes_accessed'] if row_off else 0.0
-    drop = (bytes_off - row['bytes_accessed']) / bytes_off \
-        if row and bytes_off else 0.0
-    print(json.dumps({
-        'ips': BATCH * n / dt,
-        'flops_per_batch': row['flops'] if row else 0.0,
-        'bytes_per_batch': row['bytes_accessed'] if row else 0.0,
-        'bytes_drop_frac': drop,
-    }))
-    return 0
-
-
 # ---------------------------------------------------------------------------
 # hermetic parent
 # ---------------------------------------------------------------------------
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument('--child', choices=['check', 'bench'])
-    ap.add_argument('--bench', action='store_true',
-                    help='emit the one-line JSON bench contract '
-                         '(fused_step_ips leg) instead of asserting')
+    ap.add_argument('--child', action='store_true',
+                    help=argparse.SUPPRESS)
     ap.add_argument('--min-bytes-drop', type=float, default=0.10)
     args = ap.parse_args(argv)
 
-    if args.child == 'check':
+    if args.child:
         return _child(args.min_bytes_drop)
-    if args.child == 'bench':
-        return _child_bench()
 
     env = dict(os.environ)
     env['JAX_PLATFORMS'] = 'cpu'
-    for k in ('MXTPU_FUSE', 'MXTPU_FUSE_BN_CONV', 'MXTPU_FUSE_SKIP',
+    for k in ('MXTPU_FUSE', 'MXTPU_FUSE_SKIP',
               'MXTPU_FORCE_PALLAS_INTERPRET', 'MXTPU_ASSUME_TPU'):
         env.pop(k, None)
-    cmd = [sys.executable, os.path.abspath(__file__),
-           '--child', 'bench' if args.bench else 'check']
-    if not args.bench:
-        cmd += ['--min-bytes-drop', str(args.min_bytes_drop)]
+    cmd = [sys.executable, os.path.abspath(__file__), '--child',
+           '--min-bytes-drop', str(args.min_bytes_drop)]
     out = subprocess.run(cmd, env=env, capture_output=True, text=True,
                          timeout=600)
-    if not args.bench:
-        sys.stderr.write(out.stderr)
-        sys.stdout.write(out.stdout)
-        return out.returncode
-    if out.returncode != 0:
-        sys.stderr.write(out.stderr[-2000:])
-        return out.returncode
-    print(out.stdout.strip().splitlines()[-1])
-    return 0
+    sys.stderr.write(out.stderr)
+    sys.stdout.write(out.stdout)
+    return out.returncode
 
 
 if __name__ == '__main__':

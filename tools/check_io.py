@@ -22,9 +22,8 @@ product path's data plumbing) feeding a tiny ``Module.fit`` under
    ``--strict`` floor separates the two runs (baseline passes, faulted
    exits 2).
 
-Usage: ``python tools/check_io.py [--keep]``; ``--bench`` runs the
-baseline leg only and prints a one-line JSON with ``goodput_fraction``
-(the bench.py leg).  Exits nonzero on any failed assertion.  CPU-safe;
+Usage: ``python tools/check_io.py [--keep]``.  Exits nonzero on any
+failed assertion.  CPU-safe;
 run by ``tests/test_iowatch.py`` under tier-1 and by hand after
 touching the iterator chain or the goodput ledger.
 """
@@ -142,9 +141,6 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument('--keep', action='store_true',
                     help='keep the scratch dir (prints its path)')
-    ap.add_argument('--bench', action='store_true',
-                    help='baseline leg only; print one-line JSON with '
-                         'goodput_fraction (the bench.py leg)')
     ap.add_argument('--fault-delay', type=float, default=0.08,
                     help='per-read injected delay seconds (default '
                          '%(default)s)')
@@ -169,12 +165,6 @@ def main(argv=None):
     try:
         base = _run_child(outdir, 'baseline')
         gp = base['goodput']
-        if args.bench:
-            print(json.dumps({
-                'goodput_fraction': round(gp.get('fraction', 0.0), 4),
-                'wall_secs': round(gp.get('wall_secs', 0.0), 3)}),
-                flush=True)
-            return 0
 
         # leg 1: every stage attributed
         for stage in EXPECTED_STAGES:

@@ -30,11 +30,7 @@ with ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` and
   reports ZERO collective bytes — the accounting never invents traffic
   a single device cannot have.
 
-``--bench`` instead runs the throughput child once and prints a JSON
-``{"ips": ...}`` line — what bench.py's ``multichip_fit_ips`` leg
-consumes (the parent never imports jax, so the leg stays hermetic).
-
-Usage: ``python tools/check_multichip.py [--dir D] [--keep] [--bench]``
+Usage: ``python tools/check_multichip.py [--dir D] [--keep]``
 Exits nonzero on any failed assertion.  CPU-safe; run by
 ``tests/test_multichip_fit.py`` and by hand after touching the
 sharded-fit path.
@@ -61,8 +57,7 @@ def _child(mode):
 
     Modes: 'oracle' (no mesh), 'oneone' (mesh=1x1), 'sharded'
     (mesh=4x2, cold), 'warm' (mesh=4x2, manifest replay), 'commrep'
-    (mesh=4x1 replicated — the analytic gradient-all-reduce case),
-    'bench' (mesh=4x2, steady-state imgs/sec).
+    (mesh=4x1 replicated — the analytic gradient-all-reduce case).
     """
     os.environ.setdefault('JAX_PLATFORMS', 'cpu')
     import jax
@@ -81,8 +76,7 @@ def _child(mode):
     net = mx.sym.SoftmaxOutput(net, name='softmax')
 
     rng = np.random.RandomState(0)
-    bench = mode == 'bench'
-    rows = 2048 if bench else 128
+    rows = 128
     X = rng.randn(rows, 16).astype(np.float32)
     Y = (rng.rand(rows) * 8).astype(np.float32)
     batch_size = 64
@@ -92,21 +86,12 @@ def _child(mode):
             'commrep': '4x1'}.get(mode, MESH)
     partition = None if mesh in (None, '1x1', '4x1') else PARTITION
 
-    import time
-    times = []
-
-    def batch_cb(param):
-        from mxnet_tpu.engine import sync
-        sync(mod._exec_group.execs[0].outputs)
-        times.append(time.monotonic())
-
     mx.random.seed(11)
     mod = mx.mod.Module(net, context=mx.cpu())
     mod.fit(it, num_epoch=2, optimizer='sgd',
             optimizer_params={'learning_rate': 0.1, 'momentum': 0.9},
             eval_metric='acc', initializer=mx.init.Uniform(0.05),
-            mesh=mesh, partition=partition,
-            batch_end_callback=batch_cb if bench else None)
+            mesh=mesh, partition=partition)
 
     out = {'mode': mode, 'fused': mod._fused is not None}
     # counters snapshot BEFORE the score pass below: the zero-hot-path
@@ -119,29 +104,21 @@ def _child(mode):
                      and '[' not in k}
     # total trainable-parameter bytes: the analytic gradient-all-reduce
     # formula's N (everything here is f32 and trainable)
-    arg_params0, _ = mod.get_params()
+    arg_params, _ = mod.get_params()
     out['param_bytes'] = int(sum(
-        int(np.prod(v.shape)) * 4 for v in arg_params0.values()))
-    if bench:
-        # steady-state tail: skip the first epoch's compile+warm batches
-        warm = len(times) // 2
-        tail = times[warm:]
-        out['ips'] = batch_size * (len(tail) - 1) / (tail[-1] - tail[0])
-    else:
-        arg_params, _ = mod.get_params()
-        out['params'] = {k: np.asarray(v.asnumpy(), np.float64)
-                         .reshape(-1).tolist()
-                         for k, v in sorted(arg_params.items())}
-        metric = mx.metric.create('acc')
-        # deterministic final-state metric over the train set (the
-        # 1x1-vs-unsharded identity check compares it too)
-        out['score'] = dict(mod.score(
-            mx.io.NDArrayIter(X, Y, batch_size=batch_size), metric))
+        int(np.prod(v.shape)) * 4 for v in arg_params.values()))
+    out['params'] = {k: np.asarray(v.asnumpy(), np.float64)
+                     .reshape(-1).tolist()
+                     for k, v in sorted(arg_params.items())}
+    metric = mx.metric.create('acc')
+    # deterministic final-state metric over the train set (the
+    # 1x1-vs-unsharded identity check compares it too)
+    out['score'] = dict(mod.score(
+        mx.io.NDArrayIter(X, Y, batch_size=batch_size), metric))
     print(json.dumps(out))
 
 
-def _run_child(mode, cache_dir=None, warm=False, perfwatch=True,
-               commwatch=True):
+def _run_child(mode, cache_dir=None, warm=False):
     env = dict(os.environ)
     flags = env.get('XLA_FLAGS', '')
     if 'xla_force_host_platform_device_count' not in flags:
@@ -153,8 +130,8 @@ def _run_child(mode, cache_dir=None, warm=False, perfwatch=True,
     env.setdefault('MXTPU_PEAK_FLOPS', '2e11')
     env.setdefault('MXTPU_PEAK_BW', '1e10')
     env['MXTPU_METRICS'] = '1'
-    env['MXTPU_PERFWATCH'] = '1' if perfwatch else '0'
-    env['MXTPU_COMMWATCH'] = '1' if commwatch else '0'
+    env['MXTPU_PERFWATCH'] = '1'
+    env['MXTPU_COMMWATCH'] = '1'
     env['MXTPU_WARM_START'] = '1' if warm else '0'
     if cache_dir is not None:
         env['MXTPU_COMPILE_CACHE'] = cache_dir
@@ -188,31 +165,10 @@ def main(argv=None):
     ap.add_argument('--dir', default=None,
                     help='compile-cache dir (default: fresh temp dir)')
     ap.add_argument('--keep', action='store_true')
-    ap.add_argument('--bench', action='store_true',
-                    help='print {"ips": ...} of the sharded fit only')
     args = ap.parse_args(argv)
 
     if args.run_child:
         _child(args.run_child)
-        return 0
-
-    if args.bench:
-        # perfwatch off (its ledger/phase hooks sit on the timed path)
-        # but commwatch ON: the leg persists the step's collective
-        # traffic next to its throughput — comm/compute attribution per
-        # BENCH round
-        res = _run_child('bench', perfwatch=False)
-        g = res.get('gauges') or {}
-        doc = {'ips': res['ips'], 'mesh': MESH,
-               'partition': PARTITION, 'virtual_devices': 8}
-        # OMITTED (not 0.0) when the child's accounting produced no
-        # gauge — a 0.0 would persist as a bench baseline and make the
-        # next honest round read as a comm_fraction regression
-        for src, dst in (('comm.bytes_per_step', 'comm_bytes_per_step'),
-                         ('perf.comm_fraction', 'comm_fraction')):
-            if isinstance(g.get(src), (int, float)):
-                doc[dst] = g[src]
-        print(json.dumps(doc))
         return 0
 
     cache_dir = args.dir or tempfile.mkdtemp(prefix='mxtpu_multichip_')

@@ -12,7 +12,7 @@ import sys
 
 
 PATTERNS = ['mxnet_tpu', 'launch.py', 'train_imagenet', 'train_mnist',
-            'train_cifar10', 'bench.py']
+            'train_cifar10']
 
 
 def _ancestors():
