@@ -128,65 +128,63 @@ register('GatedShortConv', _gated_short_conv_apply,
 # combines.  What the absent experts would have added is left out: under
 # expert parallelism their devices add it.
 #
-# The buffer is what a device with static shapes receives into: four times
-# the share a balanced router sends to the held experts (``_room``), each
-# expert's rows together and starting on a multiple of ``align`` rows.  The
-# products' groups are the experts' own rows rounded up to ``align``: a
-# group's first row is a tile's first row, no tile is visited for two
-# experts, and the tiles past the last group are not visited at all, so the
-# products cost what arrived, rounded up to a tile an expert, while the
-# gathers into and out of the buffer cost the buffer.  The rows past the
-# last group are whatever the device's memory held: ``filled`` masks them
-# before anything weighs them.  A step that sends more than the buffer
-# holds takes the other branch of a ``cond``, the same computation over a
-# buffer with room for every assignment: no token is ever dropped whatever
-# the imbalance, and ``expert_count`` says how often that happened.
+# The buffer is what a device with static shapes receives into: a ladder of
+# buffers (``_room``), two and four times the share a balanced router sends
+# to the held experts and one with room for every assignment, each expert's
+# rows together and starting on a multiple of ``align`` rows.  A step runs
+# in the smallest that holds what arrived, by the branches of one
+# ``switch``: no token is ever dropped whatever the imbalance, and
+# ``expert_count`` says how often the last rung was taken.  The products'
+# groups are the experts' own rows rounded up to ``align``: a group's first
+# row is a tile's first row, no tile is visited for two experts, and the
+# tiles past the last group are not visited at all, so the products cost
+# what arrived, rounded up to a tile an expert.  Every copy between the
+# tokens and the buffer is made from the buffer's side, a row of the rung
+# at a time: a token's row taken for each of the buffer's rows, and each
+# filled row of the buffer added into its token's row (``_collect``); so
+# the copies, the gate and the mask cost the rung.  The rows past the last
+# group are whatever the device's memory held: ``filled`` masks them before
+# anything weighs them and keeps them out of the sum.
 # ---------------------------------------------------------------------------
 
-def _collect(rows, slots, k):
-    """Each token's sum over its k assignments of the buffer's ``rows``;
-    ``slots`` (T * k,) is each assignment's row, or past the last row for
-    an assignment that is not in the buffer."""
-    room = rows.shape[0]
-    taken = jnp.take(rows, jnp.minimum(slots, room - 1), axis=0)
-    taken = jnp.where((slots < room)[:, None], taken, 0)
-    return taken.reshape(-1, k, rows.shape[-1]).sum(axis=1)
+def _collect(rows, token, tokens):
+    """Each of the ``tokens`` tokens' sum of the buffer's ``rows`` that hold
+    an assignment of its: ``token`` (rows,) is each row's token, or
+    ``tokens`` for a row that holds nothing, which is added nowhere."""
+    return jnp.zeros((tokens, rows.shape[-1]), rows.dtype) \
+        .at[token].add(rows, mode='drop')
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch(x, tokens, slots, k):
-    """The rows of ``x`` (T, H) that the buffer's rows hold: ``tokens`` is
-    each buffer row's token."""
-    return jnp.take(x, tokens, axis=0)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _dispatch(x, token, tokens):
+    """The rows of ``x`` (tokens, H) that the buffer's rows hold; a row
+    that holds nothing takes the last."""
+    return jnp.take(x, token, axis=0, mode='clip')
 
 
-def _dispatch_fwd(x, tokens, slots, k):
-    return _dispatch(x, tokens, slots, k), (tokens, slots)
+def _dispatch_fwd(x, token, tokens):
+    return _dispatch(x, token, tokens), token
 
 
-def _dispatch_bwd(k, res, g):
-    # the transpose of a gather by a one-to-one map is a gather by its
-    # inverse: no scatter
-    tokens, slots = res
-    return (_collect(g, slots, k), None, None)
+def _dispatch_bwd(tokens, token, g):
+    return (_collect(g, token, tokens), None)
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _combine(ys, tokens, slots, k):
-    """Each token's sum over its k assignments of the buffer's rows."""
-    return _collect(ys, slots, k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _combine(ys, token, tokens):
+    """Each token's sum over its assignments of the buffer's rows."""
+    return _collect(ys, token, tokens)
 
 
-def _combine_fwd(ys, tokens, slots, k):
-    return _combine(ys, tokens, slots, k), (tokens, slots)
+def _combine_fwd(ys, token, tokens):
+    return _combine(ys, token, tokens), token
 
 
-def _combine_bwd(k, res, g):
-    tokens, slots = res
-    return (jnp.take(g, tokens, axis=0), None, None)
+def _combine_bwd(tokens, token, g):
+    return (jnp.take(g, token, axis=0, mode='clip'), None)
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -218,17 +216,21 @@ def _held(attrs):
 
 
 def _room(assignments, held, experts):
-    """``(rows, whole, align)``: the rows of the two buffers the held
-    experts' products may run over, four times what a balanced router
-    sends to ``held`` of ``experts`` and one that holds every assignment
-    however they fall, and the multiple of rows each expert's rows start
-    on: 512 (a multiple of the grouped product's row tile on the TPU) or,
-    for a small layer, what costs at most a quarter of the first buffer."""
+    """``(rooms, four, align)``: the rows of the buffers the held experts'
+    products may run over, smallest first; of the one among them that is
+    four times what a balanced router sends to ``held`` of ``experts``; and
+    the multiple of rows each expert's rows start on.  The ladder is two
+    such shares, four, and one buffer that holds every assignment however
+    they fall; a rung as large as that one is that one.  ``align`` is 512
+    (a multiple of the grouped product's row tile on the TPU) or, for a
+    small layer, what costs at most a quarter of the four-share buffer."""
     share = 4 * -(-assignments * held // experts)
     align = min(512, max(1, min(share, assignments) // (4 * held)))
     align = 1 << (align.bit_length() - 1)
     whole = -(-(assignments + held * align) // align) * align
-    return min(-(-share // align) * align, whole), whole, align
+    two, four = (min(_aligned(rows, align), whole)
+                 for rows in (share // 2, share))
+    return tuple(sorted({two, four, whole})), four, align
 
 
 def _aligned(sizes, align):
@@ -236,12 +238,17 @@ def _aligned(sizes, align):
     return -(-sizes // align) * align
 
 
+def _rung(rooms, rows):
+    """Which of ``rooms`` is the smallest that holds ``rows``."""
+    return sum(rows > room for room in rooms[:-1])
+
+
 def _buffered(room, align, k, floats, ints):
     """The held experts' part of the layer over a buffer of ``room`` rows
     that holds every assignment that landed on them."""
     x, weights, w1, w3, w2 = floats
-    key, order, inverse, group_sizes = ints
-    count = group_sizes.shape[0]
+    order, group_sizes = ints
+    count, tokens = group_sizes.shape[0], x.shape[0]
     with jax.named_scope('dispatch'):
         # expert e has ``padded[e]`` rows of the buffer, up to ``ends[e]``,
         # and fills the first ``group_sizes[e]``; ``shift[e]`` is how far
@@ -253,12 +260,9 @@ def _buffered(room, align, k, floats, ints):
         expert = jnp.minimum((row[:, None] >= ends[None, :]).sum(axis=1),
                              count - 1)
         filled = row - (ends - padded)[expert] < group_sizes[expert]
-        assignment = jnp.take(order, jnp.clip(row - shift[expert], 0,
-                                              order.shape[0] - 1))
-        tokens = assignment // k
-        slots = jnp.where(key < count,
-                          inverse + shift[jnp.minimum(key, count - 1)], room)
-        xs = _dispatch(x, tokens, slots, k)
+        assignment = jnp.take(order, row - shift[expert], mode='clip')
+        token = jnp.where(filled, assignment // k, tokens)
+        xs = _dispatch(x, token, tokens)
     with jax.named_scope('experts'):
         # the rows past ``ends[-1]`` are in no group: unvisited, unwritten
         hidden = jax.nn.silu(grouped_matmul(xs, w1, padded)) * \
@@ -269,39 +273,38 @@ def _buffered(room, align, k, floats, ints):
         # masked before it is weighed: what a row that holds nothing reads
         # must not reach the gate's gradient as 0 x anything
         ys = jnp.where(filled[:, None], ys.astype(jnp.float32), 0) * gate
-        return _combine(ys.astype(x.dtype), tokens, slots, k)
+        return _combine(ys.astype(x.dtype), token, tokens)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _either_buffer(rooms, k, fits, floats, ints):
-    """``_buffered`` over ``rooms[0]`` rows where the step ``fits`` them
-    and over ``rooms[1]`` where it does not (``rooms[2]`` is the
-    alignment).  The backward pass computes the taken branch's forward
-    pass again inside its own branch, so that nothing a branch keeps has
-    to exist for both."""
-    return jax.lax.cond(
-        fits, *(functools.partial(_buffered, room, rooms[2], k)
-                for room in rooms[:2]), floats, ints)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _on_the_ladder(rooms, align, k, rung, floats, ints):
+    """``_buffered`` over ``rooms[rung]`` rows.  The backward pass computes
+    the taken branch's forward pass again inside its own branch, so that
+    nothing a branch keeps has to exist for every rung."""
+    return jax.lax.switch(
+        rung, [functools.partial(_buffered, room, align, k)
+               for room in rooms], floats, ints)
 
 
-def _either_buffer_fwd(rooms, k, fits, floats, ints):
-    return _either_buffer(rooms, k, fits, floats, ints), (fits, floats, ints)
+def _on_the_ladder_fwd(rooms, align, k, rung, floats, ints):
+    return _on_the_ladder(rooms, align, k, rung, floats, ints), \
+        (rung, floats, ints)
 
 
-def _either_buffer_bwd(rooms, k, res, g):
-    fits, floats, ints = res
+def _on_the_ladder_bwd(rooms, align, k, res, g):
+    rung, floats, ints = res
 
     def backward(room, floats, ints, g):
-        return jax.vjp(lambda *f: _buffered(room, rooms[2], k, f, ints),
+        return jax.vjp(lambda *f: _buffered(room, align, k, f, ints),
                        *floats)[1](g)
 
     return (None,
-            jax.lax.cond(fits, *(functools.partial(backward, room)
-                                 for room in rooms[:2]), floats, ints, g),
+            jax.lax.switch(rung, [functools.partial(backward, room)
+                                  for room in rooms], floats, ints, g),
             None)
 
 
-_either_buffer.defvjp(_either_buffer_fwd, _either_buffer_bwd)
+_on_the_ladder.defvjp(_on_the_ladder_fwd, _on_the_ladder_bwd)
 
 
 def _sparse_experts_apply(attrs, inputs, is_train, rng):
@@ -318,30 +321,31 @@ def _sparse_experts_apply(attrs, inputs, is_train, rng):
         # absent experts' assignments sort last
         key = jnp.where(mine, local, count)
         order = jnp.argsort(key, stable=True)
-        inverse = jnp.argsort(order)
         group_sizes = jnp.bincount(key, length=count + 1)[:count] \
             .astype(jnp.int32)
-    room, whole, align = _room(chosen.size, count, int(attrs['num_experts']))
+    rooms, _, align = _room(chosen.size, count, int(attrs['num_experts']))
     floats = (x, weights, w1, w3, w2)
-    ints = (key, order, inverse, group_sizes)
-    if room < whole:
-        fits = _aligned(group_sizes, align).sum() <= room
-        y = _either_buffer((room, whole, align), k, fits, floats, ints)
+    ints = (order, group_sizes)
+    if len(rooms) > 1:
+        rung = _rung(rooms, _aligned(group_sizes, align).sum())
+        y = _on_the_ladder(rooms, align, k, rung, floats, ints)
+        last = (rung == len(rooms) - 1).astype(jnp.float32)
     else:
-        fits = True
-        y = _buffered(whole, align, k, floats, ints)
+        y = _buffered(rooms[0], align, k, floats, ints)
+        last = jnp.float32(0)
     load = group_sizes.astype(jnp.float32)
     held = jnp.sum(mine).astype(jnp.float32)
     step = jnp.stack([jnp.float32(chosen.size), held, held - load.sum(),
-                      1 - jnp.float32(fits)])
+                      last])
     return [y], {'expert_load': load,
                  'expert_count': count_so_far.astype(jnp.float32) + step}
 
 
 def _sparse_experts_counters(now, before, attrs, in_shapes):
     """The layer's counts since the last drain into the registry, how
-    uneven the last step's load was over the experts held, and the share
-    of its buffer's rows that the last step's products visited."""
+    uneven the last step's load was over the experts held, and of the
+    buffer the last step ran in: its rows over the four-share buffer's, and
+    the share of them that the products visited."""
     from .. import instrument
     count = now['expert_count'] - (before['expert_count'] if before else 0)
     instrument.inc('moe.assignments', int(count[0]))
@@ -352,12 +356,13 @@ def _sparse_experts_counters(now, before, attrs, in_shapes):
     if load.sum() > 0:
         instrument.observe_hist('moe.load_max_over_mean',
                                 float(load.max() / load.mean()))
-    room, whole, align = _room(
+    rooms, four, align = _room(
         in_shapes[0][0] * int(attrs['experts_per_tok']), load.shape[0],
         int(attrs['num_experts']))
     visited = float(_aligned(load, align).sum())
-    instrument.observe_hist('moe.rows_visited_share',
-                            visited / (room if visited <= room else whole))
+    room = rooms[_rung(rooms, visited)]
+    instrument.observe_hist('moe.rows_visited_share', visited / room)
+    instrument.observe_hist('moe.rows_copied_share', room / four)
 
 
 def _sparse_experts_complete(attrs, in_shapes):
@@ -404,7 +409,10 @@ register('SparseExperts', _sparse_experts_apply,
              'expert received in the last step; expert_count (4,), running '
              'totals of assignments routed, assignments that landed on held '
              'experts, tokens dropped (always 0), and steps that sent the '
-             'held experts more than their buffer holds.  The buffer is '
-             'four times a balanced router\'s share; the grouped products '
-             'run over each held expert\'s assignments rounded up to a row '
-             'tile and over no other row of it.')
+             'held experts more than any buffer but the last holds.  The '
+             'buffer is a ladder of buffers, two and four times a balanced '
+             'router\'s share and one for every assignment, of which a step '
+             'takes the smallest that holds it; the copies into and out of '
+             'it cost that buffer\'s rows, and the grouped products run over '
+             'each held expert\'s assignments rounded up to a row tile and '
+             'over no other row of it.')

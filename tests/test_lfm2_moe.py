@@ -180,10 +180,46 @@ def case_experts_a_hundredth_of_the_layer(rng):
     return case_experts(rng, (4, 3), favour=-0.15, experts=32)
 
 
+LADDER = (48, 96, 280)      # of 3 held experts of 32, rows from multiples of 8
+
+
+def experts_with_loads(which, loads):
+    """A case of 3 held experts of 32 that receive exactly ``loads`` of the
+    64 tokens: the router's rows are unit vectors, so a token's first 32
+    features are its logits: small for the absent experts, and a held
+    expert's +8 for the tokens it is to receive and -8 for every other."""
+    def case(rng):
+        name, attrs, inputs, aux, reference = case_experts(rng, (4, 3),
+                                                           experts=32)
+        z = np.array(inputs[0])
+        z[:, :32] *= 0.1        # every other expert scores about a half
+        for held, load in enumerate(loads):
+            z[:, 4 + held] = -8.0
+            z[rng.permutation(N * T)[:load], 4 + held] = 8.0
+        inputs[0], inputs[1] = jnp.asarray(z), jnp.eye(32, HIDDEN)
+        return name, attrs, inputs, aux, reference
+    case.__name__ = 'case_experts_' + which
+    return case
+
+
+# name: (each held expert's load, which of ``LADDER`` is the smallest that
+# holds them rounded up to 8)
+ON_THE_LADDER = {
+    'nothing_held': ((0, 0, 0), 0),
+    'filling_the_first_rung': ((16, 16, 16), 0),
+    'one_row_over_the_first_rung': ((17, 16, 16), 1),
+    'one_expert_takes_every_token': ((64, 0, 0), 1),
+    'filling_the_second_rung': ((30, 28, 30), 1),
+    'one_row_over_the_second_rung': ((33, 32, 32), 2),
+    'every_held_expert_takes_every_token': ((64, 64, 64), 2),
+}
+LADDER_CASES = {which: experts_with_loads(which, loads)
+                for which, (loads, _) in ON_THE_LADDER.items()}
+
 CASES = [case_rms_norm, case_rotary, case_swiglu, case_silu, case_short_conv,
          case_attention, case_experts, case_experts_in_the_buffer,
          case_experts_over_the_buffer, case_experts_one_held_receives_nothing,
-         case_experts_a_hundredth_of_the_layer]
+         case_experts_a_hundredth_of_the_layer] + list(LADDER_CASES.values())
 
 
 def apply_op(name, attrs, inputs, aux):
@@ -606,13 +642,38 @@ def test_one_expert_taking_every_token_drops_none(held):
     assert rel(out, want) < 2e-5
 
 
-def test_the_buffer_is_four_balanced_shares_and_a_step_over_it_is_counted():
+def share_so_far(histogram):
+    """(observations, their sum) of the histogram ``moe.<histogram>``."""
+    h = instrument.metrics_snapshot().get('histograms', {}).get(
+        'moe.' + histogram, {'count': 0, 'sum': 0.0})
+    return np.array([h['count'], h['sum']])
+
+
+def drained(name, attrs, inputs, load, histogram):
+    """What one drain of the operator's counters after a step of ``load``
+    adds to ``moe.<histogram>``: (observations, their sum)."""
     from mxnet_tpu.ops import lm
-    # (the buffer, the one that holds everything, the alignment) of the
-    # cell's layer, of an uncut layer and of the two cases at this file's size
-    assert lm._room(16384 * 4, 8, 64) == (32768, 65536 + 8 * 512, 512)
-    assert lm._room(N * T * 4, 16, 16) == (320, 320, 4)
-    assert lm._room(N * T * 4, 3, 32) == (96, 280, 8)
+    was = instrument.metrics_enabled()
+    instrument.set_metrics(True)
+    try:
+        before = share_so_far(histogram)
+        lm._sparse_experts_counters(
+            {'expert_load': np.asarray(load), 'expert_count': np.zeros(4)},
+            None, get_op(name).canon_attrs(attrs), [inputs[0].shape])
+        return share_so_far(histogram) - before
+    finally:
+        instrument.set_metrics(was)
+
+
+def test_the_buffer_is_a_ladder_and_a_step_on_its_last_rung_is_counted():
+    from mxnet_tpu.ops import lm
+    # (the buffers, smallest first, the four-share one among them and the
+    # alignment) of the cell's layer, of an uncut layer and of the cases at
+    # this file's size
+    assert lm._room(16384 * 4, 8, 64) == \
+        ((16384, 32768, 65536 + 8 * 512), 32768, 512)
+    assert lm._room(N * T * 4, 16, 16) == ((320,), 320, 4)
+    assert lm._room(N * T * 4, 3, 32) == (LADDER, 96, 8)
     for case, over in ((case_experts_in_the_buffer, False),
                        (case_experts_over_the_buffer, True)):
         name, attrs, inputs, aux, _ = case(np.random.default_rng(11))
@@ -620,6 +681,36 @@ def test_the_buffer_is_four_balanced_shares_and_a_step_over_it_is_counted():
             apply_op(name, attrs, inputs, aux)[1]['expert_count'])
         assert routed == N * T * 4 and dropped == 0
         assert (held > 96) == over and steps_over == float(over)
+
+
+@pytest.mark.parametrize('which', sorted(ON_THE_LADDER))
+def test_a_step_takes_the_smallest_rung_that_holds_it(which):
+    loads, rung = ON_THE_LADDER[which]
+    name, attrs, inputs, aux, _ = LADDER_CASES[which](
+        np.random.default_rng(11))
+    out, updates = apply_op(name, attrs, inputs, aux)
+    np.testing.assert_array_equal(np.asarray(updates['expert_load']), loads)
+    routed, held, dropped, last_rung = np.asarray(updates['expert_count'])
+    assert (routed, held, dropped) == (N * T * 4, sum(loads), 0)
+    assert last_rung == float(rung == 2)
+    if not sum(loads):
+        assert not np.asarray(out[0]).any()
+    # the drain says which rung that was, against the four shares' 96 rows
+    count, share = drained(name, attrs, inputs, updates['expert_load'],
+                           'rows_copied_share')
+    assert count == 1 and share == pytest.approx(LADDER[rung] / 96)
+
+
+def test_the_lowered_layer_sorts_once():
+    # the assignments by expert; a second sort gave each assignment its row
+    # while the collect was made from the assignments' side
+    name, attrs, inputs, aux, _ = case_experts_in_the_buffer(
+        np.random.default_rng(11))
+    cotangent = draw(np.random.default_rng(12), inputs[0].shape)
+    lowered = jax.jit(jax.grad(
+        lambda *xs: experts_loss(xs, attrs, aux, cotangent)[0],
+        tuple(range(5)))).lower(*inputs).as_text()
+    assert lowered.count('stablehlo.sort') == 1
 
 
 # -- the products run over the rows that hold something ---------------------
@@ -632,24 +723,20 @@ def case_experts_filling_the_buffer(rng):
     return name, attrs, inputs, aux, reference
 
 
-def visited_share_so_far():
-    """(observations, their sum) of ``moe.rows_visited_share``."""
-    h = instrument.metrics_snapshot().get('histograms', {}).get(
-        'moe.rows_visited_share', {'count': 0, 'sum': 0.0})
-    return np.array([h['count'], h['sum']])
-
-
 def experts_loss(inputs, attrs, aux, cotangent):
     out = apply_op('SparseExperts', attrs, inputs, aux)[0][0]
     return jnp.sum(out * cotangent), out
 
 
 @pytest.mark.parametrize('case, rows, full', [
-    (case_experts_in_the_buffer, 96, False),
-    (case_experts_a_hundredth_of_the_layer, 96, False),
+    (case_experts_in_the_buffer, 48, False),
+    (case_experts_a_hundredth_of_the_layer, 48, False),
     (case_experts_over_the_buffer, 280, False),
-    (case_experts_filling_the_buffer, 64, True)],
-    ids=['in_the_buffer', 'a_hundredth', 'over_the_buffer', 'filling_it'])
+    (case_experts_filling_the_buffer, 64, True)] + [
+    (LADDER_CASES[which], LADDER[rung], which.startswith('filling'))
+    for which, (_, rung) in sorted(ON_THE_LADDER.items())],
+    ids=['in_the_buffer', 'a_hundredth', 'over_the_buffer', 'filling_it'] +
+    sorted(ON_THE_LADDER))
 def test_grouped_products_are_given_each_experts_aligned_rows_and_no_more(
         monkeypatch, case, rows, full):
     from mxnet_tpu.ops import lm
@@ -677,16 +764,7 @@ def test_grouped_products_are_given_each_experts_aligned_rows_and_no_more(
         np.testing.assert_array_equal(sizes, want)
     assert want.sum() <= rows and (want.sum() == rows) == full
     # and the share the drain reports is these rows over that buffer
-    was = instrument.metrics_enabled()
-    instrument.set_metrics(True)
-    try:
-        before = visited_share_so_far()
-        lm._sparse_experts_counters(
-            {'expert_load': load, 'expert_count': np.zeros(4)}, None,
-            get_op(name).canon_attrs(attrs), [inputs[0].shape])
-        count, share = visited_share_so_far() - before
-    finally:
-        instrument.set_metrics(was)
+    count, share = drained(name, attrs, inputs, load, 'rows_visited_share')
     assert count == 1 and share == pytest.approx(want.sum() / rows)
 
 
@@ -723,8 +801,11 @@ def poisoned(plain):
 @pytest.mark.parametrize('dtype', DTYPES, ids=['float32', 'bfloat16'])
 @pytest.mark.parametrize('case', [
     case_experts_in_the_buffer, case_experts_over_the_buffer,
-    case_experts_a_hundredth_of_the_layer],
-    ids=['in_the_buffer', 'over_the_buffer', 'a_hundredth'])
+    case_experts_a_hundredth_of_the_layer,
+    LADDER_CASES['nothing_held'], LADDER_CASES['one_row_over_the_first_rung'],
+    LADDER_CASES['one_row_over_the_second_rung']],
+    ids=['in_the_buffer', 'over_the_buffer', 'a_hundredth', 'nothing_held',
+         'on_the_second_rung', 'on_the_last_rung'])
 def test_rows_the_products_do_not_write_reach_no_output_and_no_gradient(
         monkeypatch, case, dtype):
     """The CPU's product writes zeros past its groups and would hide what
@@ -809,7 +890,9 @@ def test_device_counters_reach_the_registry_only_at_a_drain(model):
     instrument.set_metrics(True)
     try:
         before = instrument.metrics_snapshot()['counters']
-        visited_before, visited_seen = tuple(visited_share_so_far()), []
+        visited_before = tuple(share_so_far('rows_visited_share'))
+        visited_seen = []
+        copied_before = share_so_far('rows_copied_share')
         data = mx.io.NDArrayIter(
             np.tile(model['tokens'], (3, 1)).astype(np.float32),
             np.tile(model['labels'], (3, 1)).astype(np.float32),
@@ -824,7 +907,8 @@ def test_device_counters_reach_the_registry_only_at_a_drain(model):
                         for k, v in model['aux'].items()},
             batch_end_callback=lambda p: (
                 seen.append(instrument.counter_value('moe.assignments')),
-                visited_seen.append(tuple(visited_share_so_far()))))
+                visited_seen.append(
+                    tuple(share_so_far('rows_visited_share')))))
         after = instrument.metrics_snapshot()
         moved = {k: after['counters'].get(k, 0) - before.get(k, 0)
                  for k in ('moe.assignments', 'moe.assignments_held',
@@ -844,13 +928,16 @@ def test_device_counters_reach_the_registry_only_at_a_drain(model):
         # (every expert held, each expert's rows from a multiple of 4),
         # one observation a layer and drain, none between the drains
         assert visited_seen == [visited_before] * 3
-        count, share = visited_share_so_far() - visited_before
+        count, share = share_so_far('rows_visited_share') - visited_before
         loads = [aux.asnumpy() for name, aux in module.get_params()[1].items()
                  if name.endswith('_expert_load')]
         assert count >= 4 and count % 4 == 0
         assert share / count == pytest.approx(np.mean(
             [(np.ceil(load / 4) * 4).sum() / 320 for load in loads]))
         assert 0.8 < share / count <= 1
+        # a layer whose ladder is one buffer ran in it: 1 at every drain
+        np.testing.assert_array_equal(
+            share_so_far('rows_copied_share') - copied_before, [count, count])
     finally:
         instrument.set_metrics(was)
 
