@@ -69,8 +69,13 @@ class Initializer(object):
             self._init_zero(name, arr)
         elif name.endswith('moving_avg'):
             self._init_zero(name, arr)
-        elif name.endswith(('expert_load', 'expert_count')):
+        elif name.endswith(('expert_load', 'expert_count', 'kda_count')):
             # SparseExperts' counting states (its expert_bias is a bias)
+            # and KimiDeltaAttention's
+            self._init_zero(name, arr)
+        elif name.endswith('A_log'):
+            # KimiDeltaAttention's log of the decay's rate: a rate of one
+            # (its dt_bias is a bias)
             self._init_zero(name, arr)
         elif 'begin_state' in name:
             self._init_zero(name, arr)

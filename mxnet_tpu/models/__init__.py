@@ -5,6 +5,7 @@ from . import googlenet, resnext, inception_resnet_v2
 from . import lstm_lm
 from . import transformer_lm
 from . import lfm2_moe
+from . import kimi_linear
 from . import ssd
 
 _MODELS = {
@@ -31,6 +32,7 @@ _MODELS = {
     'lstm_lm': lstm_lm.get_symbol,
     'transformer_lm': transformer_lm.get_symbol,
     'lfm2_moe': lfm2_moe.get_symbol,
+    'kimi_linear': kimi_linear.get_symbol,
     'ssd-vgg16': ssd.get_symbol,
     'ssd-vgg16-train': ssd.get_symbol_train,
 }

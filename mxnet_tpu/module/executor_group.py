@@ -27,7 +27,7 @@ from .. import perfwatch
 from ..base import MXNetError
 from ..context import Context
 from ..executor import Executor
-from ..ndarray import NDArray
+from ..ndarray import NDArray, ZerosWhenRead
 
 
 def _split_input_slice(batch_size, work_load_list):
@@ -181,8 +181,11 @@ class DataParallelExecutorGroup(object):
                                            name)
             args[name] = NDArray(placed, self.contexts[0])
             if grad_req.get(name, 'null') != 'null':
-                grads[name] = NDArray(self._place_param(
-                    np.zeros(shape, np.float32), name), self.contexts[0])
+                # on the device only once read: the fused step never does
+                grads[name] = ZerosWhenRead(
+                    lambda shape=shape, name=name: self._place_param(
+                        np.zeros(shape, np.float32), name),
+                    self.contexts[0])
         for name, shape in zip(self.aux_names, aux_shapes):
             if shared_exec is not None and name in shared_exec.aux_dict:
                 aux[name] = shared_exec.aux_dict[name]

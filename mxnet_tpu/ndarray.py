@@ -281,6 +281,33 @@ class NDArray:
                            jax.device_put(state['data'], ctx.jax_device))
 
 
+class ZerosWhenRead(NDArray):
+    """An NDArray of zeros that reaches the device when it is first read.
+    A training executor is bound with an array for every parameter's
+    gradient; ``Module``'s fused step keeps its gradients inside the
+    program and never reads or writes those arrays, which for a model of
+    600M parameters are 2.4e9 B of zeros on the chip.  Written to
+    (``_set_data``, what ``Executor.backward`` does) it is an ordinary
+    NDArray from then on."""
+
+    __slots__ = ('_made', '_make')
+
+    def __init__(self, make, ctx=None):
+        self._make, self._made = make, None
+        NDArray.__init__(self, None, ctx)
+
+    @property
+    def _data(self):
+        if self._made is None:
+            self._made = self._make()
+        return self._made
+
+    @_data.setter
+    def _data(self, value):
+        self._made = value
+
+
+
 def waitall():
     """Block until all queued device work completes (engine WaitForAll)."""
     jax.effects_barrier()

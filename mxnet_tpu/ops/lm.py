@@ -1,5 +1,5 @@
 """Language-model layer operators: RMSNorm, RotaryEmbedding, SwiGLU,
-GatedShortConv and SparseExperts.
+GatedShortConv, SparseExperts and KimiDeltaAttention.
 
 Beyond the reference's 2017 op set: what a sparse decoder-only language
 model (``models/lfm2_moe.py``) needs of a Symbol graph, each with shape
@@ -138,7 +138,10 @@ register('GatedShortConv', _gated_short_conv_apply,
 # groups are the experts' own rows rounded up to ``align``: a group's first
 # row is a tile's first row, no tile is visited for two experts, and the
 # tiles past the last group are not visited at all, so the products cost
-# what arrived, rounded up to a tile an expert.  Every copy between the
+# what arrived, rounded up to a tile an expert.  The last rung runs the rows
+# of the rung before it at a time (``_in_slices``), so that a buffer no
+# balanced step ever takes costs that rung's memory and not, at 8 of 256
+# experts held, sixteen times the first one's.  Every copy between the
 # tokens and the buffer is made from the buffer's side, a row of the rung
 # at a time: a token's row taken for each of the buffer's rows, and each
 # filled row of the buffer added into its token's row (``_collect``); so
@@ -243,9 +246,18 @@ def _rung(rooms, rows):
     return sum(rows > room for room in rooms[:-1])
 
 
-def _buffered(room, align, k, floats, ints):
+def _rows_within(ends, padded, first, room):
+    """How many of each expert's ``padded`` rows of the buffer, which end at
+    row ``ends``, lie among the ``room`` rows from row ``first``."""
+    return jnp.clip(ends, first, first + room) - \
+        jnp.clip(ends - padded, first, first + room)
+
+
+def _buffered(room, align, k, floats, ints, first=None):
     """The held experts' part of the layer over a buffer of ``room`` rows
-    that holds every assignment that landed on them."""
+    that holds every assignment that landed on them; or, given ``first``,
+    over the ``room`` rows of a larger buffer that start at row ``first``
+    (a multiple of ``align``)."""
     x, weights, w1, w3, w2 = floats
     order, group_sizes = ints
     count, tokens = group_sizes.shape[0], x.shape[0]
@@ -257,6 +269,10 @@ def _buffered(room, align, k, floats, ints):
         ends = jnp.cumsum(padded)
         shift = (ends - padded) - (jnp.cumsum(group_sizes) - group_sizes)
         row = jnp.arange(room)
+        groups = padded
+        if first is not None:
+            row = row + first
+            groups = _rows_within(ends, padded, first, room)
         expert = jnp.minimum((row[:, None] >= ends[None, :]).sum(axis=1),
                              count - 1)
         filled = row - (ends - padded)[expert] < group_sizes[expert]
@@ -264,10 +280,10 @@ def _buffered(room, align, k, floats, ints):
         token = jnp.where(filled, assignment // k, tokens)
         xs = _dispatch(x, token, tokens)
     with jax.named_scope('experts'):
-        # the rows past ``ends[-1]`` are in no group: unvisited, unwritten
-        hidden = jax.nn.silu(grouped_matmul(xs, w1, padded)) * \
-            grouped_matmul(xs, w3, padded)
-        ys = grouped_matmul(hidden, w2, padded)
+        # the rows past the last group are in no group: unvisited, unwritten
+        hidden = jax.nn.silu(grouped_matmul(xs, w1, groups)) * \
+            grouped_matmul(xs, w3, groups)
+        ys = grouped_matmul(hidden, w2, groups)
     with jax.named_scope('combine'):
         gate = jnp.take(weights.reshape(-1), assignment)[:, None]
         # masked before it is weighed: what a row that holds nothing reads
@@ -276,14 +292,36 @@ def _buffered(room, align, k, floats, ints):
         return _combine(ys.astype(x.dtype), token, tokens)
 
 
+def _in_slices(room, rows, align, k, floats, ints):
+    """``_buffered`` over a buffer of ``room`` rows, ``rows`` of them at a
+    time, each slice computed again in the backward pass: the ladder's last
+    rung, whose buffer holds every assignment however they fall, then costs
+    the memory of the rung before it and not its own rows (at 8 of 256
+    experts held, sixteen times the first rung's) in a step that never
+    takes it."""
+    def one(total, first):
+        return total + _buffered(rows, align, k, floats, ints, first), None
+    total, _ = jax.lax.scan(
+        jax.checkpoint(one), jnp.zeros_like(floats[0]),
+        jnp.arange(-(-room // rows), dtype=jnp.int32) * rows)
+    return total
+
+
+def _rungs(rooms, align, k):
+    """The ladder's branches, smallest first."""
+    fns = [functools.partial(_buffered, room, align, k) for room in rooms]
+    if len(rooms) > 1 and rooms[-1] > rooms[-2]:
+        fns[-1] = functools.partial(_in_slices, rooms[-1], rooms[-2], align,
+                                    k)
+    return fns
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
 def _on_the_ladder(rooms, align, k, rung, floats, ints):
     """``_buffered`` over ``rooms[rung]`` rows.  The backward pass computes
     the taken branch's forward pass again inside its own branch, so that
     nothing a branch keeps has to exist for every rung."""
-    return jax.lax.switch(
-        rung, [functools.partial(_buffered, room, align, k)
-               for room in rooms], floats, ints)
+    return jax.lax.switch(rung, _rungs(rooms, align, k), floats, ints)
 
 
 def _on_the_ladder_fwd(rooms, align, k, rung, floats, ints):
@@ -294,13 +332,13 @@ def _on_the_ladder_fwd(rooms, align, k, rung, floats, ints):
 def _on_the_ladder_bwd(rooms, align, k, res, g):
     rung, floats, ints = res
 
-    def backward(room, floats, ints, g):
-        return jax.vjp(lambda *f: _buffered(room, align, k, f, ints),
-                       *floats)[1](g)
+    def backward(fn, floats, ints, g):
+        return jax.vjp(lambda *f: fn(f, ints), *floats)[1](g)
 
     return (None,
-            jax.lax.switch(rung, [functools.partial(backward, room)
-                                  for room in rooms], floats, ints, g),
+            jax.lax.switch(rung, [functools.partial(backward, fn)
+                                  for fn in _rungs(rooms, align, k)],
+                           floats, ints, g),
             None)
 
 
@@ -416,3 +454,437 @@ register('SparseExperts', _sparse_experts_apply,
              'it cost that buffer\'s rows, and the grouped products run over '
              'each held expert\'s assignments rounded up to a row tile and '
              'over no other row of it.')
+
+
+# ---------------------------------------------------------------------------
+# KimiDeltaAttention: what lies between the projections of a Kimi Delta
+# Attention layer (Kimi Linear, arXiv:2510.26692).  For a token t and a head,
+# d channels a head:
+#
+#   q = l2norm(silu(conv(query))) / sqrt(d),  k = l2norm(silu(conv(key))),
+#   v = silu(conv(value))                  (``conv``: causal, depthwise)
+#   g = -exp(A_log[head]) * softplus(decay + dt_bias)   log-decay, a channel
+#   beta = sigmoid(beta)                                one a head
+#   S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+#   o_t = S_t^T q_t,   output RMSNorm_d(o_t) * sigmoid(gate)
+#
+# with S_0 = 0 at the start of every sequence.  One scope, ``scan``, and
+# under it ``conv``, ``gates`` and ``out_gate``: everything runs a segment of
+# chunks at a time inside one outer scan (``_segments``), so that nothing as
+# long as the sequence exists but the layer's projections, its output and
+# their gradients.  The recurrence runs in chunks of ``chunk_size`` tokens
+# (``delta_rule_chunked``): with G the decay summed from the chunk's
+# start, u_t = beta_t (v_t - (Diag(exp(g_t)) S_{t-1})^T k_t) solves
+# (I + Diag(beta) A) U = Diag(beta) (V - (K e^G) S_0),  A_ti = sum_d k_t k_i
+# e^(G_t - G_i) for i < t, whose inverse T (a triangular solve, the WY
+# representation of the chunk's product of Householder-like factors) is made
+# for all chunks at once; a ``lax.scan`` carries the state:
+#   U = T V - (T K e^G) S_0,  O = (Q e^G) S_0 + B U,  B_ti = sum_d q_t k_i
+#   e^(G_t - G_i) for i <= t,  S_C = Diag(e^(G_C)) S_0 + (K e^(G_C - G))^T U.
+# Every e^(G_t - G_i) is a product of two factors through the sum at the
+# start of t's sub-block of ``KDA_SUB`` tokens, so that both products of a
+# chunk are matrix products: the factor of t is at most 1, that of an i in an
+# earlier sub-block too, and that of an i in t's own sub-block is at most
+# e^(-KDA_SUB x floor) because a token's log-decay of a channel is held to
+# ``KDA_DECAY_FLOOR`` at least, which is the one place where the chunked form
+# departs from the recurrence: a decay under e^-10 = 4.5e-5 a token is taken
+# as that (``count`` says how many of the log-decays were; a trained model's
+# rates times its steps stay far over it).  Sub-blocks of 16 would halve the
+# factors made and want a floor of -5, a decay of 0.0067, which drawn weights
+# reach in a tenth of the tokens of their fastest channels: on the chip that
+# read as an error of the model (PERF.md section 6, PR 34).  Sums are
+# float32; the products take the inputs' dtype and accumulate in float32; the
+# triangular algebra is float32 at the default precision of a float32 product
+# (on the TPU one bf16 pass: I - N is exact, and what the pass rounds are the
+# powers of N from the second on, which the cast of T to the inputs' dtype
+# rounds as much).
+# ---------------------------------------------------------------------------
+
+KDA_SUB = 8
+KDA_SEGMENT = 16
+# e^(8 x 10) = e^80 is under the largest number float32 and bf16 hold (e^88)
+KDA_DECAY_FLOOR = -10.0
+
+
+def causal_conv_silu(x, kernel, lead):
+    """``silu`` of the causal depthwise convolution of ``x`` (N, lead + T, C)
+    along T: tap ``j`` of ``kernel`` (C, taps) weighs the token ``j`` back;
+    the first ``lead`` rows of ``x`` (at least taps - 1; zeros before a
+    sequence's start) are the tokens before the T that get an output.
+    Shifted multiply-adds, as ``GatedShortConv``."""
+    t = x.shape[1] - lead
+    mixed = kernel[:, 0] * x[:, lead:]
+    for j in range(1, kernel.shape[1]):
+        mixed = mixed + kernel[:, j] * x[:, lead - j:lead - j + t]
+    return jax.nn.silu(mixed.astype(jnp.float32)).astype(x.dtype)
+
+
+def _l2norm(x):
+    xf = x.astype(jnp.float32)
+    return xf * jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) +
+                              1e-6)
+
+
+def _unit_lower_inverse(lower, block):
+    """The inverse of ``I + N`` for ``N`` = ``lower`` (..., C, C) strictly
+    lower triangular.  A nilpotent's inverse is a finite product, (I + N)^-1
+    = (I - N)(I + N^2)(I + N^4)..., taken in two steps so that no power
+    passes ``block``: first of N's diagonal blocks of ``block`` rows, D,
+    then of D^-1 (N - N_D), which is nilpotent over the C / block blocks."""
+    c = lower.shape[-1]
+    eye = jnp.eye(c, dtype=lower.dtype)
+
+    def neumann(n, size):
+        out, power = eye - n, n
+        for _ in range(max(0, (size - 1).bit_length() - 1)):
+            power = jnp.matmul(power, power)
+            out = out + jnp.matmul(out, power)
+        return out
+
+    same = (jnp.arange(c)[:, None] // block) == (jnp.arange(c)[None, :] //
+                                                 block)
+    inside = neumann(jnp.where(same, lower, 0.0), block)
+    if block >= c:
+        return inside
+    over = neumann(jnp.matmul(inside, jnp.where(same, 0.0, lower)),
+                   c // block)
+    return jnp.matmul(over, inside)
+
+
+def _chunk_parts(q, k, v, g, beta):
+    """What the scan over chunks takes, for every chunk at once.  ``q``,
+    ``k``, ``v``, ``g`` (..., C, d) and ``beta`` (..., C) of one chunk each
+    (``g``, at or over ``KDA_DECAY_FLOOR``, and ``beta`` float32).  Returns
+    ``W = T K e^G``, ``U0 = T V``, ``Q e^G``, ``B``, ``K e^(G_C - G)`` in the
+    inputs' dtype and ``e^(G_C)`` in float32."""
+    dtype = q.dtype
+    c, d = q.shape[-2:]
+    b = KDA_SUB                     # ``delta_rule_chunked`` makes c a multiple
+    s = c // b
+    lead = q.shape[:-2]
+    total = jnp.cumsum(g, axis=-2)                       # G, (..., C, d)
+    qf, kf = q.astype(jnp.float32), k.astype(jnp.float32)
+
+    def blocks(x):
+        return x.reshape(lead + (s, b, d))
+    tb = blocks(total)
+    # e^(G_t - G_i) as a product through the sum at the start of t's
+    # sub-block: e^(G_t - start) is at most 1, and e^(start - G_i) at most 1
+    # for an earlier sub-block's i and at most e^(-b x floor) for one of the
+    # same sub-block, which float32 and bf16 both hold
+    start = jnp.concatenate([jnp.zeros_like(tb[..., :1, -1, :]),
+                             tb[..., :-1, -1, :]], axis=-2)  # (..., S, d)
+    row = jnp.exp(tb - start[..., None, :])
+    not_later = (jnp.arange(c)[None, :] < ((jnp.arange(s) + 1) * b)[:, None])
+    col = jnp.where(
+        not_later[..., None],
+        jnp.exp(jnp.minimum(start[..., :, None, :] - total[..., None, :, :],
+                            -b * KDA_DECAY_FLOOR)), 0.0) * \
+        kf[..., None, :, :]                              # (..., S, C, d)
+    col = col.astype(dtype)
+
+    def decayed_products(x):
+        return jnp.einsum('...sid,...sjd->...sij',
+                          (blocks(x) * row).astype(dtype), col,
+                          preferred_element_type=jnp.float32) \
+            .reshape(lead + (c, c))
+    lower = jnp.tril(jnp.ones((c, c), bool))
+    a = jnp.where(jnp.tril(lower, -1), decayed_products(kf), 0.0)
+    whole_b = jnp.where(lower, decayed_products(qf), 0.0)
+    inverse = _unit_lower_inverse(beta[..., None] * a, b)
+    t = (inverse * beta[..., None, :]).astype(dtype)
+    decay = jnp.exp(total)
+    w = jnp.matmul(t, (kf * decay).astype(dtype),
+                   preferred_element_type=jnp.float32)
+    u0 = jnp.matmul(t, v, preferred_element_type=jnp.float32)
+    last = total[..., -1:, :]
+    return (w.astype(dtype), u0.astype(dtype), (qf * decay).astype(dtype),
+            whole_b.astype(dtype), (kf * jnp.exp(last - total)).astype(dtype),
+            decay[..., -1, :])
+
+
+def _chunk_step(state, part):
+    """One chunk of the scan: the state it leaves and its outputs."""
+    w, u0, q_decayed, b, k_rest, decay_last = part
+    dtype = w.dtype
+    carried = state.astype(dtype)
+    u = (u0.astype(jnp.float32) - jnp.matmul(
+        w, carried, preferred_element_type=jnp.float32)).astype(dtype)
+    out = jnp.matmul(q_decayed, carried,
+                     preferred_element_type=jnp.float32) + \
+        jnp.matmul(b, u, preferred_element_type=jnp.float32)
+    after = decay_last[..., :, None] * state + jnp.matmul(
+        k_rest.swapaxes(-2, -1), u, preferred_element_type=jnp.float32)
+    return after, out.astype(dtype)
+
+
+def _carry_state(state, parts):
+    """The scan over the chunks (axis 0 of every part) from ``state``
+    (..., d_k, d_v), float32: the state after the last chunk and the
+    outputs of every chunk, (chunks, ..., C, d_v) in the parts' dtype."""
+    return jax.lax.scan(_chunk_step, state, parts)
+
+
+def _rule_segment(t, per, c, state, xs, first):
+    """One segment of the rule: ``per`` chunks of ``c`` tokens from token
+    ``first`` of the (padded) sequences, whose first ``t`` tokens are real.
+    ``xs`` are the segment's ``q``, ``k``, ``v``, ``g`` and ``beta``, (N,
+    per * c, ...).  Returns the state after it, its outputs and how many of
+    its log-decays lay under the floor."""
+    q, k, v, g, beta = xs
+    n, _, h, _ = q.shape
+    # padded tokens write nothing and decay nothing
+    real = (first + jnp.arange(per * c)) < t
+    g = jnp.where(real[:, None, None], g.astype(jnp.float32), 0.0)
+    beta = jnp.where(real[:, None], beta.astype(jnp.float32), 0.0)
+    # the floor: a token's decay of a channel is held to e^floor at least
+    held = jnp.sum(g < KDA_DECAY_FLOOR, dtype=jnp.float32)
+    g = jnp.maximum(g, KDA_DECAY_FLOOR)
+
+    def chunked(x):
+        # (chunks, N, H, C, ...): the inner scan runs over the first axis
+        x = x.reshape((n, per, c) + x.shape[2:])
+        return jnp.transpose(x, (1, 0, 3, 2) + tuple(range(4, x.ndim)))
+    parts = _chunk_parts(*(chunked(x) for x in (q, k, v, g, beta)))
+    state, out = _carry_state(state, tuple(parts))
+    out = jnp.transpose(out, (1, 0, 3, 2, 4)).reshape(n, per * c, h, -1)
+    return state, out, held
+
+
+def _rows(x, first, count):
+    return jax.lax.dynamic_slice_in_dim(x, first, count, axis=1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _segments(static, params, arrays):
+    """``segment(params, state, xs, first)`` -> (state, outputs, a count)
+    over the whole of ``arrays`` (each (N, lead + T', ...), T' a multiple of
+    the segment's ``rows``, ``lead`` rows of zeros in front), ``rows`` at a
+    time: ``xs`` are the rows from ``first`` behind the ``lead`` before
+    them, the state (``state_shape``, float32) is carried from segment to
+    segment from zero, and ``params`` are trained arrays; ``static`` is
+    ``(segment, rows, lead, state_shape)``.  Returns the outputs (N, T',
+    ...) and the counts' sum.  The segments are cut out of the arrays where
+    they lie and the outputs written where they belong, and the backward
+    pass does the same from the last segment to the first, making each again
+    from the state it started with and writing its rows' cotangents over
+    the rows it has read: what is kept between the passes is a state a
+    segment, what lives at once is what one segment makes, the arrays are
+    held once, and nothing as long as the sequence is copied into another
+    order."""
+    return _segments_fwd(static, params, arrays)[0]
+
+
+def _segments_fwd(static, params, arrays):
+    segment, rows, lead, state_shape = static
+    total = arrays[0].shape[1] - lead
+
+    def cut(first):
+        return tuple(_rows(x, first, lead + rows) for x in arrays)
+    state = jnp.zeros(state_shape, jnp.float32)
+    like = jax.eval_shape(lambda: segment(params, state, cut(0), 0)[1])
+    out = jnp.zeros((like.shape[0], total) + like.shape[2:], like.dtype)
+
+    def one(carry, first):
+        state, out = carry
+        after, rows_out, counted = segment(params, state, cut(first), first)
+        out = jax.lax.dynamic_update_slice_in_dim(out, rows_out, first,
+                                                  axis=1)
+        return (after, out), (counted, state)
+
+    (_, out), (counted, states) = jax.lax.scan(
+        one, (state, out), jnp.arange(total // rows, dtype=jnp.int32) * rows)
+    return (out, jnp.sum(counted)), (params, arrays, states)
+
+
+def _segments_bwd(static, res, cotangent):
+    params, arrays, states = res
+    d_out, _ = cotangent
+    segment, rows, lead, _ = static
+
+    def one(carry, x):
+        d_state, d_params, arrays, edge = carry
+        first, state = x
+        xs = tuple(_rows(a, first, lead + rows) for a in arrays)
+        (_, _, counted), back = jax.vjp(
+            lambda p, s, xs: segment(p, s, xs, first), params, state, xs)
+        d_p, d_state, d_xs = back((d_state, _rows(d_out, first, rows),
+                                   jnp.zeros_like(counted)))
+        # a segment's rows give way to their cotangents once it has read
+        # them, so that the arrays are held once and not twice; but for its
+        # first ``lead`` rows, the last rows of the segment before it, which
+        # has yet to read them: their cotangents wait in ``edge`` and are
+        # added to what that segment finds for them
+        d_xs = tuple(jnp.concatenate([d[:, :rows], d[:, rows:] + e], axis=1)
+                     for d, e in zip((d.astype(a.dtype)
+                                      for d, a in zip(d_xs, arrays)), edge))
+        arrays = tuple(
+            jax.lax.dynamic_update_slice_in_dim(a, d[:, lead:], first + lead,
+                                                axis=1)
+            for a, d in zip(arrays, d_xs))
+        return (d_state, jax.tree_util.tree_map(jnp.add, d_params, d_p),
+                arrays, tuple(d[:, :lead] for d in d_xs)), None
+
+    firsts = jnp.arange(states.shape[0], dtype=jnp.int32) * rows
+    (_, d_params, d_arrays, edge), _ = jax.lax.scan(
+        one, (jnp.zeros_like(states[0]),
+              jax.tree_util.tree_map(jnp.zeros_like, params), arrays,
+              tuple(jnp.zeros_like(a[:, :lead]) for a in arrays)),
+        (firsts, states), reverse=True)
+    return d_params, tuple(
+        jax.lax.dynamic_update_slice_in_dim(a, e, 0, axis=1)
+        for a, e in zip(d_arrays, edge))
+
+
+_segments.defvjp(_segments_fwd, _segments_bwd)
+
+
+def _segmenting(chunk_size, t):
+    """How ``t`` tokens go: the tokens of a chunk (``chunk_size``, or the
+    sequence if that is shorter, up to a multiple of the sub-block), the
+    chunks of a segment (up to ``KDA_SEGMENT``, a divisor of the chunks) and
+    the tokens that pad the sequence to whole chunks."""
+    c = -(-min(int(chunk_size), t) // KDA_SUB) * KDA_SUB
+    chunks = -(-t // c)
+    per = next(s for s in range(min(KDA_SEGMENT, chunks), 0, -1)
+               if chunks % s == 0)
+    return c, per, chunks * c - t
+
+
+def _padded(x, lead, pad):
+    return jnp.pad(x, ((0, 0), (lead, pad)) + ((0, 0),) * (x.ndim - 2))
+
+
+def delta_rule_chunked(q, k, v, g, beta, chunk_size=64):
+    """The gated delta rule with a decay for every channel, in chunks.
+    ``q``, ``k`` (N, T, H, d_k), ``v`` (N, T, H, d_v), log-decay ``g``
+    (N, T, H, d_k) and ``beta`` (N, T, H), the last two float32; ``q`` comes
+    scaled.  Returns ``o`` (N, T, H, d_v) and how many of the log-decays lay
+    under ``KDA_DECAY_FLOOR`` and were taken as the floor.  A sequence that
+    is no multiple of the chunk is padded with tokens that write nothing
+    (beta 0) and decay nothing.  The chunks go in segments of up to
+    ``KDA_SEGMENT`` (``_segments``)."""
+    n, t, h, d_k = q.shape
+    c, per, pad = _segmenting(chunk_size, t)
+
+    def segment(params, state, xs, first):
+        return _rule_segment(t, per, c, state, xs, first)
+    out, held = _segments(
+        (segment, per * c, 0, (n, h, d_k, v.shape[-1])), (),
+        tuple(_padded(x, 0, pad) for x in (q, k, v, g, beta)))
+    return out[:, :t], held
+
+
+def _kda_prepare(heads, lead, kernels, a_log, dt_bias, xs):
+    """A segment's queries, keys and values, float32 log-decay and beta, and
+    its output gate by head, from its rows of the layer's projections behind
+    the ``lead`` rows before it."""
+    query, key, value, decay, beta, gate = xs
+
+    def by_head(x):
+        return x.reshape(x.shape[:2] + (heads, -1))
+    with jax.named_scope('conv'):
+        q, k, v = (by_head(causal_conv_silu(x, kernel, lead))
+                   for x, kernel in zip((query, key, value), kernels))
+        q = (_l2norm(q) * q.shape[-1] ** -0.5).astype(query.dtype)
+        k = _l2norm(k).astype(query.dtype)
+    with jax.named_scope('gates'):
+        rate = -jnp.exp(a_log.astype(jnp.float32))[:, None]
+        g = rate * jax.nn.softplus(
+            by_head(decay[:, lead:]).astype(jnp.float32) +
+            dt_bias.astype(jnp.float32).reshape(heads, -1))
+        beta = jax.nn.sigmoid(beta[:, lead:].astype(jnp.float32))
+    return (q, k, v, g, beta), by_head(gate[:, lead:])
+
+
+def _kda_out_gate(eps, gamma, o, gate):
+    """A segment's outputs normed over a head and gated."""
+    with jax.named_scope('out_gate'):
+        of = o.astype(jnp.float32)
+        of = of * jax.lax.rsqrt(jnp.mean(of * of, axis=-1, keepdims=True) +
+                                eps)
+        out = of * gamma.astype(jnp.float32) * \
+            jax.nn.sigmoid(gate.astype(jnp.float32))
+        return out.reshape(out.shape[:2] + (-1,)).astype(o.dtype)
+
+
+def _kimi_delta_attention_apply(attrs, inputs, is_train, rng):
+    (query, key, value, q_kernel, k_kernel, v_kernel, decay, a_log, dt_bias,
+     beta, gate, o_gamma, count_so_far) = inputs
+    heads, eps = int(attrs['num_heads']), float(attrs['eps'])
+    n, t, channels = query.shape
+    lead = q_kernel.shape[1] - 1
+    c, per, pad = _segmenting(attrs['chunk_size'], t)
+
+    def segment(params, state, xs, first):
+        # the convolutions, the gates and the output's norm and gate run a
+        # segment at a time with the rule, so that nothing they make is as
+        # long as the sequence: their scopes lie under ``scan``
+        kernels, a_log, dt_bias, gamma = params
+        xs, gate = _kda_prepare(heads, lead, kernels, a_log, dt_bias, xs)
+        state, out, held = _rule_segment(t, per, c, state, xs, first)
+        return state, _kda_out_gate(eps, gamma, out, gate), held
+    with jax.named_scope('scan'):
+        out, held = _segments(
+            (segment, per * c, lead, (n, heads) + (channels // heads,) * 2),
+            ((q_kernel, k_kernel, v_kernel), a_log, dt_bias, o_gamma),
+            tuple(_padded(x, lead, pad)
+                  for x in (query, key, value, decay, beta, gate)))
+    step = jnp.stack([jnp.float32(n * t), jnp.float32(n * ((t + pad) // c)),
+                      held])
+    return [out[:, :t]], {'count': count_so_far.astype(jnp.float32) + step}
+
+
+def _kimi_delta_attention_counters(now, before, attrs, in_shapes):
+    """What the layer counted since the last drain, into the registry: its
+    tokens, its chunks, the log-decays it computed (one a token and channel)
+    and how many of them lay under ``KDA_DECAY_FLOOR`` and were held to
+    it."""
+    from .. import instrument
+    count = now['count'] - (before['count'] if before else 0)
+    instrument.inc('kda.tokens', int(count[0]))
+    instrument.inc('kda.chunks', int(count[1]))
+    instrument.inc('kda.decays', int(count[0]) * int(in_shapes[0][2]))
+    instrument.inc('kda.decays_at_floor', int(count[2]))
+
+
+def _kimi_delta_attention_complete(attrs, in_shapes):
+    if in_shapes[0] is None:
+        return in_shapes
+    heads, taps = int(attrs['num_heads']), int(attrs['kernel'])
+    n, t, channels = in_shapes[0]
+    for i in (1, 2, 6, 10):
+        _complete(in_shapes, i, (n, t, channels))
+    for i in (3, 4, 5):
+        _complete(in_shapes, i, (channels, taps))
+    _complete(in_shapes, 7, (heads,))
+    _complete(in_shapes, 8, (channels,))
+    _complete(in_shapes, 9, (n, t, heads))
+    _complete(in_shapes, 11, (channels // heads,))
+    return in_shapes
+
+
+register('KimiDeltaAttention', _kimi_delta_attention_apply,
+         input_names=lambda attrs: [
+             'query', 'key', 'value', 'q_conv_weight', 'k_conv_weight',
+             'v_conv_weight', 'decay', 'A_log', 'dt_bias', 'beta', 'gate',
+             'o_norm_gamma'],
+         num_outputs=lambda attrs: 1,
+         aux_names=lambda attrs: ['count'],
+         aux_shape=lambda attrs, in_shapes: [(3,)],
+         complete_shapes=_kimi_delta_attention_complete,
+         keep_dtype=('A_log', 'dt_bias'),
+         aux_counters=_kimi_delta_attention_counters,
+         attr_defaults={'num_heads': None, 'kernel': 4, 'chunk_size': 64,
+                        'eps': 1e-5},
+         hint='kimideltaattention',
+         doc='Kimi Delta Attention between its projections: query, key, '
+             'value, decay and gate (N, T, H * d) and beta (N, T, H) -> '
+             '(N, T, H * d).  A causal depthwise convolution and silu on '
+             'query, key and value, an l2 norm on the first two; a log-decay '
+             'for every channel, -exp(A_log) softplus(decay + dt_bias); the '
+             'gated delta rule in chunks of chunk_size tokens with the state '
+             'carried by a scan; an RMS norm over a head and a sigmoid gate '
+             'on the output.  Auxiliary state count (3,): running totals of '
+             'tokens, chunks, and log-decays (one a token and channel) that '
+             'lay under -10 and were held to it.')

@@ -880,11 +880,13 @@ def _flash_attention_apply(attrs, inputs, is_train, rng):
     # ring attention over the mesh axis instead of a local kernel.
     from ..parallel.sp import current_sp_axis, current_sp_mode
     axis = current_sp_axis()
-    if k.shape[1] != q.shape[1]:
-        # grouped queries: fewer key-value heads than query heads
+    if k.shape[1] != q.shape[1] or v.shape[-1] != q.shape[-1]:
+        # grouped queries: fewer key-value heads than query heads; or
+        # values of another size than the keys (latent attention)
         if axis is not None:
-            raise NotImplementedError('FlashAttention: grouped-query '
-                                      'attention under sequence parallelism')
+            raise NotImplementedError('FlashAttention: grouped-query or '
+                                      'latent attention under sequence '
+                                      'parallelism')
         from .pallas_attention import gqa_attention
         return [gqa_attention(q, k, v, causal=causal,
                               scale=float(scale) if scale is not None

@@ -318,15 +318,26 @@ def flash_attention(q, k, v, causal=False, scale=None,
 GQA_BLOCK = 1024
 
 
+# The fused backward kernel writes the queries' gradient once for every
+# block of keys and adds the parts up afterwards: sequences x heads x
+# (T / block) x T x D values.  At 2 x 32 heads of 64 that is 0.5e9 B and
+# worth its time (above); at 2 x 32 heads of 192 it is 1.6e9 B laid out as
+# 2.1e9, more than the whole layer keeps otherwise, so past this size the
+# queries' gradient gets its own kernel.
+FUSED_BWD_PARTS_MAX_BYTES = 1 << 30
+
+
 @functools.lru_cache(maxsize=None)
-def _splash_kernel(t, group, causal, block):
+def _splash_kernel(t, group, causal, block, fused_bwd=True):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as kernel, splash_attention_mask as masks)
     mask = masks.CausalMask((t, t)) if causal else masks.FullMask((t, t))
     sizes = kernel.BlockSizes(
         block_q=block, block_kv=block, block_kv_compute=block,
         block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
-        use_fused_bwd_kernel=True)
+        block_q_dq=None if fused_bwd else block,
+        block_kv_dq=None if fused_bwd else block,
+        use_fused_bwd_kernel=fused_bwd)
     # made once and kept: its mask tables must be arrays, not the tracers
     # of whichever trace asked first
     with jax.ensure_compile_time_eval():
@@ -335,26 +346,34 @@ def _splash_kernel(t, group, causal, block):
 
 
 def gqa_attention(q, k, v, causal=False, scale=None):
-    """Attention of ``q`` [B, H, T, D] over ``k``, ``v`` [B, KV, T, D], each
-    key-value head serving H / KV consecutive query heads.  On the TPU the
-    splash kernel, with its own backward; elsewhere, and at lengths it does
-    not tile, the jnp expression over repeated keys and values."""
+    """Attention of ``q`` [B, H, T, D] over ``k`` [B, KV, T, D] and ``v``
+    [B, KV, T, Dv], each key-value head serving H / KV consecutive query
+    heads; the values may be narrower or wider than the keys (latent
+    attention: keys of 192, values of 128), and the output is [B, H, T, Dv].
+    On the TPU the splash kernel, with its own backward; elsewhere, and at
+    lengths it does not tile, the jnp expression over repeated keys and
+    values."""
     b, h, t, d = q.shape
-    kv = k.shape[1]
-    if h % kv:
-        raise ValueError('%d query heads over %d key-value heads' % (h, kv))
+    kv, dv = k.shape[1], v.shape[-1]
+    if h % kv or v.shape[1] != kv or k.shape[-1] != d or \
+            v.shape[2] != k.shape[2]:
+        raise ValueError('queries %s, keys %s and values %s do not go '
+                         'together' % (q.shape, k.shape, v.shape))
     group = h // kv
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     block = next((c for c in (GQA_BLOCK, 512, 256, 128) if t % c == 0), None)
     if _mode(seq_len=t) == 'kernel' and block and k.shape[2] == t:
-        attend = _splash_kernel(t, group, bool(causal), block)
+        parts = b * h * (t // block) * t * d * q.dtype.itemsize
+        attend = _splash_kernel(t, group, bool(causal), block,
+                                parts <= FUSED_BWD_PARTS_MAX_BYTES)
         # the kernel applies no scale of its own
         grouped = (q * jnp.asarray(scale, q.dtype)).reshape(b, kv, group,
                                                             t, d)
-        return jax.vmap(jax.vmap(attend))(grouped, k, v).reshape(q.shape)
+        return jax.vmap(jax.vmap(attend))(grouped, k, v) \
+            .reshape(b, h, t, dv)
     k3 = jnp.repeat(k, group, axis=1).reshape(b * h, k.shape[2], d)
-    v3 = jnp.repeat(v, group, axis=1).reshape(b * h, v.shape[2], d)
+    v3 = jnp.repeat(v, group, axis=1).reshape(b * h, v.shape[2], dv)
     o3, _ = _ref_attention(q.reshape(b * h, t, d), k3, v3, float(scale),
                            bool(causal))
-    return o3.reshape(q.shape)
+    return o3.reshape(b, h, t, dv)

@@ -1,7 +1,7 @@
 package org.mxtpu
 
 /** GENERATED by tools/gen_scala_ops.py from the live op
-  * registry (235 ops) — do not edit.  Imperative invoke-
+  * registry (236 ops) — do not edit.  Imperative invoke-
   * by-name creators (NDArray.genericInvoke -> the C ABI
   * MXImperativeInvokeByName), the role of the reference
   * NDArrayMacro expansion.
@@ -51,6 +51,8 @@ object NDArrayOps {
     NDArray.genericInvoke("IdentityAttachKLSparseReg", inputs, attrs)
   def InstanceNorm(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
     NDArray.genericInvoke("InstanceNorm", inputs, attrs)
+  def KimiDeltaAttention(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
+    NDArray.genericInvoke("KimiDeltaAttention", inputs, attrs)
   def L2Normalization(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
     NDArray.genericInvoke("L2Normalization", inputs, attrs)
   def LRN(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =

@@ -1,7 +1,7 @@
 package org.mxtpu
 
 /** GENERATED by tools/gen_scala_ops.py from the live op
-  * registry (235 ops) — do not edit.  The role of the
+  * registry (236 ops) — do not edit.  The role of the
   * reference scala-package macro expansion
   * (SymbolImplMacros.scala): one typed creator per
   * registered operator, delegating to Symbol.create.
@@ -54,6 +54,8 @@ object SymbolOps {
     Symbol.create("IdentityAttachKLSparseReg", name)(inputs: _*)(attrs: _*)
   def InstanceNorm(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
     Symbol.create("InstanceNorm", name)(inputs: _*)(attrs: _*)
+  def KimiDeltaAttention(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
+    Symbol.create("KimiDeltaAttention", name)(inputs: _*)(attrs: _*)
   def L2Normalization(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
     Symbol.create("L2Normalization", name)(inputs: _*)(attrs: _*)
   def LRN(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =

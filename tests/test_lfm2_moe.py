@@ -744,7 +744,11 @@ def test_grouped_products_are_given_each_experts_aligned_rows_and_no_more(
     given, plain = [], lm.grouped_matmul
 
     def recording(lhs, rhs, group_sizes):
-        given.append((lhs.shape[0], np.asarray(group_sizes)))
+        # a callback, so that a slice of the last rung, which is traced even
+        # eagerly (a checkpoint inside a scan), is seen with its numbers too
+        jax.debug.callback(
+            lambda sizes, rows=lhs.shape[0]: given.append(
+                (rows, np.asarray(sizes))), group_sizes)
         return plain(lhs, rhs, group_sizes)
 
     monkeypatch.setattr(lm, 'grouped_matmul', recording)
@@ -753,15 +757,30 @@ def test_grouped_products_are_given_each_experts_aligned_rows_and_no_more(
     # forward and backward (which computes the taken branch again)
     with jax.disable_jit():
         load = np.asarray(apply_op(name, attrs, inputs, aux)[1]['expert_load'])
+        jax.effects_barrier()
+        forward = list(given)
         jax.grad(lambda *xs: experts_loss(xs, attrs, aux, cotangent)[0],
                  tuple(range(5)))(*inputs)
-    align = lm._room(N * T * 4, attrs['experts_held'][1],
-                     attrs['num_experts'])[2]
+        jax.effects_barrier()
+    rooms, _, align = lm._room(N * T * 4, attrs['experts_held'][1],
+                               attrs['num_experts'])
     want = np.ceil(load / align) * align
     assert len(given) >= 6
-    for buffer_rows, sizes in given:
-        assert buffer_rows == rows
-        np.testing.assert_array_equal(sizes, want)
+    if len(rooms) > 1 and rows == rooms[-1]:
+        # the ladder's last rung runs the rung before it's rows at a time
+        # (PR 34): every product is given one slice's rows, in groups that
+        # start on a tile's first row, and over the slices each expert is
+        # given its aligned rows and no more, by each of the three products
+        for buffer_rows, sizes in given:
+            assert buffer_rows == rooms[-2]
+            assert sizes.sum() <= buffer_rows and not (sizes % align).any()
+        assert len(forward) == 3 * -(-rows // rooms[-2])
+        np.testing.assert_array_equal(
+            np.sum([sizes for _, sizes in forward], axis=0), 3 * want)
+    else:
+        for buffer_rows, sizes in given:
+            assert buffer_rows == rows
+            np.testing.assert_array_equal(sizes, want)
     assert want.sum() <= rows and (want.sum() == rows) == full
     # and the share the drain reports is these rows over that buffer
     count, share = drained(name, attrs, inputs, load, 'rows_visited_share')

@@ -419,6 +419,54 @@ def test_nonfused_fallback_trains_under_mesh():
         np.testing.assert_allclose(b[k], a[k], rtol=2e-5, atol=2e-6)
 
 
+def test_gradient_arrays_under_a_mesh_are_made_where_their_weights_lie():
+    """A bound training executor's gradient arrays reach the devices when
+    first read (``ndarray.ZerosWhenRead``, PR 34).  The fused sharded fit
+    never reads them; what does (``Module.update``'s per-parameter loop,
+    ``Executor.backward`` under ``grad_req='add'``, ``get_input_grads``)
+    finds zeros under the sharding the mesh gives that parameter."""
+    from mxnet_tpu.ndarray import ZerosWhenRead
+    mod = _fit(mesh='4x2', partition='auto', num_epoch=1)
+    assert mod._fused is not None
+    exec_ = mod._exec_group.execs[0]
+    assert set(exec_.grad_dict) == set(mod._param_names)
+    assert all(isinstance(g, ZerosWhenRead) and g._made is None
+               for g in exec_.grad_dict.values())
+    for name, grad in exec_.grad_dict.items():
+        weight = exec_.arg_dict[name].handle
+        assert grad.shape == weight.shape and not grad.asnumpy().any()
+        assert grad.handle.sharding.is_equivalent_to(weight.sharding,
+                                                     weight.ndim)
+    # some weight is split over the two tensor-parallel devices: so were
+    # its zeros, and not every array lies whole on every device
+    assert any(len({s.index for s in g.handle.addressable_shards}) > 1
+               for g in exec_.grad_dict.values())
+
+    # accumulated into (grad_req 'add' reads the array it adds to): twice
+    # one batch's gradient after two passes, under the mesh as without it
+    def accumulated(mesh):
+        module = mx.mod.Module(_mlp(), context=mx.cpu())
+        if mesh:
+            module._set_parallel(mesh, 'auto')
+        module.bind([('data', (32, 16))], [('softmax_label', (32,))],
+                    grad_req='add')
+        mx.random.seed(7)
+        module.init_params(mx.init.Uniform(0.05))
+        X, Y = _data(32)
+        batch = mx.io.DataBatch([mx.nd.array(X)], [mx.nd.array(Y)])
+        grads = module._exec_group.execs[0].grad_dict
+        assert all(g._made is None for g in grads.values())
+        for _ in range(2):
+            module.forward(batch, is_train=True)
+            module.backward()
+        return {k: v.asnumpy() for k, v in grads.items()}
+    plain, sharded = accumulated(None), accumulated('4x2')
+    assert np.abs(plain['fc1_weight']).max() > 0
+    for name in plain:
+        np.testing.assert_allclose(sharded[name], plain[name], rtol=2e-5,
+                                   atol=2e-6)
+
+
 # ---------------------------------------------------------------------------
 # bucketing: every bucket module inherits the mesh plan
 # ---------------------------------------------------------------------------
