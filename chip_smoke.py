@@ -40,6 +40,9 @@ FIT_ARGS = dict(
     optimizer_params={'learning_rate': 0.05, 'momentum': 0.9, 'wd': 1e-4})
 TOL = {'bfloat16': 3e-2, 'float32': 2e-3}
 ATTENTION_SHAPES = ((16, 8, 512, 64), (1, 8, 2048, 128))   # b, h, t, d
+# the gated delta rule's segment in kimi_linear_fit_8k: sequences, tokens of
+# a segment, heads, channels a head, tokens a chunk
+KDA_SEGMENT_SHAPE = (2, 1024, 32, 128, 64)
 
 
 class SmokeFailure(Exception):
@@ -357,6 +360,47 @@ def phase_kernels():
                             (q, k, v), True, TOL['bfloat16'])
         compile_and_compare('flash_attention bwd ' + shape, grads(flash),
                             grads(ref), (q, k, v), True, TOL['bfloat16'])
+
+    # the gated delta rule of KimiDeltaAttention: the two Pallas kernels of
+    # a segment against the jnp form, value and all six cotangents
+    from mxnet_tpu import config
+    from mxnet_tpu.ops import lm
+    n, rows, heads, d, chunk = KDA_SEGMENT_SHAPE
+    q = rand((n, rows, heads, d), 'bfloat16', d ** -0.5)
+    k = rand((n, rows, heads, d), 'float32')
+    k = (k / jnp.linalg.norm(k, axis=-1, keepdims=True)).astype(jnp.bfloat16)
+    v = rand((n, rows, heads, d), 'bfloat16')
+    g = -jnp.abs(rand((n, rows, heads, d), 'float32', 0.5)) ** 3
+    beta = jax.nn.sigmoid(rand((n, rows, heads), 'float32'))
+    state = rand((n, heads, d, d), 'float32', 0.1)
+    cotangent = (rand((n, heads, d, d), 'float32', 0.1),
+                 rand((n, rows, heads, d), 'bfloat16'))
+
+    def rule(kernels):
+        def segment(*a):
+            # read when the segment is traced: the jnp form is the
+            # reference
+            if not kernels:
+                os.environ['MXTPU_DISABLE_PALLAS'] = '1'
+            try:
+                after, out, _ = lm._rule_segment(
+                    rows, rows // chunk, chunk, a[5], a[:5], 0)
+            finally:
+                os.environ.pop('MXTPU_DISABLE_PALLAS', None)
+            return after, out
+        return segment
+
+    def backward(fn):
+        return lambda *a: jax.vjp(fn, *a)[1](cotangent)
+    shape = 'n=%d rows=%d h=%d d=%d chunk=%d' % KDA_SEGMENT_SHAPE
+    check(config.pallas_mode() == 'kernel' and lm._rule_in_kernel(
+        rows, d, d, chunk, jnp.bfloat16),
+          'the rule of %s does not take its kernels' % shape)
+    compile_and_compare('kda rule fwd ' + shape, rule(True), rule(False),
+                        (q, k, v, g, beta, state), True, TOL['bfloat16'])
+    compile_and_compare('kda rule bwd ' + shape, backward(rule(True)),
+                        backward(rule(False)), (q, k, v, g, beta, state),
+                        True, TOL['bfloat16'])
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from . import pallas_kda
 from .registry import register, register_simple
 from .nn import _complete
 
@@ -498,6 +499,18 @@ register('SparseExperts', _sparse_experts_apply,
 # (on the TPU one bf16 pass: I - N is exact, and what the pass rounds are the
 # powers of N from the second on, which the cast of T to the inputs' dtype
 # rounds as much).
+#
+# Two forms of one algorithm.  On a TPU, at head widths a multiple of 128,
+# chunks a multiple of 16 and in float32 or bfloat16 (``_rule_in_kernel``: a
+# static predicate on what the trace sees, no knob), a segment's rule runs in
+# the Pallas kernels of ``pallas_kda``: one call forward, one backward, a
+# chunk's parts, the inverse and the carried state in VMEM, the inverse
+# differentiated as an inverse.  Anywhere else (off the TPU, other shapes,
+# ``MXTPU_DISABLE_PALLAS``) it runs in the jnp form below (``_chunk_parts``,
+# ``_chunk_step``, ``_carry_state``, differentiated by JAX), which is also
+# the tests' second oracle beside the token-by-token recurrence.  The
+# kernels round where the jnp form on the TPU rounds and nowhere else;
+# ``count`` says through ``kda.chunks_in_kernel`` which form ran.
 # ---------------------------------------------------------------------------
 
 KDA_SUB = 8
@@ -625,6 +638,15 @@ def _carry_state(state, parts):
     return jax.lax.scan(_chunk_step, state, parts)
 
 
+def _rule_in_kernel(rows, d_k, d_v, c, dtype):
+    """Whether a segment of ``rows`` tokens in chunks of ``c``, heads of
+    ``d_k`` and ``d_v`` channels in ``dtype``, runs in the Pallas kernels
+    (``pallas_kda``): on a TPU, or under the interpreter, and at shapes and
+    a dtype they were written for; anything else takes the jnp form.  What
+    the code can see when it is traced, and no knob."""
+    return pallas_kda.engages(rows, d_k, d_v, c, KDA_SUB, dtype)
+
+
 def _rule_segment(t, per, c, state, xs, first):
     """One segment of the rule: ``per`` chunks of ``c`` tokens from token
     ``first`` of the (padded) sequences, whose first ``t`` tokens are real.
@@ -640,6 +662,10 @@ def _rule_segment(t, per, c, state, xs, first):
     # the floor: a token's decay of a channel is held to e^floor at least
     held = jnp.sum(g < KDA_DECAY_FLOOR, dtype=jnp.float32)
     g = jnp.maximum(g, KDA_DECAY_FLOOR)
+    if _rule_in_kernel(per * c, q.shape[-1], v.shape[-1], c, q.dtype):
+        state, out = pallas_kda.rule_segment(q, k, v, g, beta, state, c,
+                                             KDA_SUB, KDA_DECAY_FLOOR)
+        return state, out, held
 
     def chunked(x):
         # (chunks, N, H, C, ...): the inner scan runs over the first axis
@@ -838,13 +864,21 @@ def _kimi_delta_attention_apply(attrs, inputs, is_train, rng):
 def _kimi_delta_attention_counters(now, before, attrs, in_shapes):
     """What the layer counted since the last drain, into the registry: its
     tokens, its chunks, the log-decays it computed (one a token and channel)
-    and how many of them lay under ``KDA_DECAY_FLOOR`` and were held to
-    it."""
+    and how many of them lay under ``KDA_DECAY_FLOOR`` and were held to it;
+    and the chunks whose rule ran in the Pallas kernels: all of them or
+    none, by the predicate that chose when the step was traced (a drain
+    does not know the layer's dtype: float32 and bfloat16, what a module
+    computes in, both pass)."""
     from .. import instrument
     count = now['count'] - (before['count'] if before else 0)
+    _, t, channels = in_shapes[0]
+    d = channels // int(attrs['num_heads'])
+    c, per, _ = _segmenting(attrs['chunk_size'], t)
+    in_kernel = _rule_in_kernel(per * c, d, d, c, jnp.float32)
     instrument.inc('kda.tokens', int(count[0]))
     instrument.inc('kda.chunks', int(count[1]))
-    instrument.inc('kda.decays', int(count[0]) * int(in_shapes[0][2]))
+    instrument.inc('kda.chunks_in_kernel', int(count[1]) * in_kernel)
+    instrument.inc('kda.decays', int(count[0]) * int(channels))
     instrument.inc('kda.decays_at_floor', int(count[2]))
 
 
@@ -884,7 +918,10 @@ register('KimiDeltaAttention', _kimi_delta_attention_apply,
              'query, key and value, an l2 norm on the first two; a log-decay '
              'for every channel, -exp(A_log) softplus(decay + dt_bias); the '
              'gated delta rule in chunks of chunk_size tokens with the state '
-             'carried by a scan; an RMS norm over a head and a sigmoid gate '
-             'on the output.  Auxiliary state count (3,): running totals of '
+             'carried from chunk to chunk (on a TPU, at heads of a multiple '
+             'of 128 channels and chunks of a multiple of 16 tokens, in two '
+             'Pallas kernels, ops/pallas_kda.py; elsewhere by a scan in '
+             'jnp); an RMS norm over a head and a sigmoid gate on the '
+             'output.  Auxiliary state count (3,): running totals of '
              'tokens, chunks, and log-decays (one a token and channel) that '
              'lay under -10 and were held to it.')

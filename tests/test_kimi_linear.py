@@ -1,6 +1,8 @@
 """Kimi Linear on the CPU at a small size against its plain reference
 (``mxnet_tpu/models/kimi_linear_reference.py``): the chunked delta rule
-against the recurrence token by token, forward and gradients; values
+against the recurrence token by token, forward and gradients, in the jnp
+form and in the Pallas kernels of ``ops/pallas_kda.py`` through the
+interpreter (heads of 128 channels, the width their predicate accepts); values
 narrower than keys through ``gqa_attention``'s two paths; the whole model's
 log-probabilities, loss and every parameter's gradient, in float32 and
 bf16; one ``Module.fit`` step with Adam; the wrong models the benchmark's
@@ -15,6 +17,7 @@ over a latent of 16, 16 experts of which 4 a token and one shared,
 vocabulary 512, 2 x 40 = 80 tokens (no multiple of the chunk).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +28,7 @@ import mxnet_tpu as mx
 from mxnet_tpu import instrument, models
 from mxnet_tpu.executor import _mirror_stage_units
 from mxnet_tpu.models import kimi_linear_reference as ref
-from mxnet_tpu.ops import lm, pallas_attention
+from mxnet_tpu.ops import lm, pallas_attention, pallas_kda
 from mxnet_tpu.ops.registry import get_op
 from mxnet_tpu.parallel.train_step import make_fit_step
 
@@ -146,11 +149,33 @@ RULE_CASES = [
 ]
 
 
+# the rule's two forms: ``reference`` is the jnp form (what a CPU runs), and
+# ``interpret`` the Pallas kernels of ``ops/pallas_kda.py`` through the
+# interpreter, at heads of 128 channels, the width their predicate accepts
+PATHS = ['reference', 'interpret']
+
+
+def take_path(monkeypatch, path):
+    monkeypatch.delenv('MXTPU_DISABLE_PALLAS', raising=False)
+    monkeypatch.delenv('MXTPU_ASSUME_TPU', raising=False)
+    if path == 'interpret':
+        monkeypatch.setenv('MXTPU_FORCE_PALLAS_INTERPRET', '1')
+    else:
+        monkeypatch.delenv('MXTPU_FORCE_PALLAS_INTERPRET', raising=False)
+
+
+def kernel_calls(fn, *args):
+    return str(jax.make_jaxpr(fn)(*args)).count('pallas_call')
+
+
+@pytest.mark.parametrize('path', PATHS)
 @pytest.mark.parametrize('t, chunk, strength, floor', RULE_CASES)
 def test_chunked_delta_rule_is_the_recurrence_forward_and_backward(
-        t, chunk, strength, floor, monkeypatch):
+        t, chunk, strength, floor, path, monkeypatch):
     monkeypatch.setattr(lm, 'KDA_SEGMENT', 2)
-    inputs = rule_inputs(t, t, strength)
+    take_path(monkeypatch, path)
+    wide = dict(n=1, h=2, d_k=128, d_v=128) if path == 'interpret' else {}
+    inputs = rule_inputs(t, t, strength, **wide)
     cot = draw(np.random.default_rng(1), inputs[2].shape)
 
     def held(q, k, v, g, beta):
@@ -158,9 +183,25 @@ def test_chunked_delta_rule_is_the_recurrence_forward_and_backward(
         chunked form computes, to rounding."""
         return ref.delta_rule(q, k, v, jnp.maximum(g, lm.KDA_DECAY_FLOOR),
                               beta)
+
+    def chunked(*a):
+        return lm.delta_rule_chunked(*a, chunk_size=chunk)
+
+    def gradients(rule):
+        return jax.grad(lambda *a: jnp.sum(rule(*a) * cot),
+                        argnums=(0, 1, 2, 3, 4))(*inputs)
+    # the kernels take every chunk that is a multiple of 16 tokens (a bf16
+    # tile's rows), whole segments, padded tails and floors alike: the
+    # segment's forward kernel; differentiated, that kernel in the forward
+    # pass, again with every chunk's entering state, and the backward kernel
+    c = lm._segmenting(chunk, t)[0]
+    in_kernel = path == 'interpret' and c % 16 == 0
+    assert kernel_calls(chunked, *inputs) == (1 if in_kernel else 0)
+    assert kernel_calls(lambda *a: gradients(lambda *b: chunked(*b)[0]),
+                        *inputs) == (3 if in_kernel else 0)
     with jax.default_matmul_precision('highest'):
         want = held(*inputs)
-        got, at_floor = lm.delta_rule_chunked(*inputs, chunk_size=chunk)
+        got, at_floor = chunked(*inputs)
         assert rel(got, want) < 1e-5
         # every log-decay under the floor is counted, and no other
         assert int(at_floor) == int((inputs[3] < lm.KDA_DECAY_FLOOR).sum())
@@ -169,30 +210,40 @@ def test_chunked_delta_rule_is_the_recurrence_forward_and_backward(
         # the same where no decay reached it, and where one did a decay of
         # e^-10 for one of e^-16 at most: a ten-thousandth of the output
         assert rel(got, ref.delta_rule(*inputs)) < (1e-4 if floor else 1e-5)
-        want_grads = jax.grad(lambda *a: jnp.sum(held(*a) * cot),
-                              argnums=(0, 1, 2, 3, 4))(*inputs)
-        got_grads = jax.grad(
-            lambda *a: jnp.sum(lm.delta_rule_chunked(
-                *a, chunk_size=chunk)[0] * cot),
-            argnums=(0, 1, 2, 3, 4))(*inputs)
+        want_grads = gradients(held)
+        got_grads = gradients(lambda *a: chunked(*a)[0])
+        if in_kernel:
+            # and against the second oracle, the jnp form
+            take_path(monkeypatch, 'reference')
+            assert kernel_calls(chunked, *inputs) == 0
+            jnp_form = chunked(*inputs)[0]
+            jnp_grads = gradients(lambda *a: chunked(*a)[0])
+            assert rel(got, jnp_form) < 1e-5
+            for name, a, b in zip('q k v g beta'.split(), got_grads,
+                                  jnp_grads):
+                assert rel(a, b) < 5e-5, name
     for name, a, b in zip('q k v g beta'.split(), got_grads, want_grads):
         assert np.isfinite(np.asarray(a)).all(), name
         assert rel(a, b) < 5e-5, name
 
 
+@pytest.mark.parametrize('path', PATHS)
 @pytest.mark.parametrize('per', [1, 2])
-def test_the_layer_in_segments_is_the_layer_in_one(per, monkeypatch):
+def test_the_layer_in_segments_is_the_layer_in_one(per, path, monkeypatch):
     """The operator's convolutions read three rows before a segment, the
     last rows of the segment before it; the backward pass writes a
     segment's cotangents over the rows it has read and keeps those three
     rows' until the segment before has read them: output, count and all
-    twelve gradients are what one segment of all the chunks gives."""
-    n, t, h, d = 2, 90, 3, 8
+    twelve gradients are what one segment of all the chunks gives.  In
+    the kernels as in the jnp form, and the two agree."""
+    take_path(monkeypatch, path)
+    n, t, h, d = (1, 90, 2, 128) if path == 'interpret' else (2, 90, 3, 8)
     rng = np.random.default_rng(5)
     wide = lambda *shape: draw(rng, shape)
     inputs = [wide(n, t, h * d), wide(n, t, h * d), wide(n, t, h * d),
               wide(h * d, 4), wide(h * d, 4), wide(h * d, 4),
-              4.0 * wide(n, t, h * d), jnp.log(jnp.asarray([1.0, 4.0, 16.0])),
+              4.0 * wide(n, t, h * d),
+              jnp.log(jnp.asarray([1.0, 4.0, 16.0][:h])),
               wide(h * d), wide(n, t, h), wide(n, t, h * d),
               1.0 + 0.1 * wide(d), jnp.zeros((3,))]
     attrs = {'num_heads': h, 'kernel': 4, 'chunk_size': 16, 'eps': 1e-5}
@@ -209,6 +260,8 @@ def test_the_layer_in_segments_is_the_layer_in_one(per, monkeypatch):
                                       has_aux=True)(*inputs)
     (_, (want, want_count)), want_grads = run()       # six chunks, one segment
     monkeypatch.setattr(lm, 'KDA_SEGMENT', per)
+    assert kernel_calls(lambda *xs: loss(*xs)[0], *inputs) == \
+        (1 if path == 'interpret' else 0)
     (_, (got, got_count)), got_grads = run()
     assert rel(got, want) < 1e-6
     np.testing.assert_array_equal(np.asarray(got_count),
@@ -219,6 +272,12 @@ def test_the_layer_in_segments_is_the_layer_in_one(per, monkeypatch):
     for i, (a, b) in enumerate(zip(got_grads, want_grads)):
         assert np.isfinite(np.asarray(a)).all(), i
         assert rel(a, b) < 2e-5, i
+    if path == 'interpret':
+        take_path(monkeypatch, 'reference')
+        (_, (jnp_form, _)), jnp_grads = run()
+        assert rel(got, jnp_form) < 1e-5
+        for i, (a, b) in enumerate(zip(got_grads, jnp_grads)):
+            assert rel(a, b) < 5e-5, i
 
 
 def test_chunked_delta_rule_in_bf16_follows_the_recurrence():
@@ -228,6 +287,86 @@ def test_chunked_delta_rule_in_bf16_follows_the_recurrence():
     got, _ = lm.delta_rule_chunked(*low, g, beta, chunk_size=64)
     assert got.dtype == jnp.bfloat16
     assert rel(got, want) < 4e-2
+
+
+def test_the_kernels_in_bf16_are_no_farther_from_the_recurrence_than_jnp(
+        monkeypatch):
+    """Output and all five gradients in bf16: the kernels round where the
+    jnp form on a TPU rounds (on this CPU the jnp form's triangular algebra
+    is float32, more than a TPU gives it), and stay as near the float32
+    recurrence."""
+    q, k, v, g, beta = rule_inputs(3, 128, 1.0, n=1, h=2, d_k=128, d_v=128)
+    low = [x.astype(jnp.bfloat16) for x in (q, k, v)]
+    exact = [x.astype(jnp.float32) for x in low] + [g, beta]
+    cot = draw(np.random.default_rng(1), v.shape)
+
+    def both(rule, inputs):
+        def loss(*a):
+            out = rule(*a)
+            return jnp.sum(out.astype(jnp.float32) * cot), out
+        grads, out = jax.grad(loss, argnums=(0, 1, 2, 3, 4),
+                              has_aux=True)(*inputs)
+        return (out,) + grads
+    want = both(ref.delta_rule, exact)
+    chunked = lambda *a: lm.delta_rule_chunked(*a, chunk_size=64)[0]
+    take_path(monkeypatch, 'reference')
+    jnp_form = both(chunked, low + [g, beta])
+    take_path(monkeypatch, 'interpret')
+    assert kernel_calls(chunked, *low, g, beta) == 1
+    kernels = both(chunked, low + [g, beta])
+    assert kernels[0].dtype == jnp.bfloat16
+    for name, got, other, true in zip('out q k v g beta'.split(), kernels,
+                                      jnp_form, want):
+        assert np.isfinite(np.asarray(got, np.float32)).all(), name
+        assert rel(got, true) < 4e-2, name
+        assert rel(got, true) < 1.1 * rel(other, true), name
+
+
+@pytest.mark.parametrize('d, chunk, in_kernel', [
+    (128, 16, True),        # what the kernels were written for
+    (8, 16, False),         # a head narrower than a lane tile
+    (128, 8, False),        # a chunk shorter than a bf16 tile's rows
+])
+def test_the_counter_says_which_form_ran(d, chunk, in_kernel, monkeypatch):
+    """The choice is a static predicate on shapes: the layer outside it
+    takes the jnp form under the interpreter too and counts no chunk in
+    ``kda.chunks_in_kernel``; inside it every chunk."""
+    take_path(monkeypatch, 'interpret')
+    n, t, h = 1, 40, 2
+    rng = np.random.default_rng(6)
+    wide = lambda *shape: draw(rng, shape)
+    inputs = [wide(n, t, h * d), wide(n, t, h * d), wide(n, t, h * d),
+              wide(h * d, 4), wide(h * d, 4), wide(h * d, 4),
+              wide(n, t, h * d), jnp.zeros((h,)), wide(h * d), wide(n, t, h),
+              wide(n, t, h * d), 1.0 + 0.1 * wide(d), jnp.zeros((3,))]
+    attrs = {'num_heads': h, 'kernel': 4, 'chunk_size': chunk, 'eps': 1e-5}
+    apply = lambda *xs: get_op('KimiDeltaAttention').apply(
+        attrs, list(xs), True, None)
+    assert kernel_calls(apply, *inputs) == (1 if in_kernel else 0)
+    _, aux = apply(*inputs)
+    chunks = n * -(-t // chunk)
+    assert aux['count'].shape == (3,) and aux['count'][1] == chunks
+    was = instrument.metrics_enabled()
+    instrument.set_metrics(True)
+    try:
+        before = instrument.metrics_snapshot()['counters']
+        lm._kimi_delta_attention_counters(
+            {'count': np.asarray(aux['count'])}, None, attrs,
+            [x.shape for x in inputs[:-1]])
+        after = instrument.metrics_snapshot()['counters']
+    finally:
+        instrument.set_metrics(was)
+    moved = {k: after.get(k, 0) - before.get(k, 0)
+             for k in ('kda.chunks', 'kda.chunks_in_kernel')}
+    assert moved == {'kda.chunks': chunks,
+                     'kda.chunks_in_kernel': chunks if in_kernel else 0}
+    # off the interpreter, on this CPU, the jnp form runs whatever the shape
+    take_path(monkeypatch, 'reference')
+    assert kernel_calls(lambda *xs: apply(*xs), *inputs) == 0
+    assert not lm._rule_in_kernel(48, d, d, chunk, jnp.float32)
+    assert pallas_kda.admits(48, d, d, chunk, lm.KDA_SUB,
+                             jnp.bfloat16) == in_kernel
+    assert not pallas_kda.admits(48, d, d, chunk, lm.KDA_SUB, jnp.float16)
 
 
 def test_the_triangular_inverse_is_exact_in_two_steps():
@@ -735,6 +874,56 @@ def test_the_symbols_scope_and_the_operators_reach_the_lowered_step(model):
     for nested in ('conv', 'gates', 'out_gate'):
         assert 'closed_call/%s/' % nested in text or \
             '/%s/' % nested in text, nested
+
+
+def kernel_name_stacks(jaxpr, outer=()):
+    """The scopes every ``pallas_call`` of ``jaxpr`` stands under: the name
+    stacks along the way to it, which lowering joins into its ``op_name``."""
+    from jax._src import core
+    for eqn in jaxpr.eqns:
+        # ``jvp(KimiDeltaAttention/l0_kda)/scan``: a transform's name is
+        # a component like a scope's
+        here = outer + tuple(w for w in re.split(
+            '[/()]+', str(eqn.source_info.name_stack)) if w)
+        if eqn.primitive.name == 'pallas_call':
+            yield here
+            continue
+        for inner in core.jaxprs_in_params(eqn.params):
+            yield from kernel_name_stacks(inner, here)
+
+
+def test_the_rules_kernels_stand_under_scan_in_the_lowered_step(monkeypatch):
+    """At heads of 128 channels the rule of every layer lowers to its
+    Pallas kernels: for a layer the forward kernel, the same in the mirror
+    stage's second forward pass and again with every chunk's state in the
+    backward pass, and the backward kernel.  Each stands under its layer's
+    ``scan`` and under none of the scopes that the benchmark's reduction
+    takes out of ``scan`` (``fit_kimi_linear.refine_scopes``)."""
+    monkeypatch.setenv('MXTPU_ASSUME_TPU', '1')
+    monkeypatch.delenv('MXTPU_FORCE_PALLAS_INTERPRET', raising=False)
+    sizes = dict(SIZES, linear_attn_config=dict(LINEAR, head_dim=128,
+                                                num_heads=2))
+    symbol = models.get_symbol('kimi_linear', seq_len=T, **sizes)
+    args, aux = make_params(symbol, 0)
+    step = make_fit_step(symbol, GradsOut(), data_names=('data',),
+                         compute_dtype=jnp.bfloat16, donate=False, _raw=True)
+    batch = {'data': jnp.zeros((N, T), jnp.float32),
+             'softmax_label': jnp.zeros((N, T), jnp.float32)}
+    operands = (dict(args), {}, dict(aux), {}, batch, jnp.float32(0),
+                jax.random.PRNGKey(0))
+    text = jax.jit(step).trace(*operands).lower(
+        lowering_platforms=('tpu',)).as_text()
+    assert text.count('stablehlo.custom_call @tpu_custom_call') == 4 * 4
+    stacks = list(kernel_name_stacks(jax.make_jaxpr(step)(*operands).jaxpr))
+    assert len(stacks) == 4 * 4
+    for layer in (0, 1, 2, 4):
+        of_layer = [s for s in stacks if 'l%d_kda' % layer in s]
+        assert len(of_layer) == 4, layer
+        for stack in of_layer:
+            at = stack.index('l%d_kda' % layer)
+            assert stack[at - 1] == 'KimiDeltaAttention', stack
+            assert stack[at + 1] == 'scan', stack
+            assert not {'conv', 'gates', 'out_gate'} & set(stack), stack
 
 
 def test_the_decays_parameters_keep_their_dtype_under_bf16(model):

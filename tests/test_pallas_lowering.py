@@ -140,6 +140,59 @@ def test_flash_attention_backward_verifies(b, h, t, d):
     assert _kernel_count(lower_tpu(grad, q, q, q)) >= 1
 
 
+# the gated delta rule's segment in kimi_linear_fit_8k (2 sequences, 16
+# chunks of 64 tokens, 32 heads of 128), the shape chip_smoke.py compiles on
+# the chip; and a segment of two chunks of 16 with an odd number of heads
+KDA_SEGMENTS = [(2, 1024, 32, 128, 64), (1, 32, 3, 128, 16)]
+
+
+def _kda_segment(n, rows, heads, d, dtype):
+    wide = jnp.ones((n, rows, heads, d), dtype)
+    return (wide, wide, wide, -jnp.ones((n, rows, heads, d), jnp.float32),
+            jnp.ones((n, rows, heads), jnp.float32),
+            jnp.zeros((n, heads, d, d), jnp.float32))
+
+
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize('n,rows,heads,d,chunk', KDA_SEGMENTS)
+def test_kda_rule_forward_verifies(n, rows, heads, d, chunk, dtype):
+    from mxnet_tpu.ops import lm, pallas_kda
+    assert lm._rule_in_kernel(rows, d, d, chunk, dtype)
+    txt = lower_tpu(
+        lambda *a: pallas_kda.rule_segment(*a, chunk, lm.KDA_SUB,
+                                           lm.KDA_DECAY_FLOOR),
+        *_kda_segment(n, rows, heads, d, dtype))
+    assert _kernel_count(txt) == 1
+    assert '\\22size\\22: %d' % pallas_kda.VMEM_LIMIT_BYTES in txt
+
+
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize('n,rows,heads,d,chunk', KDA_SEGMENTS)
+def test_kda_rule_backward_verifies(n, rows, heads, d, chunk, dtype):
+    """Differentiated, a segment is the forward kernel once more, writing
+    the state every chunk entered with, and the backward kernel."""
+    from mxnet_tpu.ops import lm, pallas_kda
+    args = _kda_segment(n, rows, heads, d, dtype)
+
+    def both(*a):
+        out, back = jax.vjp(
+            lambda *b: pallas_kda.rule_segment(*b, chunk, lm.KDA_SUB,
+                                               lm.KDA_DECAY_FLOOR), *a)
+        return back(out)
+    assert _kernel_count(lower_tpu(both, *args)) == 2
+
+
+def test_kda_rule_outside_the_predicate_lowers_without_kernel():
+    """Heads of 64 channels are no column block of the projections: the
+    rule takes the jnp form and still lowers."""
+    from mxnet_tpu.ops import lm
+    q, k, v, g, beta, _ = _kda_segment(1, 32, 2, 64, jnp.bfloat16)
+    assert not lm._rule_in_kernel(32, 64, 64, 16, jnp.bfloat16)
+    txt = lower_tpu(lambda *a: lm.delta_rule_chunked(*a, chunk_size=16)[0],
+                    q, k, v, g, beta)
+    assert _kernel_count(txt) == 0
+
+
 def test_fused_resnet50_train_step_verifies(monkeypatch):
     """The full MXTPU_FUSE=aggressive train step — every rewritten conv
     with its real shape class — must pass Mosaic verification, and the
