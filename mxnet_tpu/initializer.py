@@ -69,14 +69,19 @@ class Initializer(object):
             self._init_zero(name, arr)
         elif name.endswith('moving_avg'):
             self._init_zero(name, arr)
-        elif name.endswith(('expert_load', 'expert_count', 'kda_count')):
-            # SparseExperts' counting states (its expert_bias is a bias)
-            # and KimiDeltaAttention's
+        elif name.endswith(('expert_load', 'expert_count', 'kda_count',
+                            'ssm_count')):
+            # SparseExperts' counting states (its expert_bias is a bias),
+            # KimiDeltaAttention's and Mamba2Mixer's
             self._init_zero(name, arr)
         elif name.endswith('A_log'):
-            # KimiDeltaAttention's log of the decay's rate: a rate of one
-            # (its dt_bias is a bias)
+            # KimiDeltaAttention's and Mamba2Mixer's log of the decay's
+            # rate: a rate of one (their dt_bias, and the convolution's
+            # conv_bias, are biases)
             self._init_zero(name, arr)
+        elif name.endswith('ssm_D'):
+            # Mamba2Mixer's skip from x to y: one, as published
+            self._init_one(name, arr)
         elif 'begin_state' in name:
             self._init_zero(name, arr)
         elif name.endswith('parameters'):

@@ -6,6 +6,7 @@ from . import lstm_lm
 from . import transformer_lm
 from . import lfm2_moe
 from . import kimi_linear
+from . import nemotron_h
 from . import ssd
 
 _MODELS = {
@@ -33,6 +34,7 @@ _MODELS = {
     'transformer_lm': transformer_lm.get_symbol,
     'lfm2_moe': lfm2_moe.get_symbol,
     'kimi_linear': kimi_linear.get_symbol,
+    'nemotron_h': nemotron_h.get_symbol,
     'ssd-vgg16': ssd.get_symbol,
     'ssd-vgg16-train': ssd.get_symbol_train,
 }

@@ -1,5 +1,5 @@
 """Language-model layer operators: RMSNorm, RotaryEmbedding, SwiGLU,
-GatedShortConv, SparseExperts and KimiDeltaAttention.
+GatedShortConv, SparseExperts, KimiDeltaAttention and Mamba2Mixer.
 
 Beyond the reference's 2017 op set: what a sparse decoder-only language
 model (``models/lfm2_moe.py``) needs of a Symbol graph, each with shape
@@ -125,7 +125,8 @@ register('GatedShortConv', _gated_short_conv_apply,
 # chosen.  The op is told which experts it holds (``experts_held`` =
 # (first, count)); it sorts the assignments by expert, those that landed
 # on other devices' experts last, takes the rows at the head of that order
-# into a buffer, runs three grouped matrix products over the buffer, and
+# into a buffer, runs the experts' grouped matrix products (three for a
+# gated expert, two for an ungated one) over the buffer, and
 # combines.  What the absent experts would have added is left out: under
 # expert parallelism their devices add it.
 #
@@ -202,15 +203,16 @@ def grouped_matmul(lhs, rhs, group_sizes):
                               preferred_element_type=lhs.dtype)
 
 
-def route(x, router, bias, k, normalise, scaling):
-    """Chosen experts (T, k) and their float32 weights (T, k)."""
+def route(x, router, bias, k, normalise, scaling, eps=1e-6):
+    """Chosen experts (T, k) and their float32 weights (T, k); ``eps`` is
+    added to the chosen scores' sum before it divides them."""
     logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32).T,
                      precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
     _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
     weights = jnp.take_along_axis(scores, chosen, axis=1)
     if normalise:
-        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + eps)
     return chosen, weights * scaling
 
 
@@ -258,7 +260,9 @@ def _buffered(room, align, k, floats, ints, first=None):
     """The held experts' part of the layer over a buffer of ``room`` rows
     that holds every assignment that landed on them; or, given ``first``,
     over the ``room`` rows of a larger buffer that start at row ``first``
-    (a multiple of ``align``)."""
+    (a multiple of ``align``).  ``floats`` are the rows the experts take
+    (tokens, H), the weights of ``route`` and the experts' matrices, ``w3``
+    None for experts without a gate."""
     x, weights, w1, w3, w2 = floats
     order, group_sizes = ints
     count, tokens = group_sizes.shape[0], x.shape[0]
@@ -282,8 +286,11 @@ def _buffered(room, align, k, floats, ints, first=None):
         xs = _dispatch(x, token, tokens)
     with jax.named_scope('experts'):
         # the rows past the last group are in no group: unvisited, unwritten
-        hidden = jax.nn.silu(grouped_matmul(xs, w1, groups)) * \
-            grouped_matmul(xs, w3, groups)
+        if w3 is None:      # ungated: relu(x W1)^2 W2
+            hidden = jnp.square(jax.nn.relu(grouped_matmul(xs, w1, groups)))
+        else:
+            hidden = jax.nn.silu(grouped_matmul(xs, w1, groups)) * \
+                grouped_matmul(xs, w3, groups)
         ys = grouped_matmul(hidden, w2, groups)
     with jax.named_scope('combine'):
         gate = jnp.take(weights.reshape(-1), assignment)[:, None]
@@ -346,14 +353,31 @@ def _on_the_ladder_bwd(rooms, align, k, res, g):
 _on_the_ladder.defvjp(_on_the_ladder_fwd, _on_the_ladder_bwd)
 
 
+def _sparse_experts_inputs(attrs):
+    """The op's inputs by name: the rows the experts take come apart from
+    the data the router scores under ``latent_input``, and experts without
+    a gate (``expert_form`` ``relu2``) have no ``w3_weight``."""
+    form = attrs.get('expert_form', 'swiglu')
+    if form not in ('swiglu', 'relu2'):
+        raise ValueError('SparseExperts: expert_form is swiglu or relu2, '
+                         'not %r' % (form,))
+    return ['data'] + ['latent'] * bool(attrs.get('latent_input')) + \
+        ['router_weight', 'w1_weight'] + \
+        ['w3_weight'] * (form == 'swiglu') + ['w2_weight']
+
+
 def _sparse_experts_apply(attrs, inputs, is_train, rng):
-    x, router, w1, w3, w2, bias, _, count_so_far = inputs
+    names = _sparse_experts_inputs(attrs)
+    given = dict(zip(names, inputs))
+    bias, _, count_so_far = inputs[len(names):]
+    x, router = given['data'], given['router_weight']
     k = int(attrs['experts_per_tok'])
     first, count = _held(attrs)
     with jax.named_scope('router'):
         chosen, weights = route(x, router, bias, k,
                                 bool(attrs['norm_topk_prob']),
-                                float(attrs['routed_scaling_factor']))
+                                float(attrs['routed_scaling_factor']),
+                                float(attrs['topk_eps']))
     with jax.named_scope('dispatch'):
         local = chosen.reshape(-1) - first
         mine = (local >= 0) & (local < count)
@@ -363,7 +387,8 @@ def _sparse_experts_apply(attrs, inputs, is_train, rng):
         group_sizes = jnp.bincount(key, length=count + 1)[:count] \
             .astype(jnp.int32)
     rooms, _, align = _room(chosen.size, count, int(attrs['num_experts']))
-    floats = (x, weights, w1, w3, w2)
+    floats = (given.get('latent', x), weights, given['w1_weight'],
+              given.get('w3_weight'), given['w2_weight'])
     ints = (order, group_sizes)
     if len(rooms) > 1:
         rung = _rung(rooms, _aligned(group_sizes, align).sum())
@@ -407,18 +432,24 @@ def _sparse_experts_counters(now, before, attrs, in_shapes):
 def _sparse_experts_complete(attrs, in_shapes):
     if in_shapes[0] is None:
         return in_shapes
-    hidden = in_shapes[0][-1]
+    at = {name: i for i, name in enumerate(_sparse_experts_inputs(attrs))}
     _, count = _held(attrs)
-    _complete(in_shapes, 1, (int(attrs['num_experts']), hidden))
-    if in_shapes[2] is not None:
-        width = in_shapes[2][2]
+    _complete(in_shapes, at['router_weight'],
+              (int(attrs['num_experts']), in_shapes[0][-1]))
+    rows = in_shapes[at.get('latent', 0)]
+    if rows is None:
+        return in_shapes
+    hidden = rows[-1]
+    if in_shapes[at['w1_weight']] is not None:
+        width = in_shapes[at['w1_weight']][2]
     elif attrs.get('expert_hidden') is not None:
         width = int(attrs['expert_hidden'])
     else:
         return in_shapes
-    _complete(in_shapes, 2, (count, hidden, width))
-    _complete(in_shapes, 3, (count, hidden, width))
-    _complete(in_shapes, 4, (count, width, hidden))
+    for name in ('w1_weight', 'w3_weight'):
+        if name in at:
+            _complete(in_shapes, at[name], (count, hidden, width))
+    _complete(in_shapes, at['w2_weight'], (count, width, hidden))
     return in_shapes
 
 
@@ -427,8 +458,7 @@ def _sparse_experts_aux_shapes(attrs, in_shapes):
 
 
 register('SparseExperts', _sparse_experts_apply,
-         input_names=lambda attrs: ['data', 'router_weight', 'w1_weight',
-                                    'w3_weight', 'w2_weight'],
+         input_names=_sparse_experts_inputs,
          num_outputs=lambda attrs: 1,
          aux_names=lambda attrs: ['expert_bias', 'expert_load',
                                   'expert_count'],
@@ -439,10 +469,19 @@ register('SparseExperts', _sparse_experts_apply,
          attr_defaults={'num_experts': None, 'experts_held': None,
                         'experts_per_tok': 1, 'expert_hidden': None,
                         'norm_topk_prob': True,
-                        'routed_scaling_factor': 1.0},
+                        'routed_scaling_factor': 1.0,
+                        'expert_form': 'swiglu', 'latent_input': False,
+                        'topk_eps': 1e-6},
          hint='sparseexperts',
-         doc='The held experts\' part of a routed SwiGLU expert layer: '
-             'data (T, H) -> (T, H).  Auxiliary states: expert_bias '
+         doc='The held experts\' part of a layer of routed experts: data '
+             '(T, H) -> (T, H).  An expert is silu(x W1) * (x W3) then W2 '
+             '(expert_form swiglu, the default) or relu(x W1)^2 then W2 with '
+             'no w3_weight input (relu2).  Under latent_input the experts '
+             'take the rows of a second input, latent (T, L), and give (T, '
+             'L), while the router scores data: experts that live in a '
+             'latent narrower than the model.  topk_eps is added to the '
+             'chosen scores\' sum before it divides them.  Auxiliary states: '
+             'expert_bias '
              '(num_experts,), added to the scores for the choice only and '
              'never trained; expert_load (held,), the assignments each held '
              'expert received in the last step; expert_count (4,), running '
@@ -519,16 +558,19 @@ KDA_SEGMENT = 16
 KDA_DECAY_FLOOR = -10.0
 
 
-def causal_conv_silu(x, kernel, lead):
+def causal_conv_silu(x, kernel, lead, bias=None):
     """``silu`` of the causal depthwise convolution of ``x`` (N, lead + T, C)
     along T: tap ``j`` of ``kernel`` (C, taps) weighs the token ``j`` back;
     the first ``lead`` rows of ``x`` (at least taps - 1; zeros before a
-    sequence's start) are the tokens before the T that get an output.
-    Shifted multiply-adds, as ``GatedShortConv``."""
+    sequence's start) are the tokens before the T that get an output;
+    ``bias`` (C,), if given, is added before the ``silu``.  Shifted
+    multiply-adds, as ``GatedShortConv``."""
     t = x.shape[1] - lead
     mixed = kernel[:, 0] * x[:, lead:]
     for j in range(1, kernel.shape[1]):
         mixed = mixed + kernel[:, j] * x[:, lead - j:lead - j + t]
+    if bias is not None:
+        mixed = mixed + bias
     return jax.nn.silu(mixed.astype(jnp.float32)).astype(x.dtype)
 
 
@@ -765,14 +807,14 @@ def _segments_bwd(static, res, cotangent):
 _segments.defvjp(_segments_fwd, _segments_bwd)
 
 
-def _segmenting(chunk_size, t):
+def _segmenting(chunk_size, t, sub=KDA_SUB, segment=KDA_SEGMENT):
     """How ``t`` tokens go: the tokens of a chunk (``chunk_size``, or the
-    sequence if that is shorter, up to a multiple of the sub-block), the
-    chunks of a segment (up to ``KDA_SEGMENT``, a divisor of the chunks) and
-    the tokens that pad the sequence to whole chunks."""
-    c = -(-min(int(chunk_size), t) // KDA_SUB) * KDA_SUB
+    sequence if that is shorter, up to a multiple of ``sub``), the chunks of
+    a segment (up to ``segment``, a divisor of the chunks) and the tokens
+    that pad the sequence to whole chunks."""
+    c = -(-min(int(chunk_size), t) // sub) * sub
     chunks = -(-t // c)
-    per = next(s for s in range(min(KDA_SEGMENT, chunks), 0, -1)
+    per = next(s for s in range(min(segment, chunks), 0, -1)
                if chunks % s == 0)
     return c, per, chunks * c - t
 
@@ -925,3 +967,206 @@ register('KimiDeltaAttention', _kimi_delta_attention_apply,
              'output.  Auxiliary state count (3,): running totals of '
              'tokens, chunks, and log-decays (one a token and channel) that '
              'lay under -10 and were held to it.')
+
+
+# ---------------------------------------------------------------------------
+# Mamba2Mixer: what lies between the two projections of a Mamba-2 layer
+# (Dao and Gu 2024, arXiv:2405.21060; ``model_type`` ``nemotron_h``).  With H
+# heads of P channels, G groups and a state of N a head, d = H P:
+#
+#   xBC <- silu(conv(xBC) + b_conv)     (causal, depthwise), [x | B | C] = xBC
+#   dt  <- softplus(dt + dt_bias),  A = -exp(A_log), both one a head
+#   S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T    (S is P x N; head h reads the
+#   y_t = S_t C_t + D x_t                          B, C of group h // (H / G))
+#   output RMSNorm_group(y * silu(z)) * gamma, the mean square over each
+#   group's d / G channels
+#
+# with S_0 = 0 at every sequence's start.  As ``KimiDeltaAttention``, one
+# scope, ``scan``, and under it ``conv``, ``gates`` and ``out_gate``: all of
+# it runs a segment of chunks at a time inside ``_segments``, whose backward
+# pass makes each segment again from the state it started with.  The
+# recurrence runs in chunks of ``chunk_size`` tokens as matrix products (the
+# state-space duality of the paper): with a_t = dt_t A and G_t its sum from
+# the chunk's start, inside a chunk y_t = sum_{i <= t} (C_t . B_i) e^(G_t -
+# G_i) dt_i x_i, a masked product of the chunk's C B^T (one a group) with the
+# decays (one a head); the state a chunk leaves is e^(G_C) S_0 + sum_i e^(G_C
+# - G_i) dt_i x_i B_i^T, the states at the chunks' starts follow from those
+# by the decays between chunks, and a chunk adds e^(G_t) S_0 C_t to its
+# outputs.  The decay is a scalar a head and every difference G_t - G_i is
+# taken before its exponential and is at most 0: nothing is held to a floor
+# or a clamp, and the chunked form is the recurrence up to rounding.  Sums
+# and decays are float32; the products take the inputs' dtype and accumulate
+# in float32.
+# ---------------------------------------------------------------------------
+
+SSM_SEGMENT = 8
+
+
+def _decays(sums):
+    """``e^(sums_t - sums_i)`` for ``i <= t`` along the last axis of the
+    running sums ``sums``, 0 for ``i > t``: (..., t, i).  The difference is
+    taken before the exponential and is at most 0."""
+    size = sums.shape[-1]
+    return jnp.exp(jnp.where(jnp.tril(jnp.ones((size, size), bool)),
+                             sums[..., :, None] - sums[..., None, :],
+                             -jnp.inf))
+
+
+def ssd_chunked(x, b, c, dt, a, state, per):
+    """The recurrence over ``per`` chunks from ``state`` (N, H, P, S),
+    float32.  ``x`` (N, T, H, P), ``b`` and ``c`` (N, T, G, S), the step
+    ``dt`` (N, T, H) float32 (0 for a token that writes and decays nothing)
+    and the rate ``a`` (H,) float32, under 0.  Returns the state after the
+    last chunk and ``y`` (N, T, H, P) without the ``D x`` term."""
+    n, t, h, p = x.shape
+    g, size, dtype = b.shape[2], t // per, x.dtype
+    k = h // g                                  # heads a group
+
+    def chunks(v, *by_group):
+        return v.reshape((n, per, size) + (by_group or v.shape[2:]))
+    total = jnp.cumsum(chunks(dt * a), axis=2)           # G: (N, Z, C, H)
+    last = total[:, :, -1]                               # G_C: (N, Z, H)
+    xs = chunks((x * dt[..., None]).astype(dtype), g, k, p)
+    bs, cs = chunks(b), chunks(c)
+    # inside a chunk: (C_t . B_i) e^(G_t - G_i) over i <= t, then dt_i x_i;
+    # a head's (t, i) square is the last two axes
+    pairs = jnp.einsum('nztgs,nzigs->nzgti', cs, bs,
+                       preferred_element_type=jnp.float32)
+    mixed = pairs[:, :, :, None] * _decays(total.transpose(0, 1, 3, 2)) \
+        .reshape(n, per, g, k, size, size)
+    inside = jnp.einsum('nzgkti,nzigkp->nztgkp', mixed.astype(dtype), xs,
+                        preferred_element_type=jnp.float32)
+    # what a chunk adds to the state it found: sum_i e^(G_C - G_i) dt_i x_i
+    # B_i^T
+    rest = jnp.exp(last[:, :, None] - total).reshape(n, per, size, g, k)
+    added = jnp.einsum('nzigkp,nzigs->nzgkps',
+                       (xs * rest[..., None]).astype(dtype), bs,
+                       preferred_element_type=jnp.float32)
+    # the states at the chunks' starts, and after the last: the state given
+    # and what each chunk added, by the decays of the chunks between
+    found = jnp.concatenate(
+        [state[:, None], added.reshape(n, per, h, p, -1)], axis=1)
+    sums = jnp.pad(jnp.cumsum(last, axis=1), ((0, 0), (1, 0), (0, 0)))
+    starts = jnp.einsum('nhzj,njhps->nzhps',
+                        _decays(sums.transpose(0, 2, 1)), found)
+    before = jnp.einsum(
+        'nztgs,nzgkps->nztgkp', cs,
+        starts[:, :-1].astype(dtype).reshape(n, per, g, k, p, -1),
+        preferred_element_type=jnp.float32) * \
+        jnp.exp(total).reshape(n, per, size, g, k)[..., None]
+    return starts[:, -1], (inside + before).reshape(n, t, h, p).astype(dtype)
+
+
+def _ssm_segment(sizes, t, per, size, lead, params, state, xs, first):
+    """One segment of the mixer: ``per`` chunks of ``size`` tokens from token
+    ``first`` of the (padded) sequences, whose first ``t`` tokens are real;
+    ``xs`` are its rows of ``z``, ``xBC`` and ``dt`` behind the ``lead`` rows
+    before them.  Returns the state after it and its outputs."""
+    heads, groups, states, eps = sizes
+    kernel, bias, a_log, d, dt_bias, gamma = params
+    z, xbc, dt = xs
+    n, rows = z.shape[0], per * size
+    channels = z.shape[-1]
+    with jax.named_scope('conv'):
+        xbc = causal_conv_silu(xbc, kernel, lead, bias)
+        x = xbc[..., :channels].reshape(n, rows, heads, -1)
+        b, c = (v.reshape(n, rows, groups, states) for v in jnp.split(
+            xbc[..., channels:], 2, axis=-1))
+    with jax.named_scope('gates'):
+        # padded tokens write nothing and decay nothing
+        real = (first + jnp.arange(rows)) < t
+        dt = jnp.where(real[:, None], jax.nn.softplus(
+            dt[:, lead:].astype(jnp.float32) + dt_bias.astype(jnp.float32)),
+            0.0)
+        a = -jnp.exp(a_log.astype(jnp.float32))
+    state, y = ssd_chunked(x, b, c, dt, a, state, per)
+    with jax.named_scope('out_gate'):
+        y = y.astype(jnp.float32) + \
+            d.astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+        y = y.reshape(n, rows, groups, -1) * jax.nn.silu(
+            z[:, lead:].astype(jnp.float32)).reshape(n, rows, groups, -1)
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps)
+        out = y.reshape(n, rows, channels) * gamma.astype(jnp.float32)
+    return state, out.astype(z.dtype), jnp.float32(0)
+
+
+def _mamba2_sizes(attrs):
+    return (int(attrs['num_heads']), int(attrs['head_dim']),
+            int(attrs['state_size']), int(attrs['num_groups']))
+
+
+def _mamba2_mixer_apply(attrs, inputs, is_train, rng):
+    (z, xbc, dt, kernel, bias, a_log, d, dt_bias, gamma,
+     count_so_far) = inputs
+    heads, _, states, groups = _mamba2_sizes(attrs)
+    n, t, _ = z.shape
+    lead = kernel.shape[1] - 1
+    size, per, pad = _segmenting(attrs['chunk_size'], t, 1, SSM_SEGMENT)
+    segment = functools.partial(
+        _ssm_segment, (heads, groups, states, float(attrs['eps'])), t, per,
+        size, lead)
+    with jax.named_scope('scan'):
+        out, _ = _segments(
+            (segment, per * size, lead,
+             (n, heads, z.shape[-1] // heads, states)),
+            (kernel, bias, a_log, d, dt_bias, gamma),
+            tuple(_padded(v, lead, pad) for v in (z, xbc, dt)))
+    step = jnp.asarray([n * t, n * ((t + pad) // size)], jnp.float32)
+    return [out[:, :t]], {'count': count_so_far.astype(jnp.float32) + step}
+
+
+def _mamba2_mixer_counters(now, before, attrs, in_shapes):
+    """The layer's tokens and chunks since the last drain, into the
+    registry.  The chunked form holds nothing to a floor, so there is no
+    third count as ``kda.decays_at_floor``."""
+    from .. import instrument
+    count = now['count'] - (before['count'] if before else 0)
+    instrument.inc('ssm.tokens', int(count[0]))
+    instrument.inc('ssm.chunks', int(count[1]))
+
+
+def _mamba2_mixer_complete(attrs, in_shapes):
+    if in_shapes[0] is None:
+        return in_shapes
+    heads, size, states, groups = _mamba2_sizes(attrs)
+    n, t, channels = in_shapes[0]
+    if channels != heads * size:
+        raise ValueError('Mamba2Mixer: z has %d channels, not num_heads x '
+                         'head_dim = %d' % (channels, heads * size))
+    mixed = channels + 2 * groups * states
+    _complete(in_shapes, 1, (n, t, mixed))
+    _complete(in_shapes, 2, (n, t, heads))
+    _complete(in_shapes, 3, (mixed, int(attrs['kernel'])))
+    _complete(in_shapes, 4, (mixed,))
+    for i in (5, 6, 7):
+        _complete(in_shapes, i, (heads,))
+    _complete(in_shapes, 8, (channels,))
+    return in_shapes
+
+
+register('Mamba2Mixer', _mamba2_mixer_apply,
+         input_names=lambda attrs: [
+             'z', 'xBC', 'dt', 'conv_weight', 'conv_bias', 'A_log', 'D',
+             'dt_bias', 'norm_gamma'],
+         num_outputs=lambda attrs: 1,
+         aux_names=lambda attrs: ['count'],
+         aux_shape=lambda attrs, in_shapes: [(2,)],
+         complete_shapes=_mamba2_mixer_complete,
+         keep_dtype=('A_log', 'D', 'dt_bias'),
+         aux_counters=_mamba2_mixer_counters,
+         attr_defaults={'num_heads': None, 'head_dim': None,
+                        'state_size': None, 'num_groups': 1, 'kernel': 4,
+                        'chunk_size': 128, 'eps': 1e-5},
+         hint='mamba2mixer',
+         doc='A Mamba-2 layer between its projections: z (N, T, H * P), xBC '
+             '(N, T, H * P + 2 G S) and dt (N, T, H) -> (N, T, H * P), for '
+             'num_heads H of head_dim P, num_groups G and state_size S.  A '
+             'causal depthwise convolution with a bias and silu on xBC = [x '
+             '| B | C]; a step softplus(dt + dt_bias) and a rate -exp(A_log) '
+             'a head; the state S_t = exp(dt_t A) S_(t-1) + dt_t x_t B_t^T '
+             'and y_t = S_t C_t + D x_t, head h with the B and C of group h '
+             '// (H / G), in chunks of chunk_size tokens as masked matrix '
+             'products with the state carried from chunk to chunk; an RMS '
+             'norm of y * silu(z) over each group\'s channels, times '
+             'norm_gamma.  Auxiliary state count (2,): running totals of '
+             'tokens and chunks.')

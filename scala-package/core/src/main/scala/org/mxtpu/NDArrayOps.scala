@@ -1,7 +1,7 @@
 package org.mxtpu
 
 /** GENERATED by tools/gen_scala_ops.py from the live op
-  * registry (236 ops) — do not edit.  Imperative invoke-
+  * registry (237 ops) — do not edit.  Imperative invoke-
   * by-name creators (NDArray.genericInvoke -> the C ABI
   * MXImperativeInvokeByName), the role of the reference
   * NDArrayMacro expansion.
@@ -67,6 +67,8 @@ object NDArrayOps {
     NDArray.genericInvoke("MAERegressionOutput", inputs, attrs)
   def MakeLoss(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
     NDArray.genericInvoke("MakeLoss", inputs, attrs)
+  def Mamba2Mixer(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
+    NDArray.genericInvoke("Mamba2Mixer", inputs, attrs)
   def MultiBoxDetection(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =
     NDArray.genericInvoke("MultiBoxDetection", inputs, attrs)
   def MultiBoxPrior(inputs: Seq[NDArray], attrs: (String, Any)*): Array[NDArray] =

@@ -1,7 +1,7 @@
 package org.mxtpu
 
 /** GENERATED by tools/gen_scala_ops.py from the live op
-  * registry (236 ops) — do not edit.  The role of the
+  * registry (237 ops) — do not edit.  The role of the
   * reference scala-package macro expansion
   * (SymbolImplMacros.scala): one typed creator per
   * registered operator, delegating to Symbol.create.
@@ -70,6 +70,8 @@ object SymbolOps {
     Symbol.create("MAERegressionOutput", name)(inputs: _*)(attrs: _*)
   def MakeLoss(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
     Symbol.create("MakeLoss", name)(inputs: _*)(attrs: _*)
+  def Mamba2Mixer(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
+    Symbol.create("Mamba2Mixer", name)(inputs: _*)(attrs: _*)
   def MultiBoxDetection(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
     Symbol.create("MultiBoxDetection", name)(inputs: _*)(attrs: _*)
   def MultiBoxPrior(name: String = "")(inputs: (String, Symbol)*)(attrs: (String, Any)*): Symbol =
