@@ -34,9 +34,13 @@ from . import compile_cache, instrument
 from .base import MXNetError
 from .context import Context, current_context
 from .ndarray import NDArray, zeros as nd_zeros, RANDOM
+from .ops.registry import KEEP, marks
 from .symbol import Symbol
 
 __all__ = ['Executor', 'simple_bind']
+
+# what a mirror stage keeps beside its inputs: the values its ops mark
+_KEEP_POLICY = jax.checkpoint_policies.save_only_these_names(KEEP)
 
 
 def _build_graph_fn(symbol: Symbol, is_train: bool, monitor_re=None,
@@ -66,6 +70,8 @@ def _build_graph_fn(symbol: Symbol, is_train: bool, monitor_re=None,
         if is_train and monitor_re is None else \
         [([(i, n)], None, None) for i, n in enumerate(nodes)
          if not n.is_variable]
+    # the values the mirror stages keep by name, counted at the first trace
+    counted = [not _count]
 
     def run(members, entry_vals, aux_updates, monitored, rng):
         """Apply the op nodes ``members`` ((index, node) pairs) in order,
@@ -109,23 +115,32 @@ def _build_graph_fn(symbol: Symbol, is_train: bool, monitor_re=None,
                 entry_vals[(id(node), 0)] = aux_values[node.name]
             else:
                 raise MXNetError('unbound variable %s' % node.name)
+        kept = [0]
         for members, taken, given in units:
             if taken is None:
                 run(members, entry_vals, aux_updates, monitored, rng)
                 continue
 
-            # one mirror stage: kept are its inputs, its inside is
-            # computed again in the backward pass
+            # one mirror stage: kept are its inputs and what its ops mark
+            # (ops.registry.keep), the rest of its inside is computed again
+            # in the backward pass; a stage that marks nothing keeps what a
+            # plain jax.checkpoint keeps
             def stage(values, rng, members=members, taken=taken,
                       given=given):
                 local, aux_local = dict(zip(taken, values)), {}
+                before = marks()
                 run(members, local, aux_local, None, rng)
+                kept[0] += marks() - before
                 return [local[e] for e in given], aux_local
 
-            outs, aux_local = jax.checkpoint(stage)(
+            outs, aux_local = jax.checkpoint(stage, policy=_KEEP_POLICY)(
                 [entry_vals[e] for e in taken], rng)
             entry_vals.update(zip(given, outs))
             aux_updates.update(aux_local)
+        if not counted[0]:
+            counted[0] = True
+            if kept[0]:
+                instrument.inc('executor.mirror_kept', kept[0])
         outputs = [entry_vals[(id(n), x)] for n, x in out_entries]
         if monitor_re is not None:
             return outputs, aux_updates, monitored

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from . import pallas_kda
-from .registry import register, register_simple
+from .registry import keep, register, register_simple
 from .nn import _complete
 
 
@@ -205,12 +205,18 @@ def grouped_matmul(lhs, rhs, group_sizes):
 
 def route(x, router, bias, k, normalise, scaling, eps=1e-6):
     """Chosen experts (T, k) and their float32 weights (T, k); ``eps`` is
-    added to the chosen scores' sum before it divides them."""
+    added to the chosen scores' sum before it divides them.  The choice and
+    the chosen logits are kept by a mirror stage, and the weights are the
+    sigmoid of the chosen logits (the chosen scores, value and gradient): so
+    the backward pass needs neither the (T, experts) scores nor the product
+    that made them, and runs no ``top_k`` again."""
     logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32).T,
                      precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
+    scores = jax.nn.sigmoid(jax.lax.stop_gradient(logits))
     _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
-    weights = jnp.take_along_axis(scores, chosen, axis=1)
+    chosen = keep(chosen)
+    weights = jax.nn.sigmoid(
+        keep(jnp.take_along_axis(logits, chosen, axis=1)))
     if normalise:
         weights = weights / (weights.sum(axis=-1, keepdims=True) + eps)
     return chosen, weights * scaling
@@ -383,9 +389,9 @@ def _sparse_experts_apply(attrs, inputs, is_train, rng):
         mine = (local >= 0) & (local < count)
         # absent experts' assignments sort last
         key = jnp.where(mine, local, count)
-        order = jnp.argsort(key, stable=True)
-        group_sizes = jnp.bincount(key, length=count + 1)[:count] \
-            .astype(jnp.int32)
+        order = keep(jnp.argsort(key, stable=True))
+        group_sizes = keep(jnp.bincount(key, length=count + 1)[:count]
+                           .astype(jnp.int32))
     rooms, _, align = _room(chosen.size, count, int(attrs['num_experts']))
     floats = (given.get('latent', x), weights, given['w1_weight'],
               given.get('w3_weight'), given['w2_weight'])

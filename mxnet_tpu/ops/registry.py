@@ -35,11 +35,33 @@ used by ``simple_bind`` (reference ``src/c_api/c_api_symbolic.cc:408``).
 from __future__ import annotations
 
 import ast
+import threading
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
-__all__ = ['OpDef', 'register', 'register_simple', 'get_op', 'list_ops', 'alias']
+__all__ = ['OpDef', 'register', 'register_simple', 'get_op', 'list_ops', 'alias',
+           'keep', 'marks', 'KEEP']
+
+# the one name under which an op marks a value that a mirror stage keeps for
+# the backward pass instead of computing it again (executor._build_graph_fn)
+KEEP = 'mxtpu.keep'
+_marked = threading.local()
+
+
+def keep(x):
+    """``x``, marked for a mirror stage to keep: for a value cheap to keep
+    and costly to compute again, such as a sort's order.  Outside a mirror
+    stage the mark changes nothing."""
+    _marked.count = marks() + 1
+    return checkpoint_name(x, KEEP)
+
+
+def marks():
+    """How many values ``keep`` has marked on this thread, in all."""
+    return getattr(_marked, 'count', 0)
+
 
 _REGISTRY: Dict[str, 'OpDef'] = {}
 _ALIASES: Dict[str, str] = {}
