@@ -16,7 +16,9 @@ fast as the hardware allows" north star, in three legs:
    too, so a second process reuses compiled executables from disk
    instead of re-invoking XLA.  The cache's monitoring events land in
    the PR-1 instrument registry as ``compile.cache_hits`` /
-   ``compile.cache_misses`` and the ``compile.time_saved_secs`` timer.
+   ``compile.cache_misses``, and JAX's timings of every trace, lowering,
+   backend compile and cache read as ``compile.*_secs`` histograms
+   (``_install_listeners``).
 
 2. **AOT warmup manifest** — every jit trace taken through
    :func:`traced` (the executor's forward/fwd+bwd programs, the fused
@@ -43,10 +45,11 @@ fast as the hardware allows" north star, in three legs:
    bound the number of distinct compiled inference shapes (the
    ``compile.shape_buckets`` gauge).
 
-Zero overhead when off: with neither variable set the manifest is
-never created (recording is one module-global ``is None`` check, taken
-only at trace time anyway), no JAX config is touched, no listener is
-registered, and no pool thread exists.
+Zero overhead when off: with neither variable set and the metrics
+registry off the manifest is never created (recording is one
+module-global ``is None`` check, taken only at trace time anyway), no
+JAX config is touched, no listener is registered, and no pool thread
+exists.
 """
 from __future__ import annotations
 
@@ -78,6 +81,8 @@ _cache_dir = None          # installed directory, or None
 _manifest = None           # _Manifest once the cache dir is installed
 _pool = None
 _inflight = 0
+_listening = False
+_listen_lock = threading.Lock()
 
 
 # ---------------------------------------------------------------------------
@@ -148,13 +153,46 @@ def manifest_path():
         os.path.join(_cache_dir, MANIFEST_NAME)
 
 
+# JAX's timed compile events -> the histogram each lands in
+_PHASES = {
+    '/jax/core/compile/jaxpr_trace_duration': 'compile.trace_secs',
+    '/jax/core/compile/jaxpr_to_mlir_module_duration': 'compile.lower_secs',
+    '/jax/core/compile/backend_compile_duration': 'compile.backend_secs',
+}
+_CACHE_READ = '/jax/compilation_cache/cache_retrieval_time_sec'
+_open = threading.local()     # .stack: [event, seconds inside] per thread
+
+
 def _install_listeners():
-    """Mirror the cache's monitoring events into the instrument
-    registry.  jax emits a request event at the top of every cached
-    compile and a hit event only on retrieval, on the same thread in
-    the same call — so a miss is counted eagerly per request and
-    un-counted when the hit lands (the transient is invisible outside
-    the compile call itself)."""
+    """Mirror JAX's compile events into the instrument registry, once a
+    process: when the persistent cache is installed or the registry
+    first turns on, whichever comes first.  Each listener returns after
+    one flag test while the registry is off.
+
+    - jax emits a request event at the top of every cached compile and
+      a hit event only on retrieval, on the same thread in the same
+      call — so ``compile.cache_misses`` is counted eagerly per request
+      and un-counted when the hit lands (the transient is invisible
+      outside the compile call itself), ``compile.cache_hits`` on the
+      hit.
+    - JAX times each trace of a function to a jaxpr, each lowering to
+      an MLIR module and each backend compile: the histograms
+      ``compile.trace_secs``, ``compile.lower_secs`` and
+      ``compile.backend_secs``, and every backend compile counts
+      ``compile.programs`` (compiled or fetched from the cache).  A
+      compile event inside another (a jit run while a function traces)
+      is counted once, in the innermost: each histogram takes an
+      event's time less that of the compile events inside it, so the
+      three together are the wall time spent in them.
+    - The backend event holds the persistent cache's read on a hit and
+      its write on a miss.  A hit's read is also
+      ``compile.cache_read_secs``: the compile proper is
+      ``compile.backend_secs`` less ``compile.cache_read_secs``."""
+    global _listening
+    with _listen_lock:
+        if _listening:
+            return
+        _listening = True
     from jax._src import monitoring
 
     def on_event(event, **kw):
@@ -164,12 +202,36 @@ def _install_listeners():
             instrument.inc('compile.cache_hits')
             instrument.inc('compile.cache_misses', -1)
 
+    def on_start(event, value, **kw):
+        # JAX records a timed event's start time as a scalar
+        if not instrument.metrics_enabled():
+            return
+        if event in _PHASES:
+            stack = _open.__dict__.setdefault('stack', [])
+            stack.append([event, 0.0])
+
     def on_duration(event, duration, **kw):
-        if event == '/jax/compilation_cache/compile_time_saved_sec':
-            instrument.observe('compile.time_saved_secs', duration)
+        if not instrument.metrics_enabled():
+            return
+        name = _PHASES.get(event)
+        if name is None:
+            if event == _CACHE_READ:
+                instrument.observe_hist('compile.cache_read_secs', duration)
+            return
+        stack = getattr(_open, 'stack', None)
+        inside = stack.pop()[1] if stack and stack[-1][0] == event else 0.0
+        if stack:
+            stack[-1][1] += duration
+        instrument.observe_hist(name, max(duration - inside, 0.0))
+        if name == 'compile.backend_secs':
+            instrument.inc('compile.programs')
 
     monitoring.register_event_listener(on_event)
+    monitoring.register_scalar_listener(on_start)
     monitoring.register_event_duration_secs_listener(on_duration)
+
+
+instrument.when_metrics_on(_install_listeners)
 
 
 # ---------------------------------------------------------------------------
@@ -348,17 +410,20 @@ def warmup_submit(label, build):
     pre-trace is not a hot-path retrace and must not inflate
     ``executor.xla_traces``); wall time accumulates in the
     ``compile.warmup_secs`` timer and the live count is published as
-    the ``compile.warmup_inflight`` gauge.  Returns the Future."""
+    the ``compile.warmup_inflight`` gauge; under the performance plane
+    the build is a ``perf.phase.compile`` span.  Returns the Future."""
     def run():
         global _inflight
         with _lock:
             _inflight += 1
             instrument.set_gauge('compile.warmup_inflight', _inflight)
+        from . import perfwatch
         t0 = time.perf_counter()
         try:
             with instrument.trace_redirect('compile.warmup_traces'):
                 with instrument.span('compile.warmup[%s]' % label,
-                                     cat='compile'):
+                                     cat='compile'), \
+                        perfwatch.phase('compile'):
                     return build()
         finally:
             with _lock:

@@ -60,6 +60,7 @@ __all__ = [
     'render_prometheus', 'split_labeled_name',
     'device_memory_stats',
     'set_profiling', 'set_metrics', 'profiling_enabled', 'metrics_enabled',
+    'when_metrics_on',
 ]
 
 # Cap per-thread buffered events so an always-on trace cannot grow
@@ -98,6 +99,7 @@ def set_profiling(on):
         if not _metrics_on:
             _metrics_implied = True
         _metrics_on = True
+        _metrics_turned_on()
     elif _metrics_implied:
         _metrics_on = False
         _metrics_implied = False
@@ -107,6 +109,27 @@ def set_metrics(on):
     global _metrics_on, _metrics_implied
     _metrics_on = bool(on)
     _metrics_implied = False
+    if _metrics_on:
+        _metrics_turned_on()
+
+
+# what waits for the registry's first turn on (compile_cache's
+# jax.monitoring listener): a process whose metrics stay off runs none
+_when_on = []
+
+
+def when_metrics_on(fn):
+    """Call ``fn()`` once: now if the registry is on, else the first
+    time :func:`set_metrics` or :func:`set_profiling` turns it on."""
+    if _metrics_on:
+        fn()
+    else:
+        _when_on.append(fn)
+
+
+def _metrics_turned_on():
+    while _when_on:
+        _when_on.pop(0)()
 
 
 def profiling_enabled():
@@ -141,7 +164,9 @@ class _ThreadBuffer(object):
 
 
 _buffers = []                     # every live/retired thread buffer
-_buffers_lock = threading.Lock()
+# reentrant, as the registry's lock is: a full collection's callback
+# (perfwatch's ``perf.gc`` span) may record on a thread that holds it
+_buffers_lock = threading.RLock()
 # serializes drainers against each other (the events list itself needs
 # no lock: append vs slice-copy/slice-delete are each GIL-atomic, and
 # the dropped counter is single-writer monotonic)
@@ -778,7 +803,10 @@ class _TimedCtx(object):
 
 
 _metrics = {}
-_metrics_lock = threading.Lock()
+# reentrant: Python's collector runs its callbacks on whichever thread
+# allocated, and perfwatch's ``perf.gc`` callback observes into this
+# registry, possibly on a thread that already holds the lock
+_metrics_lock = threading.RLock()
 
 
 def _get_metric(name, cls):
@@ -1103,8 +1131,8 @@ def metrics_snapshot():
                                           'count': m.count,
                                           'avg_sec': m.avg}
             elif isinstance(m, Histogram):
-                # snapshot outside the registry lock: Histogram
-                # methods take it themselves (non-reentrant)
+                # snapshot outside the registry lock: each
+                # Histogram takes it for its own read
                 hists.append(m)
     if hists:
         snap['histograms'] = {m.name: m.snapshot() for m in hists}

@@ -259,16 +259,21 @@ class BaseModule(object):
                         'Auto-resuming from checkpoint "%s-%04d.params"',
                         checkpoint_prefix, latest)
 
-        self.bind(data_shapes=train_data.provide_data,
-                  label_shapes=train_data.provide_label,
-                  for_training=True, force_rebind=force_rebind)
+        # the time before the first step, a perf.setup.* span a call
+        with _perfwatch.setup('bind'):
+            self.bind(data_shapes=train_data.provide_data,
+                      label_shapes=train_data.provide_label,
+                      for_training=True, force_rebind=force_rebind)
         if monitor is not None:
             self.install_monitor(monitor)
-        self.init_params(initializer=initializer, arg_params=arg_params,
-                         aux_params=aux_params, allow_missing=allow_missing,
-                         force_init=force_init)
-        self.init_optimizer(kvstore=kvstore, optimizer=optimizer,
-                            optimizer_params=optimizer_params)
+        with _perfwatch.setup('init_params'):
+            self.init_params(initializer=initializer, arg_params=arg_params,
+                             aux_params=aux_params,
+                             allow_missing=allow_missing,
+                             force_init=force_init)
+        with _perfwatch.setup('init_optimizer'):
+            self.init_optimizer(kvstore=kvstore, optimizer=optimizer,
+                                optimizer_params=optimizer_params)
 
         if validation_metric is None:
             validation_metric = eval_metric
@@ -344,7 +349,7 @@ class BaseModule(object):
                     warm_start = bool(_config.get('MXTPU_WARM_START'))
                 if warm_start or getattr(self, '_warm_eager', False):
                     from .. import compile_cache
-                    with instrument.span('fit.warm_start', cat='fit'), \
+                    with _perfwatch.setup('warm_start'), \
                             _iowatch.account('compile'):
                         compile_cache.warm_start(self, eval_metric,
                                                  data_iter=train_data)

@@ -982,7 +982,8 @@ class Module(BaseModule):
             try:
                 # the same lower+compile the jit path would pay —
                 # goodput charges it to the compile bucket
-                with _iowatch.account('compile'):
+                with _iowatch.account('compile'), \
+                        _perfwatch.phase('compile'):
                     aot = self._fused.lower(*args).compile()
             except Exception:
                 self._perf_aot_failed.add(sig)

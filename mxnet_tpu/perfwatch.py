@@ -33,7 +33,8 @@ centrally in ``cluster_status.json``/``.prom``):
    :func:`phase` context manager attributes wall time to the loop's
    seams (``feed_wait``, ``step_prep``, ``dispatch``, ``window_wait``,
    ``callbacks``, ``metric_drain``, ``epoch_end``, ``device_wait``; on
-   the feed thread ``feed_fetch`` and ``feed_stage``) as
+   the feed thread ``feed_fetch`` and ``feed_stage``; ``compile`` round
+   the program's own ``lower().compile()``) as
    ``perf.phase.*`` histograms, under the :func:`fit_step` root of each
    iteration; every one is also a ``mxtpu.``-prefixed annotation in a
    running ``jax.profiler`` trace, on the device planes' clock, and —
@@ -59,6 +60,13 @@ centrally in ``cluster_status.json``/``.prom``):
    the largest live ledger entries, and the current MFU/phase snapshot:
    an OOM becomes a postmortem instead of a stack trace.
 
+The time before the first step and the host's stalls have spans of the
+same seam: :func:`setup` round ``BaseModule.fit``'s bind, parameter and
+optimizer initialisation and warm start (``perf.setup.*``), and a
+``perf.gc`` span with a ``perf.gc_full`` count for every full
+(generation 2) collection of Python's collector, live from import while
+the plane is on so that set-up is covered.
+
 Zero overhead with knobs off: every hook is one module-global check
 (``tests/test_perfwatch.py`` pins < 2x an inlined ideal floor).
 ``MXTPU_PERFWATCH=1`` implies the metrics registry the same way
@@ -66,6 +74,7 @@ Zero overhead with knobs off: every hook is one module-global check
 """
 from __future__ import annotations
 
+import gc
 import hashlib
 import threading
 import time
@@ -80,7 +89,7 @@ __all__ = [
     'extract_cost', 'extract_memory', 'register_executable',
     'executables', 'executable_info', 'clear_executables',
     'PEAKS', 'device_peaks', 'peak_flops', 'mfu', 'roofline_mandatory',
-    'note_step', 'phase', 'sample_tick', 'sample_sync',
+    'note_step', 'phase', 'setup', 'sample_tick', 'sample_sync',
     'ledger_alloc', 'ledger_donate', 'ledger_top', 'ledger_stats',
     'ledger_reset',
     'on_error', 'is_oom', 'forensics_snapshot',
@@ -132,13 +141,9 @@ def refresh():
     at import and from :func:`activate_fit` so an env var exported
     between fits takes effect; hot-path hooks read the cached module
     globals only."""
-    global _on, _sample_n
-    _on = bool(config.get('MXTPU_PERFWATCH'))
+    global _sample_n
     _sample_n = max(0, int(config.get('MXTPU_STEP_SAMPLE')))
-    if _on and not instrument.metrics_enabled():
-        # the plane's output IS the metrics registry — implied on, the
-        # same contract as MXTPU_PROFILE
-        instrument.set_metrics(True)
+    set_enabled(config.get('MXTPU_PERFWATCH'))
 
 
 def set_enabled(on):
@@ -146,7 +151,10 @@ def set_enabled(on):
     global _on
     _on = bool(on)
     if _on and not instrument.metrics_enabled():
+        # the plane's output IS the metrics registry — implied on, the
+        # same contract as MXTPU_PROFILE
         instrument.set_metrics(True)
+    _watch_gc(_on)
 
 
 def enabled():
@@ -504,6 +512,49 @@ def phase(name):
     if not _on:
         return _NULL_PHASE
     return instrument.hist_span('perf.phase.' + name, cat='phase')
+
+
+def setup(name):
+    """A span of the time before the first step (``perf.setup.<name>``:
+    ``bind``, ``init_params``, ``init_optimizer``, ``warm_start`` in
+    ``BaseModule.fit``), on :func:`phase`'s seam.  The shared no-op when
+    the plane is off."""
+    if not _on:
+        return _NULL_PHASE
+    return instrument.hist_span('perf.setup.' + name, cat='setup')
+
+
+# the full collection in progress: collections never overlap, and a
+# collection's start and stop callbacks run on the one thread
+_gc_span = None
+
+
+def _on_collect(stage, info):
+    """``gc.callbacks`` entry while the plane is on: a full (generation
+    2) collection is a ``perf.gc`` span and counts ``perf.gc_full``."""
+    global _gc_span
+    if info['generation'] != 2:
+        return
+    if stage == 'start':
+        _gc_span = instrument.hist_span('perf.gc', cat='gc')
+        _gc_span.__enter__()
+    elif _gc_span is not None:
+        span, _gc_span = _gc_span, None
+        span.__exit__(None, None, None)
+        instrument.inc('perf.gc_full')
+
+
+def _watch_gc(on):
+    """Register :func:`_on_collect` while the plane is on, and only
+    then."""
+    if on and _on_collect not in gc.callbacks:
+        # made now, so that a collection's callback never adds to the
+        # registry and a set-up without one reads 0
+        instrument.histogram('perf.gc')
+        instrument.counter('perf.gc_full')
+        gc.callbacks.append(_on_collect)
+    elif not on and _on_collect in gc.callbacks:
+        gc.callbacks.remove(_on_collect)
 
 
 def fit_step(step_num):

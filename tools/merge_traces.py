@@ -25,8 +25,8 @@ Usage::
 
     python tools/merge_traces.py -o merged.json rank0.json rank1.json ...
     python tools/merge_traces.py -o merged.json --ranks 0,3 a.json b.json
-    python tools/merge_traces.py -o merged.json --anchor fit.warm_start \\
-        --no-align r0.json r1.json
+    python tools/merge_traces.py -o merged.json \\
+        --anchor perf.setup.warm_start --no-align r0.json r1.json
 
 **Replica lanes**: serving events (``cat: 'serving'`` — flush spans
 and the MXTPU_SERVEWATCH request-attribution chains) carry their
