@@ -729,32 +729,46 @@ def _rows(x, first, count):
     return jax.lax.dynamic_slice_in_dim(x, first, count, axis=1)
 
 
+def _cut(x, first, rows, lead):
+    """The ``rows`` rows of ``x`` from row ``first`` behind the ``lead``
+    rows before them, zeros where those lie before the sequence's start."""
+    before = _rows(x, jnp.maximum(first - lead, 0), lead)
+    return jnp.concatenate([jnp.where(first > 0, before, 0),
+                            _rows(x, first, rows)], axis=1)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _segments(static, params, arrays):
     """``segment(params, state, xs, first)`` -> (state, outputs, a count)
-    over the whole of ``arrays`` (each (N, lead + T', ...), T' a multiple of
-    the segment's ``rows``, ``lead`` rows of zeros in front), ``rows`` at a
-    time: ``xs`` are the rows from ``first`` behind the ``lead`` before
-    them, the state (``state_shape``, float32) is carried from segment to
-    segment from zero, and ``params`` are trained arrays; ``static`` is
-    ``(segment, rows, lead, state_shape)``.  Returns the outputs (N, T',
-    ...) and the counts' sum.  The segments are cut out of the arrays where
-    they lie and the outputs written where they belong, and the backward
-    pass does the same from the last segment to the first, making each again
-    from the state it started with and writing its rows' cotangents over
-    the rows it has read: what is kept between the passes is a state a
-    segment, what lives at once is what one segment makes, the arrays are
-    held once, and nothing as long as the sequence is copied into another
-    order."""
+    over the whole of ``arrays`` (each (N, T', ...), T' a multiple of the
+    segment's ``rows``, ``rows`` no fewer than ``lead``, nothing padded in
+    front), ``rows`` at a time:
+    ``xs`` are the rows from ``first`` behind the ``lead`` rows before them
+    (zeros before the first segment), the state (``state_shape``, float32)
+    is carried from segment to segment from zero, and ``params`` are
+    trained arrays; ``static`` is ``(segment, rows, lead, state_shape)``.
+    Returns the outputs (N, T', ...) and the counts' sum.  A segment's rows
+    are cut out of the arrays from row ``first``, where a tile starts, its
+    ``lead`` rows by a slice of their own, and its outputs written from row
+    ``first``.  The backward pass goes from the last segment to the first,
+    making each again from the state it started with and writing its rows'
+    cotangents from row ``first`` over the rows it has read: what is kept
+    between the passes is a state a segment, what lives at once is what one
+    segment makes, the arrays are held once, and nothing as long as the
+    sequence is copied into another order."""
     return _segments_fwd(static, params, arrays)[0]
 
 
 def _segments_fwd(static, params, arrays):
     segment, rows, lead, state_shape = static
-    total = arrays[0].shape[1] - lead
+    total = arrays[0].shape[1]
+    if total % rows or rows < lead:
+        raise ValueError('_segments: arrays of %d rows are no multiple of a '
+                         'segment of %d rows, or it reads %d before it'
+                         % (total, rows, lead))
 
     def cut(first):
-        return tuple(_rows(x, first, lead + rows) for x in arrays)
+        return tuple(_cut(x, first, rows, lead) for x in arrays)
     state = jnp.zeros(state_shape, jnp.float32)
     like = jax.eval_shape(lambda: segment(params, state, cut(0), 0)[1])
     out = jnp.zeros((like.shape[0], total) + like.shape[2:], like.dtype)
@@ -779,54 +793,53 @@ def _segments_bwd(static, res, cotangent):
     def one(carry, x):
         d_state, d_params, arrays, edge = carry
         first, state = x
-        xs = tuple(_rows(a, first, lead + rows) for a in arrays)
+        xs = tuple(_cut(a, first, rows, lead) for a in arrays)
         (_, _, counted), back = jax.vjp(
             lambda p, s, xs: segment(p, s, xs, first), params, state, xs)
         d_p, d_state, d_xs = back((d_state, _rows(d_out, first, rows),
                                    jnp.zeros_like(counted)))
+        d_xs = tuple(d.astype(a.dtype) for d, a in zip(d_xs, arrays))
         # a segment's rows give way to their cotangents once it has read
-        # them, so that the arrays are held once and not twice; but for its
-        # first ``lead`` rows, the last rows of the segment before it, which
-        # has yet to read them: their cotangents wait in ``edge`` and are
-        # added to what that segment finds for them
-        d_xs = tuple(jnp.concatenate([d[:, :rows], d[:, rows:] + e], axis=1)
-                     for d, e in zip((d.astype(a.dtype)
-                                      for d, a in zip(d_xs, arrays)), edge))
+        # them, so that the arrays are held once and not twice; its last
+        # ``lead`` rows take what the segment after it found for them
+        # (``edge``), and the cotangents of the ``lead`` rows before it, the
+        # last rows of the segment before, which has yet to read them, wait
+        # in ``edge`` in turn; the first segment's lie before the sequence
         arrays = tuple(
-            jax.lax.dynamic_update_slice_in_dim(a, d[:, lead:], first + lead,
-                                                axis=1)
-            for a, d in zip(arrays, d_xs))
+            jax.lax.dynamic_update_slice_in_dim(
+                a, jnp.concatenate([d[:, lead:rows], d[:, rows:] + e],
+                                   axis=1), first, axis=1)
+            for a, d, e in zip(arrays, d_xs, edge))
         return (d_state, jax.tree_util.tree_map(jnp.add, d_params, d_p),
                 arrays, tuple(d[:, :lead] for d in d_xs)), None
 
     firsts = jnp.arange(states.shape[0], dtype=jnp.int32) * rows
-    (_, d_params, d_arrays, edge), _ = jax.lax.scan(
+    (_, d_params, d_arrays, _), _ = jax.lax.scan(
         one, (jnp.zeros_like(states[0]),
               jax.tree_util.tree_map(jnp.zeros_like, params), arrays,
               tuple(jnp.zeros_like(a[:, :lead]) for a in arrays)),
         (firsts, states), reverse=True)
-    return d_params, tuple(
-        jax.lax.dynamic_update_slice_in_dim(a, e, 0, axis=1)
-        for a, e in zip(d_arrays, edge))
+    return d_params, d_arrays
 
 
 _segments.defvjp(_segments_fwd, _segments_bwd)
 
 
-def _segmenting(chunk_size, t, sub=KDA_SUB, segment=KDA_SEGMENT):
+def _segmenting(chunk_size, t, sub, segment, lead):
     """How ``t`` tokens go: the tokens of a chunk (``chunk_size``, or the
-    sequence if that is shorter, up to a multiple of ``sub``), the chunks of
-    a segment (up to ``segment``, a divisor of the chunks) and the tokens
+    sequence if that is shorter, but no fewer than the ``lead`` rows a
+    segment reads before it, up to a multiple of ``sub``), the chunks of a
+    segment (up to ``segment``, a divisor of the chunks) and the tokens
     that pad the sequence to whole chunks."""
-    c = -(-min(int(chunk_size), t) // sub) * sub
+    c = -(-max(min(int(chunk_size), t), lead) // sub) * sub
     chunks = -(-t // c)
     per = next(s for s in range(min(segment, chunks), 0, -1)
                if chunks % s == 0)
     return c, per, chunks * c - t
 
 
-def _padded(x, lead, pad):
-    return jnp.pad(x, ((0, 0), (lead, pad)) + ((0, 0),) * (x.ndim - 2))
+def _padded(x, pad):
+    return jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
 
 
 def delta_rule_chunked(q, k, v, g, beta, chunk_size=64):
@@ -839,13 +852,13 @@ def delta_rule_chunked(q, k, v, g, beta, chunk_size=64):
     (beta 0) and decay nothing.  The chunks go in segments of up to
     ``KDA_SEGMENT`` (``_segments``)."""
     n, t, h, d_k = q.shape
-    c, per, pad = _segmenting(chunk_size, t)
+    c, per, pad = _segmenting(chunk_size, t, KDA_SUB, KDA_SEGMENT, 0)
 
     def segment(params, state, xs, first):
         return _rule_segment(t, per, c, state, xs, first)
     out, held = _segments(
         (segment, per * c, 0, (n, h, d_k, v.shape[-1])), (),
-        tuple(_padded(x, 0, pad) for x in (q, k, v, g, beta)))
+        tuple(_padded(x, pad) for x in (q, k, v, g, beta)))
     return out[:, :t], held
 
 
@@ -888,7 +901,8 @@ def _kimi_delta_attention_apply(attrs, inputs, is_train, rng):
     heads, eps = int(attrs['num_heads']), float(attrs['eps'])
     n, t, channels = query.shape
     lead = q_kernel.shape[1] - 1
-    c, per, pad = _segmenting(attrs['chunk_size'], t)
+    c, per, pad = _segmenting(attrs['chunk_size'], t, KDA_SUB, KDA_SEGMENT,
+                              lead)
 
     def segment(params, state, xs, first):
         # the convolutions, the gates and the output's norm and gate run a
@@ -902,7 +916,7 @@ def _kimi_delta_attention_apply(attrs, inputs, is_train, rng):
         out, held = _segments(
             (segment, per * c, lead, (n, heads) + (channels // heads,) * 2),
             ((q_kernel, k_kernel, v_kernel), a_log, dt_bias, o_gamma),
-            tuple(_padded(x, lead, pad)
+            tuple(_padded(x, pad)
                   for x in (query, key, value, decay, beta, gate)))
     step = jnp.stack([jnp.float32(n * t), jnp.float32(n * ((t + pad) // c)),
                       held])
@@ -921,7 +935,8 @@ def _kimi_delta_attention_counters(now, before, attrs, in_shapes):
     count = now['count'] - (before['count'] if before else 0)
     _, t, channels = in_shapes[0]
     d = channels // int(attrs['num_heads'])
-    c, per, _ = _segmenting(attrs['chunk_size'], t)
+    c, per, _ = _segmenting(attrs['chunk_size'], t, KDA_SUB, KDA_SEGMENT,
+                            int(attrs['kernel']) - 1)
     in_kernel = _rule_in_kernel(per * c, d, d, c, jnp.float32)
     instrument.inc('kda.tokens', int(count[0]))
     instrument.inc('kda.chunks', int(count[1]))
@@ -1107,7 +1122,8 @@ def _mamba2_mixer_apply(attrs, inputs, is_train, rng):
     heads, _, states, groups = _mamba2_sizes(attrs)
     n, t, _ = z.shape
     lead = kernel.shape[1] - 1
-    size, per, pad = _segmenting(attrs['chunk_size'], t, 1, SSM_SEGMENT)
+    size, per, pad = _segmenting(attrs['chunk_size'], t, 1, SSM_SEGMENT,
+                                  lead)
     segment = functools.partial(
         _ssm_segment, (heads, groups, states, float(attrs['eps'])), t, per,
         size, lead)
@@ -1116,7 +1132,7 @@ def _mamba2_mixer_apply(attrs, inputs, is_train, rng):
             (segment, per * size, lead,
              (n, heads, z.shape[-1] // heads, states)),
             (kernel, bias, a_log, d, dt_bias, gamma),
-            tuple(_padded(v, lead, pad) for v in (z, xbc, dt)))
+            tuple(_padded(v, pad) for v in (z, xbc, dt)))
     step = jnp.asarray([n * t, n * ((t + pad) // size)], jnp.float32)
     return [out[:, :t]], {'count': count_so_far.astype(jnp.float32) + step}
 

@@ -194,7 +194,7 @@ def test_chunked_delta_rule_is_the_recurrence_forward_and_backward(
     # tile's rows), whole segments, padded tails and floors alike: the
     # segment's forward kernel; differentiated, that kernel in the forward
     # pass, again with every chunk's entering state, and the backward kernel
-    c = lm._segmenting(chunk, t)[0]
+    c = lm._segmenting(chunk, t, lm.KDA_SUB, lm.KDA_SEGMENT, 0)[0]
     in_kernel = path == 'interpret' and c % 16 == 0
     assert kernel_calls(chunked, *inputs) == (1 if in_kernel else 0)
     assert kernel_calls(lambda *a: gradients(lambda *b: chunked(*b)[0]),
@@ -227,18 +227,10 @@ def test_chunked_delta_rule_is_the_recurrence_forward_and_backward(
         assert rel(a, b) < 5e-5, name
 
 
-@pytest.mark.parametrize('path', PATHS)
-@pytest.mark.parametrize('per', [1, 2])
-def test_the_layer_in_segments_is_the_layer_in_one(per, path, monkeypatch):
-    """The operator's convolutions read three rows before a segment, the
-    last rows of the segment before it; the backward pass writes a
-    segment's cotangents over the rows it has read and keeps those three
-    rows' until the segment before has read them: output, count and all
-    twelve gradients are what one segment of all the chunks gives.  In
-    the kernels as in the jnp form, and the two agree."""
-    take_path(monkeypatch, path)
-    n, t, h, d = (1, 90, 2, 128) if path == 'interpret' else (2, 90, 3, 8)
-    rng = np.random.default_rng(5)
+def segments_inputs(n, t, h, d, seed=5):
+    """``KimiDeltaAttention``'s inputs, seeded, with the drawn cotangent of
+    its output."""
+    rng = np.random.default_rng(seed)
     wide = lambda *shape: draw(rng, shape)
     inputs = [wide(n, t, h * d), wide(n, t, h * d), wide(n, t, h * d),
               wide(h * d, 4), wide(h * d, 4), wide(h * d, 4),
@@ -246,8 +238,31 @@ def test_the_layer_in_segments_is_the_layer_in_one(per, path, monkeypatch):
               jnp.log(jnp.asarray([1.0, 4.0, 16.0][:h])),
               wide(h * d), wide(n, t, h), wide(n, t, h * d),
               1.0 + 0.1 * wide(d), jnp.zeros((3,))]
+    return inputs, wide(n, t, h * d)
+
+
+# chunks a segment -> tokens a sequence in chunks of 16: 90 in 6 chunks (6
+# tokens of padding) in six segments or three; 190 in 12 chunks (2 of
+# padding, fewer than the three rows before a segment) in three of four
+SEGMENT_LENGTHS = {1: 90, 2: 90, 4: 190}
+
+
+@pytest.mark.parametrize('path', PATHS)
+@pytest.mark.parametrize('per', sorted(SEGMENT_LENGTHS))
+def test_the_layer_in_segments_is_the_layer_in_one(per, path, monkeypatch):
+    """The operator's convolutions read three rows before a segment, the
+    last rows of the segment before it (zeros before the first segment);
+    the backward pass writes a segment's cotangents from its first row over
+    the rows it has read and keeps those three rows' until the segment
+    before has read them, and the first segment's are dropped: output,
+    count and all twelve gradients are what one segment of all the chunks
+    gives, the padded rows of the last segment too.  In the kernels as in
+    the jnp form, and the two agree."""
+    take_path(monkeypatch, path)
+    t = SEGMENT_LENGTHS[per]
+    n, h, d = (1, 2, 128) if path == 'interpret' else (2, 3, 8)
+    inputs, cot = segments_inputs(n, t, h, d)
     attrs = {'num_heads': h, 'kernel': 4, 'chunk_size': 16, 'eps': 1e-5}
-    cot = wide(n, t, h * d)
 
     def loss(*xs):
         outs, aux = get_op('KimiDeltaAttention').apply(attrs, list(xs), True,
@@ -258,7 +273,7 @@ def test_the_layer_in_segments_is_the_layer_in_one(per, path, monkeypatch):
         with jax.default_matmul_precision('highest'):
             return jax.value_and_grad(loss, argnums=tuple(range(12)),
                                       has_aux=True)(*inputs)
-    (_, (want, want_count)), want_grads = run()       # six chunks, one segment
+    (_, (want, want_count)), want_grads = run()      # every chunk, one segment
     monkeypatch.setattr(lm, 'KDA_SEGMENT', per)
     assert kernel_calls(lambda *xs: loss(*xs)[0], *inputs) == \
         (1 if path == 'interpret' else 0)
@@ -266,8 +281,8 @@ def test_the_layer_in_segments_is_the_layer_in_one(per, path, monkeypatch):
     assert rel(got, want) < 1e-6
     np.testing.assert_array_equal(np.asarray(got_count),
                                   np.asarray(want_count))
-    # 90 tokens a sequence in 6 chunks, and some decays under the floor
-    assert want_count[0] == n * t and want_count[1] == n * 6
+    # the tokens a sequence in chunks of 16, and some decays under the floor
+    assert want_count[0] == n * t and want_count[1] == n * -(-t // 16)
     assert 0 < want_count[2] < n * t * h * d / 2
     for i, (a, b) in enumerate(zip(got_grads, want_grads)):
         assert np.isfinite(np.asarray(a)).all(), i
@@ -278,6 +293,58 @@ def test_the_layer_in_segments_is_the_layer_in_one(per, path, monkeypatch):
         assert rel(got, jnp_form) < 1e-5
         for i, (a, b) in enumerate(zip(got_grads, jnp_grads)):
             assert rel(a, b) < 5e-5, i
+
+
+def front_pads(fn, args, length):
+    """The pads in the program of ``fn`` at ``args``, forward and through
+    ``jax.vjp``, that put rows in front of axis 1 of an array of ``length``
+    rows or more: (rows in front, rows), one a pad, sub-programs included."""
+    from jax.extend import core
+
+    def both(*xs):
+        out, back = jax.vjp(fn, *xs)
+        return out, back(jnp.ones_like(out))
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == 'pad':
+                shape = eqn.invars[0].aval.shape
+                low = eqn.params['padding_config'][1][0]
+                if len(shape) > 1 and shape[1] >= length and low:
+                    yield low, shape[1]
+            for value in eqn.params.values():
+                for inner in (value if isinstance(value, (tuple, list))
+                              else [value]):
+                    if isinstance(inner, core.ClosedJaxpr):
+                        inner = inner.jaxpr
+                    if isinstance(inner, core.Jaxpr):
+                        yield from walk(inner)
+    return list(walk(jax.make_jaxpr(both)(*args).jaxpr))
+
+
+@pytest.mark.parametrize('t', [90, 96])
+def test_the_layer_pads_nothing_in_front_of_its_arrays(t, monkeypatch):
+    """``_segments`` cuts a segment's three rows before it apart from its
+    rows, so no array as long as the sequence is padded in front, forward
+    or backward (the convolutions' own pads are a segment long); and it
+    refuses arrays whose length is no multiple of a segment."""
+    monkeypatch.setattr(lm, 'KDA_SEGMENT', 2)          # segments of 32 rows
+    inputs, _ = segments_inputs(2, t, 2, 8)
+    attrs = {'num_heads': 2, 'kernel': 4, 'chunk_size': 16, 'eps': 1e-5}
+
+    def layer(*xs):
+        return get_op('KimiDeltaAttention').apply(
+            attrs, list(xs) + inputs[12:], True, None)[0][0]
+    assert front_pads(layer, inputs[:12], t) == []
+    # the rule without the convolutions reads no row before a segment
+    q, k, v, g, beta = rule_inputs(1, t, 0.5, d_k=8, d_v=8)
+    assert front_pads(lambda *a: lm.delta_rule_chunked(*a, chunk_size=16)[0],
+                      (q, k, v, g, beta), t) == []
+
+    def segment(params, state, xs, first):
+        return state, xs[0][:, 3:], jnp.float32(0)
+    with pytest.raises(ValueError, match='no multiple'):
+        lm._segments((segment, 32, 3, (1,)), (), (inputs[0][:, :t - 8],))
 
 
 def test_chunked_delta_rule_in_bf16_follows_the_recurrence():

@@ -30,6 +30,7 @@ from mxnet_tpu.ops.registry import get_op
 from mxnet_tpu.parallel.train_step import make_fit_step
 
 import test_lfm2_moe as lfm2
+from test_kimi_linear import front_pads
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 N, T, HIDDEN, VOCAB = 2, 48, 64, 256
@@ -153,12 +154,20 @@ def apply_mixer(chunk, inputs):
 
 # (tokens, chunk): whole chunks in one segment; 9 chunks, so three
 # segments of three; a length that is no multiple of the chunk (padded); a
-# chunk longer than the sequence; 16 chunks in two segments of eight
-MIXER_CASES = [(48, 16), (72, 8), (43, 16), (20, 64), (64, 4)]
+# chunk longer than the sequence; 16 chunks in two segments of eight; 9
+# chunks in three segments of three, the last padded by 2 tokens, fewer
+# than the three rows the convolution reads before a segment; sequences
+# shorter than those three rows, in a chunk of three
+MIXER_CASES = [(48, 16), (72, 8), (43, 16), (20, 64), (64, 4), (70, 8),
+               (2, 64), (1, 16)]
 
 
 @pytest.mark.parametrize('t, chunk', MIXER_CASES)
-def test_the_chunked_mixer_is_the_recurrence_forward_and_backward(t, chunk):
+def test_the_chunked_mixer_is_the_recurrence_forward_and_backward(
+        t, chunk, monkeypatch):
+    """Against the recurrence, and against the same operator in one segment:
+    the rows a segment reads before it (zeros before the first) and the
+    cotangents it keeps for them, the last segment's padded rows."""
     inputs = mixer_inputs(t, t)
     cotangent = draw(np.random.default_rng(1), (2, t, HEADS * SIZE))
     which = tuple(range(len(inputs)))
@@ -171,31 +180,62 @@ def test_the_chunked_mixer_is_the_recurrence_forward_and_backward(t, chunk):
         with jax.default_matmul_precision('highest'):
             return jnp.sum(plain_mixer(*xs) * cotangent)
 
+    def run():
+        with jax.default_matmul_precision('highest'):
+            return jax.grad(program, which, has_aux=True)(*inputs)
     with jax.default_matmul_precision('highest'):
         want = plain_mixer(*inputs)
-        grads, (out, count) = jax.grad(program, which, has_aux=True)(*inputs)
+    grads, (out, count) = run()
     grads_want = jax.grad(plain, which)(*inputs)
+    monkeypatch.setattr(lm, 'SSM_SEGMENT', 64)           # every chunk in one
+    grads_one, (out_one, _) = run()
     assert rel(out, want) < 2e-5
-    for name, got, wanted in zip(get_op('Mamba2Mixer').input_names({}),
-                                 grads, grads_want):
+    assert rel(out, out_one) < 2e-5
+    for name, got, wanted, one in zip(get_op('Mamba2Mixer').input_names({}),
+                                      grads, grads_want, grads_one):
         assert bool(jnp.isfinite(got).all()), name
         assert rel(got, wanted) < 1e-4, name
+        assert rel(got, one) < 1e-4, name
     size = min(chunk, t)
     np.testing.assert_array_equal(np.asarray(count),
                                   [2 * t, 2 * -(-t // size)])
 
 
+@pytest.mark.parametrize('t, chunk', [(70, 8), (64, 4)])
+def test_the_mixer_pads_nothing_in_front_of_its_arrays(t, chunk):
+    """No array as long as the sequence is padded in front, forward or
+    backward: a segment's three rows before it are cut apart from its rows
+    (the convolution's own pads are a segment long); and ``_segments``
+    refuses arrays whose length is no multiple of a segment, and segments
+    shorter than the rows they read before them."""
+    inputs = mixer_inputs(t, t)
+    assert lm._segmenting(chunk, t, 1, lm.SSM_SEGMENT, 3)[1] * chunk < t
+    assert front_pads(lambda *xs: apply_mixer(chunk, xs)[0], inputs, t) == []
+
+    def segment(params, state, xs, first):
+        return state, xs[0][:, 3:], jnp.float32(0)
+    with pytest.raises(ValueError, match='no multiple'):
+        lm._segments((segment, 24, 3, (1,)), (), (inputs[0],))
+    with pytest.raises(ValueError, match='reads 3 before it'):
+        lm._segments((segment, 2, 3, (1,)), (), (inputs[0],))
+
+
 def test_the_mixer_runs_more_than_one_segment_where_the_chunks_allow():
     # 9 chunks go three to a segment, 16 go eight, 7 (a prime) one by one
-    assert lm._segmenting(8, 72, 1, lm.SSM_SEGMENT) == (8, 3, 0)
-    assert lm._segmenting(4, 64, 1, lm.SSM_SEGMENT) == (4, 8, 0)
-    assert lm._segmenting(16, 112, 1, lm.SSM_SEGMENT) == (16, 7, 0)
-    assert lm._segmenting(16, 43, 1, lm.SSM_SEGMENT) == (16, 3, 5)
+    assert lm._segmenting(8, 72, 1, lm.SSM_SEGMENT, 3) == (8, 3, 0)
+    assert lm._segmenting(4, 64, 1, lm.SSM_SEGMENT, 3) == (4, 8, 0)
+    assert lm._segmenting(16, 112, 1, lm.SSM_SEGMENT, 3) == (16, 7, 0)
+    assert lm._segmenting(16, 43, 1, lm.SSM_SEGMENT, 3) == (16, 3, 5)
     # the cell's: 64 chunks of 128 to a sequence of 8192, eight to a segment
-    assert lm._segmenting(128, 8192, 1, lm.SSM_SEGMENT) == (128, 8, 0)
+    assert lm._segmenting(128, 8192, 1, lm.SSM_SEGMENT, 3) == (128, 8, 0)
+    # a chunk is no shorter than the three rows a segment reads before it
+    assert lm._segmenting(128, 2, 1, lm.SSM_SEGMENT, 3) == (3, 1, 1)
+    assert lm._segmenting(1, 11, 1, lm.SSM_SEGMENT, 3) == (3, 4, 1)
     # Kimi Delta Attention's are what they were
-    assert lm._segmenting(64, 8192) == (64, 16, 0)
-    assert lm._segmenting(16, 40) == (16, 3, 8)
+    assert lm._segmenting(64, 8192, lm.KDA_SUB, lm.KDA_SEGMENT, 3) == \
+        (64, 16, 0)
+    assert lm._segmenting(16, 40, lm.KDA_SUB, lm.KDA_SEGMENT, 3) == \
+        (16, 3, 8)
 
 
 def test_the_mixer_in_bf16_follows_the_recurrence():
